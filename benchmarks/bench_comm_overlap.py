@@ -128,7 +128,7 @@ def main() -> None:
     import optax
     from flax.training import train_state
 
-    from distributed_tensorflow_guide_tpu.core.compat import shard_map
+    from jax import shard_map
     from distributed_tensorflow_guide_tpu.core.dist import initialize
     from distributed_tensorflow_guide_tpu.core.mesh import (
         MeshSpec,
@@ -164,8 +164,8 @@ def main() -> None:
         max_len=S, causal=True, dtype=jnp.float32)
     model = Transformer(cfg)
     loss_fn = make_lm_loss_fn(model, fused_ce=False)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, S), jnp.int32))["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, S), jnp.int32))["params"]
     grad_bytes = sum(l.size * np.dtype(l.dtype).itemsize
                      for l in jax.tree.leaves(params))
 

@@ -79,7 +79,7 @@ def main() -> None:
 
     # 2. model + compiled sync-DP step (identical to bench_mnist_dp)
     model = MNISTCNN()
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1))
     )["params"]
 

@@ -48,7 +48,7 @@ def main() -> None:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from distributed_tensorflow_guide_tpu.core.compat import shard_map
+    from jax import shard_map
     from distributed_tensorflow_guide_tpu.core.dist import initialize
     from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec, build_mesh
     from distributed_tensorflow_guide_tpu.parallel.sequence import (

@@ -69,7 +69,7 @@ def main() -> None:
     from jax.sharding import PartitionSpec as P
 
     import distributed_tensorflow_guide_tpu.collectives as cc
-    from distributed_tensorflow_guide_tpu.core.compat import shard_map
+    from jax import shard_map
     from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec, build_mesh
     from distributed_tensorflow_guide_tpu.parallel.sequence import (
         ring_attention,

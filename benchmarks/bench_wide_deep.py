@@ -47,8 +47,9 @@ def main() -> None:
                      mlp_dims=(256, 128))
     data = SyntheticCTR(args.global_batch, vocab_sizes=vocabs, num_dense=8)
     b0 = data.take(1)[0]
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(b0["cat"]),
-                        jnp.asarray(b0["dense"]))["params"]
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.asarray(b0["cat"]),
+        jnp.asarray(b0["dense"]))["params"]
 
     mesh = build_mesh(MeshSpec(data=-1))
     dp = DataParallel(mesh)

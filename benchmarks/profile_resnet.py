@@ -60,7 +60,8 @@ def main() -> None:
     dp = DataParallel(mesh)
     model = ResNet50(num_classes=1000, dtype=jnp.bfloat16)
     rng = jax.random.PRNGKey(0)
-    variables = model.init(rng, jnp.zeros((1, 224, 224, 3)), train=False)
+    variables = jax.jit(lambda rng: model.init(
+        rng, jnp.zeros((1, 224, 224, 3)), train=False))(rng)
     tx = optax.sgd(0.1, momentum=0.9)
     state = dp.replicate(
         TrainStateWithStats.create(

@@ -5,8 +5,8 @@ Runs the full benchmark suite in a fixed order, each bench in its own
 subprocess with a hard timeout, and appends one JSON object per bench to
 ``bench_results/battery_<stamp>.jsonl`` — the bench's own result line plus
 {name, argv, rc, secs, tail-on-failure}. A bench that fails or hangs does
-not stop the battery (the chip may flap mid-capture; partial evidence
-beats none).
+not stop the battery (partial evidence beats none), but the battery then
+exits 1.
 
 Order is by evidence value for the round: flagship ResNet first (the
 driver's metric), then the compute-bound MFU configs (GPT-2 pipeline,
@@ -69,7 +69,7 @@ BATTERY: list[tuple[str, list[str], int]] = [
     # autotune table), then the pipeline A/B row: identical argv to
     # gpt2_pp_gpipe except --fused-ce on — fused CE is the only changed
     # variable vs that row. The pair adjudicates the round-8 MFU>=0.45
-    # target (BASELINE.md config 5).
+    # target (BASELINE.json config 5).
     ("fused_ce_kernel",
      ["benchmarks/bench_fused_ce.py", "--tune"], 1200),
     ("gpt2_pp_fused_ce",
@@ -514,11 +514,10 @@ def main() -> None:
     outdir.mkdir(exist_ok=True)
     stamp = time.strftime("%Y%m%d_%H%M%S")
     path = Path(args.out) if args.out else outdir / f"battery_{stamp}.jsonl"
-    # history context computed ONCE (detect_device_kind imports jax in
-    # this driver process — cheap relative to one bench, not to 45)
-    hist = None if args.no_history else {
-        "device_kind": regress.detect_device_kind(),
-        "git_rev": regress.git_sha()}
+    # history context computed ONCE. The device_kind of each entry is the
+    # bench's own (its result line): this driver never touches jax, or it
+    # would hold the chip every row's subprocess needs.
+    hist = None if args.no_history else {"git_rev": regress.git_sha()}
     n_ok = 0
     n_recs = 0  # bench records actually written (run_one writes one each)
     try:
@@ -539,6 +538,8 @@ def main() -> None:
         if n_recs == 0 and path.exists():
             path.unlink()
     print(f"[battery] {n_ok}/{len(todo)} ok -> {path}", file=sys.stderr)
+    if n_ok < len(todo):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -45,29 +45,17 @@ LINT_DEVICES = 8  # the tier-1 fake-mesh size every expectation is pinned at
 def _ensure_cpu_devices(n: int = LINT_DEVICES) -> None:
     """Fake CPU devices for standalone runs. Importing this package already
     imports jax, but the *backend* only materializes at the first
-    ``jax.devices()`` — until then the device count is still configurable
-    (0.4.x reads the XLA flag at client creation; ≥0.5 has the config).
-    If a backend is already live (pytest / bench harness), that caller's
-    device setup wins — contracts are pinned at 8 devices either way
-    (tests/conftest.py uses 8 too)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("JAX_NUM_CPU_DEVICES", str(n))
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
+    ``jax.devices()`` — until then the platform and the device count are
+    still configurable. If a backend is already live (pytest / bench
+    harness), that caller's device setup wins — contracts are pinned at 8
+    devices either way (tests/conftest.py uses 8 too)."""
     import jax
+    from jax._src import xla_bridge
 
-    from distributed_tensorflow_guide_tpu.core import compat
-
-    try:
-        from jax._src import xla_bridge
-        if xla_bridge.backends_are_initialized():
-            return
-    except Exception:
-        pass
+    if xla_bridge.backends_are_initialized():
+        return
     jax.config.update("jax_platforms", "cpu")
-    compat.set_cpu_device_count(n)
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 # ---- tracing + rule execution ----------------------------------------------

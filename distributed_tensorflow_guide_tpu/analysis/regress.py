@@ -14,10 +14,10 @@ maps to a registered program the report joins the golden-fingerprint
 bless ``reason`` that last changed that program's trace — the first
 suspect for "the model moved" vs "the machine moved".
 
-Deliberately jax-free at import (like ``obs/recon.py``): the history
-store must be writable from the battery driver and readable from CI
-without bringing up a backend. ``detect_device_kind`` imports jax
-lazily and degrades to a host label.
+Deliberately jax-free (like ``obs/recon.py``): the history store is
+written by the battery driver, and a driver that brought up a backend
+would hold the chip its bench subprocesses need. The ``device_kind`` an
+entry is grouped by therefore comes from the bench's own result line.
 
 Non-guarantees: ``append_entry`` is best-effort (a read-only checkout
 must never fail a bench run over bookkeeping), and the gate compares a
@@ -81,19 +81,6 @@ def history_path() -> Path:
     return _repo_root() / DEFAULT_DIRNAME / HISTORY_FILENAME
 
 
-def detect_device_kind() -> str:
-    """``jax.devices()[0].device_kind`` when a backend is importable,
-    else a host-arch label — the grouping key must never raise."""
-    try:
-        import jax
-
-        return jax.devices()[0].device_kind
-    except Exception:
-        import platform
-
-        return f"host-{platform.machine() or 'unknown'}"
-
-
 def git_sha() -> str | None:
     """Short HEAD sha, or None outside a readable git checkout."""
     try:
@@ -114,7 +101,8 @@ def make_entry(row: str, result: dict | None, *,
     """One history entry from a bench's JSON result line.
 
     ``result`` is the line :func:`benchmarks.common.report` printed (or
-    None / a ``{"skipped": ...}`` stub). The measured-vs-modeled ratio
+    None / a ``{"skipped": ...}`` stub); its ``device_kind`` names the
+    entry's group unless the caller passes one. The measured-vs-modeled ratio
     comes from whichever evidence the line carries, best first:
     ``efficiency`` + ``bound`` (an ``obs.recon.reconcile`` output
     embedded in the line), else the roofline fractions
@@ -125,7 +113,8 @@ def make_entry(row: str, result: dict | None, *,
     entry: dict = {
         "ts": round(time.time() if ts is None else ts, 3),
         "row": row,
-        "device_kind": device_kind or detect_device_kind(),
+        "device_kind": (device_kind or (result or {}).get("device_kind")
+                        or "unknown"),
         "git_sha": git_rev if git_rev is not None else git_sha(),
     }
     if program:

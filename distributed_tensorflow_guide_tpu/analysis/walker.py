@@ -14,9 +14,8 @@ pinned by positive controls in tests/test_analysis.py:
   counted as one use. :func:`input_use_counts` counts list occurrences.
 
 Everything duck-types on the ``jax.extend.core`` surface (``eqns`` /
-``jaxpr`` / ``invars`` / ``outvars`` / ``primitive.name``) so the walker
-keeps working across the 0.4/0.5/0.7 lines core/compat.py spans — and so
-tests can feed it hand-built equation shells as positive controls.
+``jaxpr`` / ``invars`` / ``outvars`` / ``primitive.name``) so tests can
+feed the walker hand-built equation shells as positive controls.
 """
 
 from __future__ import annotations

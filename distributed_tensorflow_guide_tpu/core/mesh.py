@@ -166,10 +166,10 @@ def build_mesh(
 ) -> Mesh:
     """Build a ``jax.sharding.Mesh`` over ``devices`` (default: all).
 
-    Uses ``mesh_utils.create_device_mesh`` when possible so the logical mesh
-    maps onto the physical ICI torus with nearest-neighbor rings per axis
-    (critical for ppermute/psum bandwidth); falls back to a plain reshape on
-    backends with no topology info (CPU fake devices in tests).
+    Uses ``mesh_utils.create_device_mesh`` so the logical mesh maps onto
+    the physical ICI torus with nearest-neighbor rings per axis (critical
+    for ppermute/psum bandwidth); on backends with no topology (CPU fake
+    devices in tests) that is a plain reshape.
 
     Multi-slice deployments (``num_slices() > 1``) get the hybrid layout:
     ``dcn_axis`` (default ``data`` — one gradient allreduce per step is
@@ -186,29 +186,12 @@ def build_mesh(
             hybrid_device_array(sizes, devices, n_slices, dcn_axis), AXES
         )
     shape = tuple(sizes[a] for a in AXES)
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception as e:
-        # On a real pod slice this fallback loses ICI-neighbor placement, so
-        # warn loudly there; on CPU ordering is meaningless, so log quietly.
-        import logging
-
-        lg = logging.getLogger(__name__)
-        level = (
-            logging.DEBUG
-            if devices and devices[0].platform == "cpu"
-            else logging.WARNING
-        )
-        lg.log(
-            level,
-            "create_device_mesh failed (%s); falling back to reshape "
-            "ordering — logical axes may not map to ICI neighbors",
-            e,
-        )
-        dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, AXES)
+    # No fallback: where create_device_mesh cannot map the logical axes
+    # onto the chip's torus it raises, and so does this. Reshape order on
+    # a real slice would run, with collectives on the wrong links.
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devices), AXES)
 
 
 def single_device_mesh(device: jax.Device | None = None) -> Mesh:

@@ -22,8 +22,10 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import hashlib
 import logging
 import os
+import shutil
 import subprocess
 from pathlib import Path
 from typing import Iterator, Mapping, Sequence
@@ -38,6 +40,10 @@ _LIB_CACHE: dict[str, ctypes.CDLL] = {}
 MASK64 = (1 << 64) - 1
 
 
+class NativeLoaderUnavailable(RuntimeError):
+    """This machine has no C++ toolchain to build the loader with."""
+
+
 # -- build + bind ------------------------------------------------------------
 
 
@@ -45,8 +51,10 @@ def _build_lib(cache_dir: str | Path | None = None) -> Path:
     cache_dir = Path(cache_dir or os.environ.get(
         "DTG_NATIVE_CACHE", Path.home() / ".cache" / "dtg_native"))
     cache_dir.mkdir(parents=True, exist_ok=True)
-    src_mtime = int(_SRC.stat().st_mtime)
-    so = cache_dir / f"dataloader_{src_mtime}.so"
+    # keyed by what the source SAYS: a copied checkout has new mtimes and
+    # the same code, an edited file can keep its second
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    so = cache_dir / f"dataloader_{digest}.so"
     if so.exists():
         return so
     tmp = so.with_suffix(f".build{os.getpid()}.so")
@@ -59,12 +67,18 @@ def _build_lib(cache_dir: str | Path | None = None) -> Path:
 
 
 def load_native_lib() -> ctypes.CDLL | None:
-    """Compile (cached) and bind the C ABI; None if no toolchain."""
+    """Compile (cached) and bind the C ABI; None if there is no toolchain.
+    With a toolchain, a build that fails raises: the source is broken, and
+    the Python twin would hide it."""
+    if shutil.which("g++") is None:
+        log.warning("no g++: native dataloader unavailable; using Python twin")
+        return None
     try:
         so = _build_lib()
-    except (subprocess.CalledProcessError, FileNotFoundError, OSError) as e:
-        log.warning("native dataloader unavailable (%s); using Python twin", e)
-        return None
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            "native dataloader failed to build:\n"
+            + e.stderr.decode(errors="replace")) from e
     key = str(so)
     if key not in _LIB_CACHE:
         lib = ctypes.CDLL(key)
@@ -308,7 +322,8 @@ class NativeRecordLoader:
         self._rb = record_bytes(self.fields)
         lib = load_native_lib()
         if lib is None:
-            raise RuntimeError("native loader unavailable; use PyRecordLoader")
+            raise NativeLoaderUnavailable(
+                "native loader unavailable; use PyRecordLoader")
         self._lib = lib
         if augment is None:
             self._h = lib.dl_open(str(path).encode(), self._rb, batch_size,
@@ -452,7 +467,7 @@ def open_record_loader(path, fields, batch_size, **kw):
     """Native if a toolchain exists, Python twin otherwise."""
     try:
         return NativeRecordLoader(path, fields, batch_size, **kw)
-    except RuntimeError:
+    except NativeLoaderUnavailable:
         kw.pop("prefetch", None)
         kw.pop("n_threads", None)
         return PyRecordLoader(path, fields, batch_size, **kw)

@@ -23,7 +23,8 @@ Usage::
         examples/mnist_sync_dp.py --steps 100
 
     # On a TPU pod each host runs the SAME command (no launcher needed);
-    # this CLI is for single-host multi-process development and CI.
+    # this CLI is for single-host multi-process development and CI on
+    # virtual CPU devices.
 
 The launched script needs no flags parsing for topology: it just calls
 ``distributed_tensorflow_guide_tpu.core.dist.initialize()``, which reads the
@@ -52,12 +53,14 @@ def _build_parser() -> argparse.ArgumentParser:
     p.add_argument("--num-processes", "-n", type=int, default=2)
     p.add_argument(
         "--devices-per-process", type=int, default=1,
-        help="virtual CPU devices per process (cpu platform only)",
+        help="virtual CPU devices per process",
     )
     p.add_argument(
-        "--platform", choices=["cpu", "tpu", "auto"], default="cpu",
-        help="cpu: force JAX_PLATFORMS=cpu with virtual devices (default, "
-        "for dev/CI); tpu/auto: leave device selection to JAX",
+        "--platform", choices=["cpu"], default="cpu",
+        help="children run on virtual CPU devices. A chip belongs to one "
+        "process at a time, and N children started with one environment "
+        "would all reach for the same chips: on a TPU host one process "
+        "drives every local chip, and a pod runs the same command per host",
     )
     p.add_argument("--timeout", type=float, default=600.0,
                    help="wall-clock limit for the whole run (seconds)")
@@ -80,12 +83,11 @@ def _child_env(ns: argparse.Namespace, coordinator: str, pid: int) -> dict:
     env["JAX_COORDINATOR_ADDRESS"] = coordinator
     env["JAX_NUM_PROCESSES"] = str(ns.num_processes)
     env["JAX_PROCESS_ID"] = str(pid)
-    if ns.platform == "cpu":
-        env["JAX_PLATFORMS"] = "cpu"
-        env["JAX_NUM_CPU_DEVICES"] = str(ns.devices_per_process)
-        # Scrub a parent XLA_FLAGS device-count override that would fight
-        # the per-process count above.
-        env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_NUM_CPU_DEVICES"] = str(ns.devices_per_process)
+    # Scrub a parent XLA_FLAGS device-count override that would fight the
+    # per-process count above.
+    env.pop("XLA_FLAGS", None)
     return env
 
 

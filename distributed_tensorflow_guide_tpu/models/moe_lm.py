@@ -19,12 +19,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import distributed_tensorflow_guide_tpu.collectives as cc
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import axis_sizes
 from distributed_tensorflow_guide_tpu.models.transformer import (
     MultiHeadAttention,

@@ -1,5 +1,5 @@
 """ResNet — judged config 2: "ResNet-50 ImageNet MultiWorkerMirroredStrategy
-(NCCL allreduce → lax.psum)" (BASELINE.md), the north-star throughput model.
+(NCCL allreduce → lax.psum)" (BASELINE.json), the north-star throughput model.
 
 Reference context: the guide's multi-GPU tower example (⚠
 Multiple-GPUs-Single-Machine/) replicates a model per GPU and averages tower

@@ -67,9 +67,9 @@ class TransformerConfig:
     #            custom_partitioning rule that shards batch/heads (heads →
     #            the "model" axis) and replicates seq/head_dim.
     # "auto"   — flash for causal long-context (max_len >= 1024), else dense.
-    #            Measured on the v5 lite chip: dense wins below ~1k tokens
-    #            (XLA's fused softmax beats the kernel-dispatch overhead) and
-    #            CANNOT COMPILE at >= 1024 under remat, where flash runs.
+    #            Round-5 runs on the v5 lite chip had dense ahead below ~1k
+    #            tokens (XLA's fused softmax beats the kernel-dispatch
+    #            overhead); the crossover has not been measured since.
     attn_impl: str = "auto"
     # Manual-SPMD tensor parallelism (TP inside shard_map, e.g. TP-sharded
     # pipeline stages): set ``tp_axis`` to the mesh axis name and build the

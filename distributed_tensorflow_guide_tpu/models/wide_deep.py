@@ -1,5 +1,5 @@
 """Wide&Deep recommender — judged config 4: "Wide&Deep recommender, async PS
-→ synchronous ICI allreduce" (BASELINE.md).
+→ synchronous ICI allreduce" (BASELINE.json).
 
 Reference context: recommender training is the canonical
 ParameterServerStrategy workload

@@ -6,10 +6,11 @@ collective bytes) with a live measured duration and report achieved
 GF/s / GB/s, per-resource roofline fractions, and which resource the
 measurement says the program is bound by.
 
-Deliberately jax-free: ``reconcile`` duck-types its ``cost`` argument —
-a real :class:`~distributed_tensorflow_guide_tpu.analysis.cost.CostVector`,
-or any dict with the same keys (e.g. one loaded from a lint ``--json``
-report) — so the obs package stays stdlib-only at import.
+``reconcile`` duck-types its ``cost`` argument — a real
+:class:`~distributed_tensorflow_guide_tpu.analysis.cost.CostVector`, or
+any dict with the same keys (e.g. one loaded from a lint ``--json``
+report) — and touches no device; only ``Roofline.from_env`` asks which
+one is attached.
 
 Non-guarantees: the cost vector is the *algorithmic* model (fusion
 boundaries, undercounted while-bodies — see docs/analysis.md); the
@@ -28,10 +29,11 @@ import os
 class Roofline:
     """Peak rates to reconcile against (bytes and flops per second).
 
-    ``from_env`` reads ``DTG_PEAK_FLOPS`` / ``DTG_PEAK_HBM_BPS`` /
-    ``DTG_PEAK_ICI_BPS`` / ``DTG_PEAK_PCIE_BPS`` with v5e-class
-    defaults — callers with a real device table (benchmarks/common.py)
-    should pass explicit numbers.
+    ``from_env`` takes FLOP/s and HBM from the peaks table's row for the
+    attached TPU (core/device.py; an unknown TPU raises) — off-chip, from
+    the row of the part the repo is sized for, which makes every fraction
+    a model. ``DTG_PEAK_FLOPS`` / ``DTG_PEAK_HBM_BPS`` override them;
+    ``DTG_PEAK_ICI_BPS`` / ``DTG_PEAK_PCIE_BPS`` opt those resources in.
     """
 
     peak_flops_s: float
@@ -43,12 +45,17 @@ class Roofline:
 
     @classmethod
     def from_env(cls) -> "Roofline":
+        from distributed_tensorflow_guide_tpu.core import device
+
+        peaks = (device.attached_peaks()
+                 or device.peaks_for(device.REFERENCE_KIND))
         ici = os.environ.get("DTG_PEAK_ICI_BPS")
         pcie = os.environ.get("DTG_PEAK_PCIE_BPS")
         return cls(
-            peak_flops_s=float(os.environ.get("DTG_PEAK_FLOPS", 1.97e14)),
+            peak_flops_s=float(
+                os.environ.get("DTG_PEAK_FLOPS", peaks.bf16_flops)),
             peak_hbm_bytes_s=float(
-                os.environ.get("DTG_PEAK_HBM_BPS", 8.19e11)),
+                os.environ.get("DTG_PEAK_HBM_BPS", peaks.hbm_bytes)),
             peak_ici_bytes_s=float(ici) if ici else None,
             peak_pcie_bytes_s=float(pcie) if pcie else None)
 

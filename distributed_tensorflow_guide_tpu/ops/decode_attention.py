@@ -45,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distributed_tensorflow_guide_tpu.ops import autotune
 from distributed_tensorflow_guide_tpu.ops.autotune import (
@@ -60,11 +61,6 @@ from distributed_tensorflow_guide_tpu.ops.flash_attention import (
     _vmem_scratch,
     _vmem_spec,
 )
-
-try:  # pltpu resolves fully on TPU builds; interpret mode works regardless
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
 
 LANE = 128
 
@@ -121,14 +117,14 @@ def ensure_decode_tuned(*, b: int, h: int, s: int, d: int, dtype,
 
 def supported(s: int, blk_k: int, chunk: int = 1) -> bool:
     """Shapes the kernel handles: sublane-multiple KV edge dividing the
-    cache length, a resolvable grid spec, and a q chunk within the
-    unblocked-tile VMEM cap (``DECODE_MAX_CHUNK`` — the one grid cell
-    holds the whole padded chunk plus its f32 score temporaries). Callers
+    cache length, and a q chunk within the unblocked-tile VMEM cap
+    (``DECODE_MAX_CHUNK`` — the one grid cell holds the whole padded
+    chunk plus its f32 score temporaries). Callers
     fall back to the dense kernel-layout path otherwise; for a long
     prefill chunk that is the DESIGNED route, not a degradation."""
     cp = -(-chunk // DECODE_CHUNK_SUBLANES) * DECODE_CHUNK_SUBLANES
-    return (pltpu is not None and blk_k % 8 == 0 and s % blk_k == 0
-            and s >= blk_k and cp <= DECODE_MAX_CHUNK)
+    return (blk_k % 8 == 0 and s % blk_k == 0 and s >= blk_k
+            and cp <= DECODE_MAX_CHUNK)
 
 
 # --------------------------------------------------------------------------
@@ -671,9 +667,7 @@ def _default_blk_k(s: int) -> int:
     """The tested-default cascade: the largest default edge that divides
     ``s`` (the cache length, or the pool block size on the paged path).
     Sweep-free and lookup-free — the online front door's fallback must
-    never re-enter the resolution path. Defined BELOW the pallas kernels
-    on purpose: jaxpr fingerprints embed kernel source line numbers, so
-    resolution-layer code must not shift them."""
+    never re-enter the resolution path."""
     for cand in (DEFAULT_DECODE_BLK_K, 128, 64, 32, 16, 8):
         if cand <= s and s % cand == 0:
             return cand

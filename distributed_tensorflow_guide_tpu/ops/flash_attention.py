@@ -35,14 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports only resolve fully on TPU builds; interpret works anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from distributed_tensorflow_guide_tpu.ops import autotune
 from distributed_tensorflow_guide_tpu.ops.autotune import (
@@ -59,18 +52,11 @@ def _interpret() -> bool:
 
 
 def _vmem_spec(block_shape=None, index_map=None):
-    kw = {}
-    if _VMEM is not None:
-        kw["memory_space"] = _VMEM
-    return pl.BlockSpec(block_shape, index_map, **kw)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _vmem_scratch(shape, dtype):
-    if _VMEM is not None:
-        return _VMEM(shape, dtype)
-    from jax.experimental.pallas import MemorySpace
-
-    return MemorySpace.ANY(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 # --------------------------------------------------------------------------
@@ -508,38 +494,15 @@ def _in_auto_mesh() -> bool:
     activates every logical constraint eagerly, which breaks flax's own
     ``DenseGeneral`` + ``with_logical_partitioning`` combination (the kernel
     initializes flattened to rank 2 while the logical names are rank 4)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):  # jax >= 0.7
-        am = jax.sharding.get_abstract_mesh()
-        if am.axis_names:
-            from jax.sharding import AxisType
+    am = jax.sharding.get_abstract_mesh()
+    if am.axis_names:
+        from jax.sharding import AxisType
 
-            return not any(t == AxisType.Manual for t in am.axis_types)
-    try:  # legacy `with mesh:` context (no public accessor)
-        from jax._src import mesh as mesh_lib
+        return not any(t == AxisType.Manual for t in am.axis_types)
+    # legacy `with mesh:` context: no public accessor
+    from jax._src import mesh as mesh_lib
 
-        if not hasattr(jax.sharding, "get_abstract_mesh"):
-            # 0.4.x: shard_map's Manual context shows up as bound named
-            # axes, not as an AxisType — axes bound means the raw kernel
-            # call is right
-            from jax._src.core import get_axis_env
-
-            if get_axis_env().axis_sizes:
-                return False
-        return not mesh_lib.thread_resources.env.physical_mesh.empty
-    except (ImportError, AttributeError):  # pragma: no cover
-        # A jax upgrade moved the private probe. Warn loudly: without it,
-        # TensorParallel+flash would fall back to the raw pallas call and
-        # die in the GSPMD partitioner with a cryptic custom-call error.
-        import warnings
-
-        warnings.warn(
-            "flash_attention: legacy mesh probe broke (jax internals "
-            "moved); the custom_partitioning path may not engage under "
-            "TensorParallel. Update _in_auto_mesh for this jax version.",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return False
+    return not mesh_lib.thread_resources.env.physical_mesh.empty
 
 
 def _bh_sharding(mesh, sharding, rank: int = 4):
@@ -552,15 +515,10 @@ def _bh_sharding(mesh, sharding, rank: int = 4):
 
 
 def _make_cp():
-    from jax.experimental.custom_partitioning import custom_partitioning
-
-    try:
-        # Shardy rules exist from jax 0.5; 0.4.x runs the GSPMD partitioner
-        # only, where def_partition has no sharding_rule kwarg — omit it
-        # there (the infer/partition callbacks carry the same contract).
-        from jax.experimental.custom_partitioning import SdyShardingRule
-    except ImportError:
-        SdyShardingRule = None
+    from jax.experimental.custom_partitioning import (
+        SdyShardingRule,
+        custom_partitioning,
+    )
 
     fwd_cp = custom_partitioning(
         lambda q, k, v, scale, causal, blk_q, blk_k: _fwd_call(
@@ -582,17 +540,14 @@ def _make_cp():
 
         return mesh, lower, (s, s), (s, s, s)
 
-    fwd_kwargs = {}
-    if SdyShardingRule is not None:
-        fwd_kwargs["sharding_rule"] = SdyShardingRule(
-            (("b", "h", "s", "d"),) * 3,
-            (("b", "h", "s", "d"), ("b", "h", "s", "l")),
-            need_replication_factors=("s", "d", "l"),
-        )
     fwd_cp.def_partition(
         partition=fwd_part,
         infer_sharding_from_operands=fwd_infer,
-        **fwd_kwargs,
+        sharding_rule=SdyShardingRule(
+            (("b", "h", "s", "d"),) * 3,
+            (("b", "h", "s", "d"), ("b", "h", "s", "l")),
+            need_replication_factors=("s", "d", "l"),
+        ),
     )
 
     bwd_cp = custom_partitioning(
@@ -618,18 +573,15 @@ def _make_cp():
 
         return mesh, lower, (s, s, s), (s, s, s, s, s, s3)
 
-    bwd_kwargs = {}
-    if SdyShardingRule is not None:
-        bwd_kwargs["sharding_rule"] = SdyShardingRule(
+    bwd_cp.def_partition(
+        partition=bwd_part,
+        infer_sharding_from_operands=bwd_infer,
+        sharding_rule=SdyShardingRule(
             (("b", "h", "s", "d"),) * 4
             + (("b", "h", "s", "l"), ("b", "h", "s")),
             (("b", "h", "s", "d"),) * 3,
             need_replication_factors=("s", "d", "l"),
-        )
-    bwd_cp.def_partition(
-        partition=bwd_part,
-        infer_sharding_from_operands=bwd_infer,
-        **bwd_kwargs,
+        ),
     )
     return fwd_cp, bwd_cp
 

@@ -40,12 +40,11 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import distributed_tensorflow_guide_tpu.collectives as cc
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import axis_sizes
 from distributed_tensorflow_guide_tpu.parallel.grad_accum import (
     accumulate_grads,

@@ -28,11 +28,11 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import distributed_tensorflow_guide_tpu.collectives as cc
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import axis_sizes
 from distributed_tensorflow_guide_tpu.parallel import overlap as overlap_mod
 

@@ -42,12 +42,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import distributed_tensorflow_guide_tpu.collectives as cc
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import (
     AXES,
     MeshSpec,
@@ -241,14 +240,7 @@ class MultiSliceLocalSGD:
         return TwoTierState(inner=state, outer_momentum=momentum)
 
     def replicate(self, tt_state: TwoTierState) -> TwoTierState:
-        from distributed_tensorflow_guide_tpu.core.compat import (
-            device_put_global,
-        )
-
-        sharding = NamedSharding(self.mesh, P())
-        return device_put_global(
-            tt_state, jax.tree.map(lambda _: sharding, tt_state)
-        )
+        return jax.device_put(tt_state, NamedSharding(self.mesh, P()))
 
     def batch_spec(self, *, leading_time_axis: bool = True) -> P:
         axes = (self.outer_axis, self.inner_axis)

@@ -58,8 +58,8 @@ def ring_attention(q, k, v, *, axis: str = "context", causal: bool = False,
     on-chip at EVERY tested length (round-5 battery: the Pallas carry path
     sustained only 0.157/0.255/0.487x of XLA at seq 1k/2k/4k), so "auto"
     now selects it unconditionally; the round-3 6.4x-the-other-way numbers
-    predate the round-4 rewrites of both paths and are retired in
-    BASELINE.md. "pallas" OPTS IN to the fused carry-kernel path
+    predate the round-4 rewrites of both paths and are retired. Neither
+    has been measured on this machine. "pallas" OPTS IN to the fused carry-kernel path
     (ops/flash_attention.py flash_carry_step, hand-written ring backward,
     ``lax.cond`` dead-rotation skip) — the survey's designated hard native
     part, kept first-class for the planned on-chip bisect and for any part
@@ -160,8 +160,7 @@ def _ring_steps_fwd(q, k, v, axis, causal, scale):
     n = cc.axis_size(axis)
     # the rotation-source index matters only for causal masking; tracing
     # axis_index into the non-causal program would put a live-but-unused
-    # PartitionId in the scan carry, which jax 0.4.x's SPMD partitioner
-    # refuses to lower
+    # PartitionId in the scan carry for nothing
     my = lax.axis_index(axis) if causal else jnp.int32(0)
     b, h, s, d = q.shape
     dp = -(-d // F.LANE) * F.LANE

@@ -1,5 +1,5 @@
 """Tensor parallelism via pjit/NamedSharding — judged config 3: "BERT-base
-GLUE under ParameterServerStrategy → pjit param-sharded" (BASELINE.md).
+GLUE under ParameterServerStrategy → pjit param-sharded" (BASELINE.json).
 
 Reference context: ParameterServerStrategyV2
 (tensorflow/python/distribute/parameter_server_strategy_v2.py:77) shards

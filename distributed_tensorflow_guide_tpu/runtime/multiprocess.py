@@ -42,10 +42,7 @@ import json, os, sys, importlib
 spec = json.loads(os.environ["DTG_MP_SPEC"])
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-from distributed_tensorflow_guide_tpu.core import compat
-jax.config.update("jax_platforms", "cpu")
-compat.set_cpu_device_count(spec["local_devices"])
-compat.enable_cpu_cross_process_collectives()
+jax.config.update("jax_num_cpu_devices", spec["local_devices"])
 jax.distributed.initialize(
     spec["coordinator"],
     num_processes=spec["num_processes"],

@@ -16,7 +16,6 @@ numbers see benchmarks/bench_serving.py.
 from __future__ import annotations
 
 import argparse
-import os
 
 
 def main() -> None:
@@ -41,7 +40,8 @@ def main() -> None:
     ap.add_argument("--fleet", type=int, default=0, metavar="N",
                     help="serve through an N-replica FleetScheduler "
                          "(global admission/DRR/routing over N stock "
-                         "engines) instead of a single engine; watch "
+                         "engines, replica i on local device i mod the "
+                         "device count) instead of a single engine; watch "
                          "the per-replica healths and fleet counters")
     ap.add_argument("--fleet-roles", choices=["colocated", "disagg"],
                     default="colocated",
@@ -49,13 +49,13 @@ def main() -> None:
                          "roles and ships KV blocks at the phase flip")
     args = ap.parse_args()
 
-    # device env before any jax import (the dtg-lint pattern)
-    os.environ.setdefault("JAX_PLATFORMS", os.environ.get(
-        "JAX_PLATFORMS", ""))
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from distributed_tensorflow_guide_tpu.core.device import (
+        setup_compile_cache,
+    )
     from distributed_tensorflow_guide_tpu.models.transformer import (
         Transformer,
         TransformerConfig,
@@ -67,6 +67,7 @@ def main() -> None:
 
     import dataclasses
 
+    setup_compile_cache()
     cfg = TransformerConfig(vocab_size=256, num_layers=2, num_heads=2,
                             d_model=32, d_ff=64, max_len=64, causal=True,
                             dtype=jnp.float32)

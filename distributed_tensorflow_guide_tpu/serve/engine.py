@@ -88,6 +88,7 @@ from distributed_tensorflow_guide_tpu.serve.scheduler import (
     Request,
     Scheduler,
 )
+from distributed_tensorflow_guide_tpu.testing.chaos import ChaosInjectedError
 from distributed_tensorflow_guide_tpu.utils.watchdog import (
     Watchdog,
     WatchdogTimeout,
@@ -143,10 +144,25 @@ def paged_cache_shapes(pcfg: TransformerConfig, slots: int):
     return variables["cache"]
 
 
-def paged_cache_pool(pcfg: TransformerConfig, slots: int):
-    """Allocate the zeroed block pool."""
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                        paged_cache_shapes(pcfg, slots))
+def paged_cache_pool(pcfg: TransformerConfig, slots: int, device=None):
+    """Allocate the zeroed block pool, committed to ``device`` so that the
+    pool-only programs (spill gather/scatter) run there too; None leaves it
+    uncommitted where JAX's default puts it."""
+    with jax.default_device(device):
+        pool = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                            paged_cache_shapes(pcfg, slots))
+    return pool if device is None else jax.device_put(pool, device)
+
+
+def params_device(params):
+    """The one device every array leaf of ``params`` lives on, or None when
+    they span several (a mesh-sharded tree: placement is the sharding's) or
+    there are no device arrays to ask."""
+    devices = set()
+    for leaf in jax.tree.leaves(params):
+        if isinstance(leaf, jax.Array):
+            devices |= leaf.devices()
+    return next(iter(devices)) if len(devices) == 1 else None
 
 
 def adapter_bank_shapes(cfg: TransformerConfig):
@@ -399,6 +415,11 @@ class ServeEngine:
             block_size=block_size, prefill_chunk=prefill_chunk,
             temperature=temperature, top_k=top_k)
         self.params = params
+        # The engine lives where its weights live: the pool and the adapter
+        # bank are allocated on the params' device and the host operands of
+        # each tick go in as numpy, so every launch runs there. A fleet
+        # puts replicas on different chips by placing their params.
+        self.device = params_device(params)
         self.num_slots = slots
         # cache hierarchy (PR 16): host_blocks > 0 attaches a host-RAM
         # spill tier of that many blocks under the device pool —
@@ -446,16 +467,15 @@ class ServeEngine:
         if self.fns.lora:
             # the bank is a jit-operand (not a closed-over constant):
             # swapping adapter weights never retraces the two programs
-            self.adapters = jax.tree.map(
-                jnp.asarray,
+            self.adapters = jax.device_put(
                 adapters if adapters is not None
-                else init_adapter_bank(self.fns.cfg))
+                else init_adapter_bank(self.fns.cfg), self.device)
         elif adapters is not None:
             raise ValueError(
                 "ServeEngine(adapters=...) requires cfg.lora_rank")
         else:
             self.adapters = None
-        self.pool = paged_cache_pool(self.fns.cfg, slots)
+        self.pool = paged_cache_pool(self.fns.cfg, slots, self.device)
         self._trash_row = table_row(
             [], self.fns.n_blk, self.sched.pool.trash_block)
         if self.store is not None:
@@ -577,7 +597,7 @@ class ServeEngine:
         n = len(blocks)
         pad = -(-n // 8) * 8 - n
         trash = self.sched.pool.trash_block
-        idx = jnp.asarray(list(blocks) + [trash] * pad)
+        idx = np.asarray(list(blocks) + [trash] * pad, np.int32)
         stacked = [np.asarray(s) for s in _pool_gather(self.pool, idx)]
         return [[s[j].copy() for s in stacked] for j in range(n)]
 
@@ -601,10 +621,9 @@ class ServeEngine:
         n = len(blocks)
         pad = -(-n // 8) * 8 - n
         trash = self.sched.pool.trash_block
-        idx = jnp.asarray(list(blocks) + [trash] * pad)
-        rows = [jnp.asarray(np.stack(
-                    [np.asarray(p[i]) for p in payloads]
-                    + [np.asarray(payloads[0][i])] * pad))
+        idx = np.asarray(list(blocks) + [trash] * pad, np.int32)
+        rows = [np.stack([np.asarray(p[i]) for p in payloads]
+                         + [np.asarray(payloads[0][i])] * pad)
                 for i in range(len(payloads[0]))]
         self.pool = _pool_scatter(self.pool, idx, rows)
 
@@ -737,17 +756,17 @@ class ServeEngine:
         transient failure re-runs the SAME tick bitwise, because every
         launch input is rebuilt from host state and the sampling keys
         are position-derived. Injected chaos failures fire BEFORE the
-        program runs (the pool is untouched); a real failure that lands
-        mid-launch on a donating backend is not retriable in place (the
-        pool was donated) — that path recovers via snapshot restore, as
-        docs/serving.md spells out."""
+        program runs (the pool is untouched) and retry on every backend.
+        A real failure that lands mid-launch on a donating backend is NOT
+        retried: the pool was donated, so a second attempt could only
+        fail on the deleted buffer and bury the first error under its
+        own. It propagates as it is; that path recovers via snapshot
+        restore, as docs/serving.md spells out."""
 
         def attempt():
             try:
                 if self._injected_exc:
                     self._injected_exc -= 1
-                    from distributed_tensorflow_guide_tpu.testing.chaos \
-                        import ChaosInjectedError
                     raise ChaosInjectedError(
                         f"chaos: injected serve step exception ({tag})")
                 wd = self._watchdog
@@ -768,6 +787,8 @@ class ServeEngine:
         return retry_with_backoff(
             attempt, attempts=self.retry_attempts,
             base_delay_s=self.retry_base_delay_s, max_delay_s=1.0,
+            retry_on=((ChaosInjectedError,) if self.fns.donates_pool
+                      else (RuntimeError, OSError)),
             what=tag)
 
     def _run_prefill(self, i: int, now: float) -> list[Event]:
@@ -779,12 +800,13 @@ class ServeEngine:
         chunk[0, :valid] = s.prompt[start:start + valid]
         tables = table_row(s.blocks, self.fns.n_blk,
                            self.sched.pool.trash_block)[None]
-        args = (self.params, self.pool, jnp.asarray(tables),
-                jnp.full((1,), start, jnp.int32), jnp.asarray(chunk),
-                jnp.int32(valid), jnp.asarray(s.rng))
+        # host operands go in as numpy: the launch moves them straight to
+        # the device the committed params and pool are on
+        args = (self.params, self.pool, tables,
+                np.full((1,), start, np.int32), chunk,
+                np.int32(valid), np.asarray(s.rng, np.uint32))
         if self.fns.lora:
-            args += (self.adapters,
-                     jnp.full((1,), s.adapter, jnp.int32))
+            args += (self.adapters, np.full((1,), s.adapter, np.int32))
         if self.fns.moe:
             tok, self.pool, load, overflow = self._launch(
                 lambda: self.fns.prefill(*args),
@@ -813,11 +835,9 @@ class ServeEngine:
             last_tok[i] = s.pending
             keys[i] = s.rng
             adapter_ids[i] = s.adapter
-        args = (self.params, self.pool, jnp.asarray(tables),
-                jnp.asarray(written), jnp.asarray(last_tok),
-                jnp.asarray(keys))
+        args = (self.params, self.pool, tables, written, last_tok, keys)
         if self.fns.lora:
-            args += (self.adapters, jnp.asarray(adapter_ids))
+            args += (self.adapters, adapter_ids)
         if self.fns.moe:
             nxt, self.pool, of_tok, load, overflow = self._launch(
                 lambda: self.fns.decode(*args),

@@ -77,8 +77,10 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import time
 
+import jax
 import numpy as np
 
 from distributed_tensorflow_guide_tpu.obs import events as obs_events
@@ -92,7 +94,22 @@ from distributed_tensorflow_guide_tpu.serve.scheduler import Scheduler
 
 __all__ = ["FleetScheduler"]
 
+log = logging.getLogger("dtg.serve.fleet")
+
 ROLES = ("colocated", "prefill", "decode")
+
+
+def replica_devices(replicas: int, devices=None) -> list:
+    """Where each replica's params go: replica i on local chip i mod the
+    chip count, so N replicas on an N-chip host are N engines on N chips
+    and not N engines on the first. ``None`` leaves a tree where it is:
+    virtual CPU devices share one host's cores and every program compiles
+    once per device it runs on, so spreading over them buys nothing and
+    multiplies the compiles."""
+    devices = jax.local_devices() if devices is None else devices
+    if devices[0].platform == "cpu":
+        return [None] * replicas
+    return [devices[i % len(devices)] for i in range(replicas)]
 
 
 @dataclasses.dataclass
@@ -196,12 +213,16 @@ class FleetScheduler:
                 f"{replicas}")
         self.rec = (recorder if recorder is not None
                     else obs_events.current())
-        # params may be one tree shared by every replica, or a
-        # per-replica list — each replica anchored on its own DP×TP mesh
-        # (device_put with per-mesh shardings); the step programs are
-        # the same memoized objects either way
-        params_list = (list(params) if isinstance(params, (list, tuple))
-                       else [params] * replicas)
+        # An engine lives where its params live (ServeEngine.device): ONE
+        # tree is placed per replica by replica_devices(). A per-replica
+        # list is taken as placed by the caller — each replica anchored on
+        # its own DP×TP mesh (device_put with per-mesh shardings). The
+        # step programs are the same memoized objects either way.
+        if isinstance(params, (list, tuple)):
+            params_list = list(params)
+        else:
+            params_list = [params if d is None else jax.device_put(params, d)
+                           for d in replica_devices(replicas)]
         if len(params_list) != replicas:
             raise ValueError(
                 f"params list length {len(params_list)} != replicas "
@@ -299,6 +320,9 @@ class FleetScheduler:
         self.breaker_probes = 0
         self.breaker_recoveries = 0
         self.replica_faults = 0
+        # the first exception a replica's step let escape: the breaker
+        # recovers from faults, so whoever must know WHY reads it here
+        self.first_fault: Exception | None = None
         self.migration_dups_dropped = 0
         self.autoscale_added = 0
         self.autoscale_retired = 0
@@ -934,6 +958,10 @@ class FleetScheduler:
         ``retry_with_backoff`` convention at the step-boundary
         granularity."""
         self.replica_faults += 1
+        if self.first_fault is None:
+            self.first_fault = exc
+        log.warning("replica %d step failed at tick %d", i, tick,
+                    exc_info=exc)
         br = self._breaker[i]
         if self.rec.enabled:
             self.rec.emit(

@@ -86,7 +86,7 @@ class LoggingHook(BaseHook):
 class StepCounterHook(BaseHook):
     """steps/sec + examples/sec — the guide's only quantitative signal
     (tensorflow/python/training/basic_session_run_hooks.py:674), extended with
-    the BASELINE.md examples/sec/chip metric."""
+    the BASELINE.json examples/sec/chip metric."""
 
     def __init__(self, every_steps: int = 100, batch_size: int | None = None,
                  n_chips: int = 1):

@@ -71,12 +71,7 @@ def main() -> None:
     import jax
 
     if args.fake_devices:
-        jax.config.update("jax_platforms", "cpu")
-        from distributed_tensorflow_guide_tpu.core.compat import (
-            set_cpu_device_count,
-        )
-
-        set_cpu_device_count(args.fake_devices)
+        jax.config.update("jax_num_cpu_devices", args.fake_devices)
 
     import jax.numpy as jnp
     import numpy as np
@@ -138,8 +133,9 @@ def main() -> None:
         d_model=args.d_model, d_ff=4 * args.d_model,
         max_len=args.seq_len, causal=True, dtype=jnp.float32)
     model = Transformer(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, cfg.max_len), jnp.int32))["params"]
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0),
+        jnp.zeros((1, cfg.max_len), jnp.int32))["params"]
     state = dp.replicate(train_state.TrainState.create(
         apply_fn=model.apply, params=params, tx=optax.adam(args.lr)))
     step = dp.make_train_step(make_lm_loss_fn(model))

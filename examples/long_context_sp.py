@@ -43,7 +43,7 @@ def main() -> None:
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from distributed_tensorflow_guide_tpu.core.compat import shard_map
+    from jax import shard_map
     from distributed_tensorflow_guide_tpu.core.dist import initialize
     from distributed_tensorflow_guide_tpu.core.mesh import (
         MeshSpec,

@@ -61,12 +61,7 @@ def main() -> None:
     import jax
 
     if args.fake_devices:
-        jax.config.update("jax_platforms", "cpu")
-        from distributed_tensorflow_guide_tpu.core.compat import (
-            set_cpu_device_count,
-        )
-
-        set_cpu_device_count(args.fake_devices)
+        jax.config.update("jax_num_cpu_devices", args.fake_devices)
 
     import jax.numpy as jnp
     import optax
@@ -107,11 +102,10 @@ def main() -> None:
     model_cls = ResNet50 if args.model == "resnet50" else ResNet18ish
     model = model_cls(num_classes=args.num_classes, dtype=jnp.bfloat16)
 
-    variables = model.init(
-        jax.random.PRNGKey(0),
-        jnp.zeros((1, args.image_size, args.image_size, 3)),
-        train=False,
-    )
+    # one compiled init: op by op, each op is a compile of its own
+    variables = jax.jit(lambda rng: model.init(
+        rng, jnp.zeros((1, args.image_size, args.image_size, 3)),
+        train=False))(jax.random.PRNGKey(0))
     state = dp.replicate(TrainStateWithStats.create(
         apply_fn=model.apply,
         params=variables["params"],

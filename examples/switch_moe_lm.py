@@ -38,12 +38,7 @@ def main() -> None:
     import jax
 
     if args.fake_devices:
-        jax.config.update("jax_platforms", "cpu")
-        from distributed_tensorflow_guide_tpu.core.compat import (
-            set_cpu_device_count,
-        )
-
-        set_cpu_device_count(args.fake_devices)
+        jax.config.update("jax_num_cpu_devices", args.fake_devices)
     import jax.numpy as jnp
     import numpy as np
     import optax

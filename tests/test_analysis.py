@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distributed_tensorflow_guide_tpu.analysis import lint, walker
@@ -22,7 +23,6 @@ from distributed_tensorflow_guide_tpu.analysis.contracts import (
     ProgramContract,
     registered_contracts,
 )
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec, build_mesh
 
 

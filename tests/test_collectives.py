@@ -3,11 +3,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import distributed_tensorflow_guide_tpu.collectives as cc
-
-from distributed_tensorflow_guide_tpu.core.compat import shard_map  # noqa: E402
 
 
 def test_psum_matches_sum(mesh8):

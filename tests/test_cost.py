@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distributed_tensorflow_guide_tpu.analysis import cost, fingerprint, lint
@@ -20,7 +21,6 @@ from distributed_tensorflow_guide_tpu.analysis.contracts import (
     DonationSpec,
     ProgramContract,
 )
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec, build_mesh
 
 

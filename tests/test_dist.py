@@ -1,55 +1,12 @@
-"""Unit contract of core.dist.ensure_platform_from_env.
+"""Unit contract of core.dist.reinitialize (round-12 satellite).
 
-The subprocess-level behavior (a "CPU" example actually landing on CPU
-with the accelerator plugin registered) is covered by
-tests/test_examples.py::test_non_distributed_control_example; these pin
-the helper's error handling, which only manifests once a backend is live —
-exactly the state an in-process pytest run is in (conftest touched
-devices).
+The resize path: shutdown + initialize at the new world size, retried
+with backoff under its own env knobs (DTG_REINIT_RETRIES/_BACKOFF_S —
+mirroring the first-init pair). Pinned against a fake jax.distributed so
+no real coordinator is cycled inside the test process.
 """
 
-import jax
 import pytest
-
-from distributed_tensorflow_guide_tpu.core.dist import (
-    ensure_platform_from_env,
-)
-
-
-def test_noop_when_env_matches(monkeypatch, devices):
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setenv("JAX_NUM_CPU_DEVICES", str(len(devices)))
-    ensure_platform_from_env(strict=True)  # matching values: no update, no raise
-
-
-def test_strict_names_malformed_device_count(monkeypatch):
-    monkeypatch.setenv("JAX_NUM_CPU_DEVICES", "4,4")
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(ValueError, match="JAX_NUM_CPU_DEVICES"):
-        ensure_platform_from_env(strict=True)
-    ensure_platform_from_env(strict=False)  # best-effort swallows it
-
-
-def test_strict_raises_actionable_after_backend_live(monkeypatch, devices):
-    # the devices fixture guarantees a live CPU backend (required even when
-    # this test runs in isolation), so a conflicting request cannot be
-    # applied; strict mode must say what to do about it
-    n_live = len(devices)
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    # any count != the live one conflicts; derive it so the test tracks
-    # the fixture instead of hard-coding its device count
-    monkeypatch.setenv("JAX_NUM_CPU_DEVICES", str(n_live + 1))
-    with pytest.raises(RuntimeError, match="initialize\\(\\) must run"):
-        ensure_platform_from_env(strict=True)
-    ensure_platform_from_env(strict=False)  # best-effort degrades to a log
-    assert jax.device_count() == n_live  # nothing changed
-
-
-# ---- elastic reinitialize (round-12 satellite) ------------------------------
-# The resize path: shutdown + initialize at the new world size, retried
-# with backoff under its own env knobs (DTG_REINIT_RETRIES/_BACKOFF_S —
-# mirroring the first-init pair). Pinned against a fake jax.distributed so
-# no real coordinator is cycled inside the test process.
 
 
 class _FakeDistributed:

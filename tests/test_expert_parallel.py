@@ -5,8 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec, build_mesh
 from distributed_tensorflow_guide_tpu.parallel.expert import (
     ExpertParallel,

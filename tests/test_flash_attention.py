@@ -9,8 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.ops.attention import dense_attention
 from distributed_tensorflow_guide_tpu.ops.flash_attention import (
     flash_attention,

@@ -115,6 +115,42 @@ def test_fleet_matches_one_shot_bitwise(params, temp, top_k):
     fl.close()
 
 
+# ---- one replica, one chip (PR 21) -------------------------------------------
+
+
+def test_replica_devices_one_chip_each_and_cpu_left_alone():
+    from types import SimpleNamespace
+
+    from distributed_tensorflow_guide_tpu.serve.fleet import replica_devices
+
+    chips = [SimpleNamespace(platform="tpu", id=i) for i in range(4)]
+    assert replica_devices(4, chips) == chips
+    assert [d.id for d in replica_devices(6, chips)] == [0, 1, 2, 3, 0, 1]
+    # virtual CPU devices: one host's cores, a compile per device
+    assert replica_devices(3) == [None, None, None]
+
+
+def test_a_replica_lives_where_its_params_live(params, devices):
+    """Per-replica params placed on two different devices: each engine's
+    pool is allocated beside its weights and stays there through serving,
+    and the streams are the ones the one-shot path gives — this is what
+    puts four fleet replicas on four chips."""
+    placed = [jax.device_put(params, d) for d in devices[1:3]]
+    fl = _fleet(placed)
+    assert [e.device for e in fl.engines] == list(devices[1:3])
+    _submit_all(fl)
+    fl.run()
+    for eng, dev in zip(fl.engines, devices[1:3]):
+        assert eng.health()["completed"] >= 1
+        assert all(leaf.devices() == {dev}
+                   for leaf in jax.tree.leaves(eng.pool))
+    got = fl.completions()
+    for i in range(len(PROMPTS)):
+        assert got[i] == _oracle(CFG, params, i, 0.0, None), f"req {i}"
+    fl.check_leaks()
+    fl.close()
+
+
 @pytest.mark.parametrize("temp,top_k", [(0.0, None), (0.8, 10)],
                          ids=["greedy", "sampled"])
 def test_disagg_migration_is_bitwise(params, temp, top_k):

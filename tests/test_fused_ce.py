@@ -16,10 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distributed_tensorflow_guide_tpu.core import precision
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec, build_mesh
 from distributed_tensorflow_guide_tpu.ops import autotune
 from distributed_tensorflow_guide_tpu.ops import fused_ce as fce

@@ -122,11 +122,7 @@ def _target_fsdp_sharded_step(steps):
         apply_fn=None, params=params, tx=optax.sgd(0.1)
     )
     st_sh = fsdp.state_shardings(state, shardings)
-    from distributed_tensorflow_guide_tpu.core.compat import (
-        device_put_global,
-    )
-
-    state = device_put_global(state, st_sh)
+    state = jax.device_put(state, st_sh)
 
     rng = np.random.RandomState(1)
     gx = rng.randn(8, 8).astype(np.float32)

@@ -140,6 +140,44 @@ def test_open_record_loader_falls_back(record_file, monkeypatch):
     assert dl.next_batch()["label"].shape == (16,)
 
 
+def test_native_lib_is_keyed_by_what_the_source_says(tmp_path, monkeypatch):
+    """A copied checkout has new mtimes and the same code: same library.
+    An edit changes the key whatever the clock says."""
+    import hashlib
+    import os
+
+    import distributed_tensorflow_guide_tpu.data.native_loader as nl
+
+    src = tmp_path / "dataloader.cpp"
+    src.write_bytes(nl._SRC.read_bytes())
+    os.utime(src, (1, 1))
+    monkeypatch.setattr(nl, "_SRC", src)
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    built = tmp_path / f"dataloader_{digest}.so"
+    built.write_bytes(b"stands for the library built from this source")
+    assert nl._build_lib(tmp_path) == built  # found, not rebuilt
+    os.utime(src, (2, 2))
+    assert nl._build_lib(tmp_path) == built
+    assert built.read_bytes().startswith(b"stands for")
+
+
+@needs_native
+def test_a_loader_that_cannot_be_built_is_an_error(tmp_path, monkeypatch):
+    """With a toolchain, a build failure means the source is broken; the
+    Python twin must not paper over it. Without one, the twin is the
+    designed path."""
+    import distributed_tensorflow_guide_tpu.data.native_loader as nl
+
+    src = tmp_path / "dataloader.cpp"
+    src.write_text("this is not C++;\n")
+    monkeypatch.setattr(nl, "_SRC", src)
+    monkeypatch.setenv("DTG_NATIVE_CACHE", str(tmp_path / "cache"))
+    with pytest.raises(RuntimeError, match="failed to build"):
+        load_native_lib()
+    monkeypatch.setattr(nl.shutil, "which", lambda name: None)
+    assert load_native_lib() is None
+
+
 @needs_native
 def test_native_pooled_gather_large_records(tmp_path):
     # batch*record > 64KB exercises the persistent worker pool (small

@@ -26,6 +26,7 @@ import numpy as np
 import optax
 import pytest
 from flax.training import train_state
+from jax import shard_map
 
 import distributed_tensorflow_guide_tpu.collectives as cc
 from distributed_tensorflow_guide_tpu.analysis import cost as cost_mod
@@ -36,7 +37,6 @@ from distributed_tensorflow_guide_tpu.analysis.contracts import (
     ProgramContract,
 )
 from distributed_tensorflow_guide_tpu.core import precision
-from distributed_tensorflow_guide_tpu.core.compat import shard_map
 from distributed_tensorflow_guide_tpu.core.mesh import MeshSpec
 from distributed_tensorflow_guide_tpu.models.generation import (
     decode_cache_bytes_per_step,

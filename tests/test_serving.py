@@ -542,6 +542,36 @@ def test_step_exception_and_pool_pressure_storm_is_invisible(params):
     assert eng.live_blocks() == 0
 
 
+def test_real_failure_on_a_donated_pool_is_not_retried(params):
+    """Where the step programs donate the pool, a failure that lands
+    mid-launch has consumed it: a retry could only fail on the deleted
+    buffer and bury the first error. The FIRST exception propagates, once;
+    injected failures (raised before the program runs) still retry."""
+    import copy
+
+    sched = FaultSchedule([Fault("serve_step_exception", 1)])
+    eng = ServeEngine(CFG, params, slots=2, num_blocks=33, block_size=8,
+                      prefill_chunk=8, chaos=sched,
+                      retry_base_delay_s=0.001)
+    eng.fns = copy.copy(eng.fns)  # the memoized pair is shared: keep it so
+    eng.fns.donates_pool = True
+    _submit_all(eng)
+    for _ in range(4):  # tick 1's injected failure is retried and absorbed
+        eng.step(float("inf"))
+    assert eng.launch_failures == 1 and len(sched.fired) == 1
+
+    calls = []
+
+    def faulting(*args):
+        calls.append(len(args))
+        raise RuntimeError("first and only")
+
+    eng.fns.decode = eng.fns.prefill = faulting
+    with pytest.raises(RuntimeError, match="first and only"):
+        eng.step(float("inf"))
+    assert len(calls) == 1 and eng.launch_failures == 2
+
+
 def test_arrival_burst_and_client_abandon(params):
     """A burst-injected request streams to completion bitwise like any
     other; a client_abandon fault cancels a live rid whose delivered

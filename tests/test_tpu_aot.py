@@ -1,0 +1,245 @@
+"""The TPU compiler's verdict without a TPU.
+
+libtpu can describe a topology it is not attached to, and JAX compiles for
+it ahead of time: ``get_topology_desc("v5e:2x2")``, then
+``jit(f).trace(...).lower(lowering_platforms=("tpu",)).compile()``. So every
+Pallas kernel in ``ops/`` meets Mosaic — block shapes, layouts, the scoped
+VMEM limit — in tier-1 on the CPU, and a PR learns that a kernel no longer
+compiles before it spends chip time on it. Compiling is not executing: that
+the programs run, and give right answers, is ``chip_smoke.py``'s to show.
+
+On the CPU the package resolves five switches from ``jax.default_backend()``
+(Pallas interpret mode, the decode kernels, fused cross-entropy, donation,
+the autotune table). The ``as_on_tpu`` fixture gives them the values the chip
+gives them, so what compiles here is what the chip would be handed.
+
+The whole-program variants (GPT-2 124M train step, the engine's step pair)
+take tens of seconds of host compile and are marked ``slow``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+from distributed_tensorflow_guide_tpu.ops import flash_attention as FA
+
+# GPT-2 124M head shapes, as chip_smoke.py runs them
+B, H, S, HD = 2, 12, 1024, 64
+DP = FA.LANE  # head dim padded to a lane
+BLK = (128, 128)
+SCALE = 1.0 / HD ** 0.5
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described (not attached) v5e 2x2."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or one that cannot
+        pytest.skip(f"libtpu cannot describe a v5e topology here: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch, isolated_autotune_table):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not FA._interpret()
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def compile_for(sharding, fn, *args):
+    """Compile ``fn`` (a function, or a ``jax.jit`` of one with its own
+    donation) for the described TPU; ``args`` are abstract."""
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    jitted = fn if hasattr(fn, "trace") else jax.jit(fn)
+    return jitted.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
+
+def assert_mosaic(compiled) -> None:
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compiler_is_in_the_loop(v5e):
+    """The control: a kernel whose one block is 32 MiB of VMEM is refused,
+    so a kernel that compiles below was really judged."""
+    n = 4096 * 2048
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    def too_big(x):
+        return pl.pallas_call(
+            copy, out_shape=sds((4096, 2048), jnp.float32),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM))(x)
+
+    assert n * 4 == 32 << 20
+    with pytest.raises(Exception, match="(?i)vmem"):
+        compile_for(SingleDeviceSharding(v5e[0]), too_big,
+                    sds((4096, 2048), jnp.float32))
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "carry"])
+def test_flash_kernels_compile(v5e, as_on_tpu, kernel):
+    x = sds((B, H, S, DP), jnp.bfloat16)
+    row = sds((B, H, S, FA.LANE), jnp.float32)  # lse / delta / m / l
+    kw = dict(scale=SCALE, blk_q=BLK[0], blk_k=BLK[1])
+    fn, args = {
+        "fwd": (functools.partial(FA._fwd_call, causal=True, **kw),
+                (x, x, x)),
+        "dq": (functools.partial(FA._bwd_dq_call, causal=True, **kw),
+               (x, x, x, x, row, row)),
+        "dkv": (functools.partial(FA._bwd_dkv_call, causal=True, **kw),
+                (x, x, x, x, row, row)),
+        "carry": (functools.partial(FA.flash_carry_step, diag=True, **kw),
+                  (x, x, x, row, row, sds((B, H, S, DP), jnp.float32))),
+    }[kernel]
+    assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]), fn, *args))
+
+
+def test_flash_public_api_compiles_forward_and_backward(v5e, as_on_tpu):
+    q = sds((B, S, H, HD), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(FA.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]),
+                              jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
+    assert not FA.fallback_stats()
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+def test_decode_kernel_compiles(v5e, as_on_tpu, cache):
+    b = 8
+    q = sds((b, 1, H, HD), jnp.bfloat16)
+    kv = sds((b, H, S, HD), jnp.int8 if cache == "int8" else jnp.bfloat16)
+    scale = sds((b, H, 1, S), jnp.float32)
+
+    def fn(q, k, v, *scales):
+        ks, vs = scales or (None, None)
+        return DA.decode_attention(q, k, v, 700, key_scale=ks,
+                                   value_scale=vs)
+
+    args = (q, kv, kv) + ((scale, scale) if cache == "int8" else ())
+    assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]), fn, *args))
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+@pytest.mark.parametrize("block_size,chunk", [(8, 1), (16, 1), (16, 128),
+                                              (32, 1), (128, 1)])
+def test_paged_decode_kernel_compiles(v5e, as_on_tpu, cache, block_size,
+                                      chunk):
+    b = 8 if chunk == 1 else 1  # a decode step, or one prefill chunk
+    n_blk = S // block_size
+    q = sds((b, chunk, H, HD), jnp.bfloat16)
+    pool = sds((8 * n_blk + 1, H, block_size, HD),
+               jnp.int8 if cache == "int8" else jnp.bfloat16)
+    scale = sds((8 * n_blk + 1, H, 1, block_size), jnp.float32)
+
+    def fn(q, k, v, tables, lengths, *scales):
+        ks, vs = scales or (None, None)
+        return DA.paged_decode_attention(
+            q, k, v, tables, lengths, key_scale_pool=ks,
+            value_scale_pool=vs, block_size=block_size)
+
+    args = (q, pool, pool, sds((b, n_blk), jnp.int32), sds((b,), jnp.int32))
+    args += (scale, scale) if cache == "int8" else ()
+    assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]), fn, *args))
+
+
+# ---- whole programs, at chip_smoke.py's sizes -------------------------------
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gpt2_train_step_compiles(v5e, as_on_tpu, chips):
+    import optax
+    from flax.training import train_state
+
+    from distributed_tensorflow_guide_tpu.core.mesh import (
+        MeshSpec,
+        build_mesh,
+    )
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        Transformer,
+        gpt2_124m,
+        make_lm_loss_fn,
+    )
+    from distributed_tensorflow_guide_tpu.parallel.data_parallel import (
+        DataParallel,
+    )
+
+    cfg = gpt2_124m(dtype=jnp.bfloat16)
+    model = Transformer(cfg)
+    mesh = build_mesh(MeshSpec(data=-1), devices=v5e[:chips])
+    dp = DataParallel(mesh)
+    state = jax.eval_shape(
+        lambda key: train_state.TrainState.create(
+            apply_fn=model.apply, tx=optax.adamw(6e-4),
+            params=model.init(
+                key, jnp.zeros((1, cfg.max_len), jnp.int32))["params"]),
+        jax.random.PRNGKey(0))
+    step = dp.make_train_step(make_lm_loss_fn(model))
+    place = lambda tree, spec: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=NamedSharding(mesh, spec)),
+        tree)
+    batch = {"tokens": sds((8 * chips, cfg.max_len), jnp.int32)}
+    compiled = (step.trace(place(state, P()), place(batch, P("data")))
+                .lower(lowering_platforms=("tpu",)).compile())
+    assert_mosaic(compiled)
+    # fits one chip's 16 GB with room for the serving pool that follows
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+    assert not FA.fallback_stats()
+
+
+@pytest.mark.slow
+def test_serve_step_pair_compiles(v5e, as_on_tpu):
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        Transformer,
+        gpt2_124m,
+    )
+    from distributed_tensorflow_guide_tpu.serve.engine import (
+        build_step_fns,
+        paged_cache_shapes,
+    )
+
+    cfg = gpt2_124m(dtype=jnp.bfloat16)
+    slots, block_size, chunk = 8, 16, 128
+    num_blocks = slots * (cfg.max_len // block_size) + 1
+    fns = build_step_fns(cfg, slots=slots, num_blocks=num_blocks,
+                         block_size=block_size, prefill_chunk=chunk)
+    assert fns.donates_pool
+    params = jax.eval_shape(
+        Transformer(cfg).init, jax.random.PRNGKey(0),
+        jnp.zeros((1, cfg.max_len), jnp.int32))["params"]
+    pool = paged_cache_shapes(fns.cfg, slots)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    one = SingleDeviceSharding(v5e[0])
+    decode = compile_for(
+        one, fns.decode, params, pool, i32(slots, fns.n_blk), i32(slots),
+        i32(slots), sds((slots, 2), jnp.uint32))
+    prefill = compile_for(
+        one, fns.prefill, params, pool, i32(1, fns.n_blk), i32(1),
+        i32(1, chunk), i32(), sds((2,), jnp.uint32))
+    assert_mosaic(decode)
+    assert_mosaic(prefill)  # a 128-token chunk still takes the kernel
+    assert not FA.fallback_stats()
