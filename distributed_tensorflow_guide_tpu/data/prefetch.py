@@ -23,7 +23,9 @@ Two composable pieces:
   with ``stacked_batch=True``).
 * :class:`DevicePrefetchIterator` — the double/triple-buffered device
   placement stage, with :class:`PrefetchStats` accounting so the overlap is
-  *measured*, not asserted.
+  *measured*, not asserted, and the spans ``prefetch.host_fetch`` and
+  ``prefetch.put`` (obs/tracing.span) so a profiler session sees each
+  fetch and each put beside the device's own events.
 
 Donation safety: every batch becomes a FRESH device allocation (a
 ``device_put`` result); the iterator drops its own reference before the
@@ -39,6 +41,9 @@ from collections import deque
 from typing import Any, Callable, Iterable, Iterator
 
 import numpy as np
+
+from distributed_tensorflow_guide_tpu.obs import events as obs_events
+from distributed_tensorflow_guide_tpu.obs.tracing import span
 
 
 @dataclasses.dataclass
@@ -143,6 +148,7 @@ class DevicePrefetchIterator:
         self.depth = depth
         self.max_host_wait_s = max_host_wait_s
         self.stats = PrefetchStats()
+        self._rec = obs_events.current()  # resolved once, as everywhere
         if put_fn is not None:
             self._put = put_fn
         else:
@@ -159,7 +165,8 @@ class DevicePrefetchIterator:
         while len(self._buf) < self.depth and not self._exhausted:
             t0 = time.perf_counter()
             try:
-                host_batch = next(self._src)
+                with span(self._rec, "prefetch.host_fetch"):
+                    host_batch = next(self._src)
             except StopIteration:
                 self._exhausted = True
                 return
@@ -183,7 +190,8 @@ class DevicePrefetchIterator:
                     f"{self.max_host_wait_s:g}s "
                     f"(after {self.stats.batches} batches)"
                 )
-            self._buf.append(self._put(host_batch))
+            with span(self._rec, "prefetch.put"):
+                self._buf.append(self._put(host_batch))
             t2 = time.perf_counter()
             self.stats.put_s += t2 - t1
             self.stats.peak_ahead = max(self.stats.peak_ahead,

@@ -1,12 +1,21 @@
 """Span API + Chrome/Perfetto trace-event JSON exporter.
 
-Spans are just paired events (``span.begin`` / ``span.end``) in the same
-flight-recorder stream — no second bookkeeping path. The exporter maps
-the serve engine's event vocabulary onto the Chrome trace-event format
+:func:`span` is the one way the program marks a span, and it writes to two
+sinks. Always: an annotation of ``jax.profiler`` named ``dtg.<name>``, so
+that whenever a profiler session runs (``jax.profiler.start_trace``,
+``utils.profiling.ProfilerHook``, the benchmark's ``--trace 1``) the span
+lies on the host plane of the same ``.xplane.pb`` as the device's ``XLA
+Modules`` and ``XLA Ops`` lines, on their clock. The session is the switch:
+with none running the annotation records nothing. And, when the recorder
+is enabled, paired events (``span.begin`` / ``span.end``) in the same
+flight-recorder stream as everything else, with no second bookkeeping
+path. The exporter maps the serve engine's event vocabulary onto the
+Chrome trace-event format
 (`chrome://tracing` / https://ui.perfetto.dev, "Open trace file"):
 
 * ``span.begin`` / ``span.end``   -> ``B``/``E`` duration events on the
-  track named in the payload (train data-wait / dispatch timelines).
+  track named in the payload (the train loop's data-wait / dispatch /
+  hooks timeline, the engine's tick phases).
 * ``prefill.launch`` / ``decode.launch`` -> ``X`` complete events on one
   track per engine slot (``slot0``, ``slot1``, ...), so a request reads
   as queued -> admitted -> prefill chunk(s) -> decode on its slot lane.
@@ -16,8 +25,9 @@ the serve engine's event vocabulary onto the Chrome trace-event format
   terminals, prefix hits/evictions, chaos faults, snapshots, ...).
 
 Timestamps: the exporter prefers the semantic clock ``t`` (the engine's
-virtual ``now``) and falls back to ``mono`` when ``t`` is None (train
-spans). Events whose resolved timestamp is non-finite are skipped —
+virtual ``now``) and falls back to ``mono`` when ``t`` is None (every
+span: a span measures the host, so it carries the wall clock). Events
+whose resolved timestamp is non-finite are skipped —
 ``ServeEngine.run()`` drains with ``now=inf``, which is meaningful to
 the scheduler but not to a timeline. ``pid`` is the event category,
 ``tid`` the track; both are stable small integers with ``M`` metadata
@@ -28,27 +38,52 @@ from __future__ import annotations
 
 import json
 import math
-from contextlib import contextmanager
 from typing import Iterable
 
 from distributed_tensorflow_guide_tpu.obs.events import ObsEvent
 
 
-@contextmanager
-def span(rec, name: str, *, track: str = "main", cat: str = "train",
-         actor: str = ""):
-    """Emit ``span.begin``/``span.end`` around a block. Payload carries
-    the (name, track) pair the exporter turns into a B/E lane."""
-    if not rec.enabled:
-        yield
-        return
-    rec.emit("span.begin", cat=cat, actor=actor,
-             payload={"name": name, "track": track})
-    try:
-        yield
-    finally:
-        rec.emit("span.end", cat=cat, actor=actor,
-                 payload={"name": name, "track": track})
+_annotation = None  # bound by the first span(): obs/ imports without jax
+
+
+class span:
+    """Mark a ``with`` block as the span ``name`` (dotted: ``engine.build``).
+
+    The profiler sees ``dtg.<name>`` with ``attrs`` as the event's stats
+    whenever a session runs; the recorder, when enabled, gets
+    ``span.begin`` (payload: name, track, ``attrs``) and ``span.end``.
+    Track and actor are the name's first part, so one component's spans
+    share a lane. ``attrs`` are small values already at hand: they are
+    evaluated whether or not anything listens. Spans nest; the ones of a
+    tick or a step share its ``tick`` / ``step``. A class and not a
+    generator under ``contextmanager``, which alone costs more than both
+    sinks do when nothing listens."""
+
+    __slots__ = ("_profiled", "_rec", "_cat", "_name", "_attrs")
+
+    def __init__(self, rec, name: str, *, cat: str = "train", **attrs):
+        global _annotation
+        if _annotation is None:
+            import jax
+
+            _annotation = jax.profiler.TraceAnnotation
+        self._profiled = _annotation("dtg." + name, **attrs)
+        self._rec, self._cat, self._name, self._attrs = rec, cat, name, attrs
+
+    def _emit(self, kind: str, attrs: dict) -> None:
+        track = self._name.partition(".")[0]
+        self._rec.emit(kind, cat=self._cat, actor=track,
+                       payload={"name": self._name, "track": track, **attrs})
+
+    def __enter__(self) -> None:
+        self._profiled.__enter__()
+        if self._rec.enabled:
+            self._emit("span.begin", self._attrs)
+
+    def __exit__(self, *exc) -> None:
+        if self._rec.enabled:
+            self._emit("span.end", {})
+        self._profiled.__exit__(*exc)
 
 
 def _fields(e) -> tuple[str, str, str, float | None, float, dict]:

@@ -76,13 +76,13 @@ from distributed_tensorflow_guide_tpu.models.transformer import (
     TransformerConfig,
 )
 from distributed_tensorflow_guide_tpu.obs import events as obs_events
+from distributed_tensorflow_guide_tpu.obs.tracing import span
 from distributed_tensorflow_guide_tpu.serve.paged_cache import (
     BlockStore,
     table_row,
 )
 from distributed_tensorflow_guide_tpu.serve.prefix_index import CACHE_RID
 from distributed_tensorflow_guide_tpu.serve.scheduler import (
-    DECODE,
     PREFILL,
     EngineOverloaded,
     Request,
@@ -667,60 +667,98 @@ class ServeEngine:
         (cancellations / deadlines), admit arrived requests, launch (at
         most) one program, apply its results. Returns (events, kind)
         with kind in {"prefill", "decode", "idle"} — the bench times
-        this call to get per-launch service time."""
+        this call to get per-launch service time.
+
+        The tick is one span, ``engine.tick``, and its phases five more
+        under it (obs/tracing.span: on the profiler's clock whenever a
+        session runs, in the recorder when it is enabled): ``schedule``
+        up to the plan, ``build`` the launch's host operands,
+        ``dispatch`` the jitted call until it returns, ``fetch`` the host
+        blocked until the device hands the tokens back, ``apply`` from
+        the scheduler's bookkeeping to the lifecycle events."""
         tick = self._tick
         self._tick += 1
-        rec = self.rec
-        if rec.enabled:
-            self.sched.now = now  # timestamps scheduler decisions
-        if self.chaos is not None:
-            if rec.enabled:
-                self.chaos.recorder = rec
-                self.chaos.obs_now = now
-            self._apply_chaos(tick, now)
-        self._release_pressure(tick)
-        events = [Event(now, *t) for t in self.sched.sweep(now)]
-        if self.store is not None:
-            # prefetch ahead of schedule: queued spilled continuations'
-            # h2d copies land NOW, before this tick's launch, so a
-            # swap-in resume at a later admit finds its blocks already
-            # on device instead of serializing the copies with it
-            self.sched.prefetch()
-        self.sched.admit(now)
-        kind, arg = self.sched.plan()
-        launch = None
-        if rec.enabled and kind != "idle":
-            # capture launch identity BEFORE the program runs: apply_*
-            # frees a slot the moment its request completes
+        rec, sd = self.rec, self.sched
+        with span(rec, "engine.tick", cat="serve", tick=tick):
+            with span(rec, "engine.schedule", cat="serve", tick=tick):
+                if rec.enabled:
+                    sd.now = now  # timestamps scheduler decisions
+                if self.chaos is not None:
+                    if rec.enabled:
+                        self.chaos.recorder = rec
+                        self.chaos.obs_now = now
+                    self._apply_chaos(tick, now)
+                self._release_pressure(tick)
+                events = [Event(now, *t) for t in sd.sweep(now)]
+                if self.store is not None:
+                    # prefetch ahead of schedule: queued spilled
+                    # continuations' h2d copies land NOW, before this
+                    # tick's launch, so a swap-in resume at a later admit
+                    # finds its blocks already on device instead of
+                    # serializing the copies with it
+                    sd.prefetch()
+                sd.admit(now)
+                kind, arg = sd.plan()
+            if kind == "idle":
+                self.last_tick_s = 0.0
+                self.steps[kind] += 1
+                if rec.enabled and events:
+                    self._emit_lifecycle(events, now, tick)
+                return events, kind
             if kind == PREFILL:
-                s = self.sched.slots[arg]
-                launch = {"slot": arg, "rid": s.rid,
-                          "chunk": s.chunk_cursor}
+                slot = sd.slots[arg]
+                rows, fn, program = 1, self.fns.prefill, "prefill_chunk_step"
+                ids = {"tick": tick, "rid": slot.rid}
             else:
-                launch = {"slots": list(arg),
-                          "rids": [self.sched.slots[i].rid for i in arg]}
-        t0 = time.perf_counter()
-        if kind == PREFILL:
-            events.extend(self._run_prefill(arg, now))
-        elif kind == DECODE:
-            events.extend(self._run_decode(arg, now))
-        self.last_tick_s = time.perf_counter() - t0
-        self.steps[kind] += 1
-        if launch is not None:
-            launch["tick"] = tick
-            launch["dur_s"] = self.last_tick_s
-            rec.emit(f"{kind}.launch", cat="serve", actor="engine",
-                     payload=launch, t=now)
-        for e in events:
-            if e.first and e.status == "ok":
-                arrival = self.sched.meta.get(e.rid, (now, None, None))[0]
-                ttft = max(0.0, now - arrival)
-                if np.isfinite(ttft):
-                    self._ttft_ewma = (
-                        ttft if self._ttft_ewma is None
-                        else 0.8 * self._ttft_ewma + 0.2 * ttft)
-        if rec.enabled and events:
-            self._emit_lifecycle(events, now, tick)
+                rows, fn, program = len(arg), self.fns.decode, "decode_step"
+                ids = {"tick": tick}
+            launch = None
+            if rec.enabled:
+                # launch identity, taken BEFORE the program runs: apply_*
+                # frees a slot the moment its request completes
+                launch = ({"slot": arg, "rid": slot.rid,
+                           "chunk": slot.chunk_cursor} if kind == PREFILL
+                          else {"slots": list(arg),
+                                "rids": [sd.slots[i].rid for i in arg]})
+            t0 = time.perf_counter()
+            with span(rec, "engine.build", cat="serve", kind=kind,
+                      rows=rows, **ids):
+                args = (self._prefill_operands(arg) if kind == PREFILL
+                        else self._decode_operands(arg))
+            with span(rec, "engine.dispatch", cat="serve", program=program,
+                      **ids):
+                toks, self.pool, *moe = self._launch(
+                    lambda: fn(*args), tag="serve_" + program)
+            with span(rec, "engine.fetch", cat="serve", **ids):
+                toks = np.asarray(toks)
+                if moe:  # ([overflowed slots,] expert load, overflow)
+                    moe = [np.asarray(x) for x in moe]
+                    self._moe_load += moe[-2].astype(np.int64)
+                    self._moe_overflow += moe[-1].astype(np.int64)
+            with span(rec, "engine.apply", cat="serve", **ids):
+                if kind == PREFILL:
+                    produced = sd.apply_prefill(arg, int(toks))
+                else:
+                    produced = self._apply_decode(
+                        arg, toks, moe[0] if moe else None)
+                events.extend(Event(now, *ev) for ev in produced)
+                self.last_tick_s = time.perf_counter() - t0
+                self.steps[kind] += 1
+                if launch is not None:
+                    launch["tick"] = tick
+                    launch["dur_s"] = self.last_tick_s
+                    rec.emit(f"{kind}.launch", cat="serve", actor="engine",
+                             payload=launch, t=now)
+                for e in events:
+                    if e.first and e.status == "ok":
+                        arrival = sd.meta.get(e.rid, (now, None, None))[0]
+                        ttft = max(0.0, now - arrival)
+                        if np.isfinite(ttft):
+                            self._ttft_ewma = (
+                                ttft if self._ttft_ewma is None
+                                else 0.8 * self._ttft_ewma + 0.2 * ttft)
+                if rec.enabled and events:
+                    self._emit_lifecycle(events, now, tick)
         return events, kind
 
     def _emit_lifecycle(self, events: list[Event], now: float,
@@ -791,7 +829,9 @@ class ServeEngine:
                       else (RuntimeError, OSError)),
             what=tag)
 
-    def _run_prefill(self, i: int, now: float) -> list[Event]:
+    def _prefill_operands(self, i: int) -> tuple:
+        """The next chunk of slot ``i``'s prompt as the prefill program's
+        arguments."""
         s = self.sched.slots[i]
         CH = self.sched.prefill_chunk
         start = s.chunk_cursor * CH
@@ -807,20 +847,11 @@ class ServeEngine:
                 np.int32(valid), np.asarray(s.rng, np.uint32))
         if self.fns.lora:
             args += (self.adapters, np.full((1,), s.adapter, np.int32))
-        if self.fns.moe:
-            tok, self.pool, load, overflow = self._launch(
-                lambda: self.fns.prefill(*args),
-                tag="serve_prefill_chunk_step")
-            self._moe_load += np.asarray(load).astype(np.int64)
-            self._moe_overflow += np.asarray(overflow).astype(np.int64)
-        else:
-            tok, self.pool = self._launch(
-                lambda: self.fns.prefill(*args),
-                tag="serve_prefill_chunk_step")
-        return [Event(now, *ev) for ev in
-                self.sched.apply_prefill(i, int(tok))]
+        return args
 
-    def _run_decode(self, ready: list[int], now: float) -> list[Event]:
+    def _decode_operands(self, ready: list[int]) -> tuple:
+        """One decode step over the ``ready`` slots as the decode
+        program's arguments; every other row reads the trash block."""
         S, n_blk = self.num_slots, self.fns.n_blk
         tables = np.tile(self._trash_row, (S, 1))
         written = np.zeros((S,), np.int32)
@@ -838,43 +869,28 @@ class ServeEngine:
         args = (self.params, self.pool, tables, written, last_tok, keys)
         if self.fns.lora:
             args += (self.adapters, adapter_ids)
-        if self.fns.moe:
-            nxt, self.pool, of_tok, load, overflow = self._launch(
-                lambda: self.fns.decode(*args),
-                tag="serve_decode_step")
-            self._moe_load += np.asarray(load).astype(np.int64)
-            self._moe_overflow += np.asarray(overflow).astype(np.int64)
-            of = np.asarray(of_tok)
-            nxt = np.asarray(nxt)
-            events = []
-            stalled = 0
-            for i in ready:
-                if of[i]:
-                    # degrade-to-overflow: the slot's sampled token came
-                    # from a forward that skipped its expert at some
-                    # layer — discard it and leave pending/written
-                    # untouched, so the SAME token retries next tick
-                    # (cache rewrites are idempotent; dispatch fills in
-                    # slot order, so the lowest contending slot always
-                    # advances). A hot expert costs goodput, never a
-                    # dropped or corrupted token.
-                    stalled += 1
-                    continue
-                events.extend(Event(now, *ev) for ev in
-                              self.sched.apply_decode(i, int(nxt[i])))
-            if stalled:
-                self._moe_stall_slot_ticks += stalled
-                self._moe_stall_ticks += 1
-            return events
-        nxt, self.pool = self._launch(
-            lambda: self.fns.decode(*args),
-            tag="serve_decode_step")
-        nxt = np.asarray(nxt)
-        events = []
+        return args
+
+    def _apply_decode(self, ready: list[int], nxt, overflowed) -> list:
+        """Hand the scheduler each ready slot's sampled token.
+        ``overflowed`` (MoE only) flags the slots whose token came from a
+        forward that skipped its expert at some layer."""
+        produced, stalled = [], 0
         for i in ready:
-            events.extend(Event(now, *ev) for ev in
-                          self.sched.apply_decode(i, int(nxt[i])))
-        return events
+            if overflowed is not None and overflowed[i]:
+                # degrade-to-overflow: discard the token and leave
+                # pending/written untouched, so the SAME token retries
+                # next tick (cache rewrites are idempotent; dispatch fills
+                # in slot order, so the lowest contending slot always
+                # advances). A hot expert costs goodput, never a dropped
+                # or corrupted token.
+                stalled += 1
+                continue
+            produced.extend(self.sched.apply_decode(i, int(nxt[i])))
+        if stalled:
+            self._moe_stall_slot_ticks += stalled
+            self._moe_stall_ticks += 1
+        return produced
 
     # ---- chaos application (testing.chaos serve kinds) -------------------
 

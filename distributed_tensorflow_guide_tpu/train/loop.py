@@ -33,6 +33,7 @@ import logging
 from typing import Any, Callable, Iterable, Iterator, Sequence
 
 from distributed_tensorflow_guide_tpu.obs import events as obs_events
+from distributed_tensorflow_guide_tpu.obs.tracing import span
 from distributed_tensorflow_guide_tpu.train.hooks import Hook
 
 log = logging.getLogger("dtg.train")
@@ -108,11 +109,11 @@ class TrainLoop:
         self._stop = False
         self.stop_reason: str | None = None
         self._last_return: float | None = None
-        # observability (PR 14): observe-only — span.begin/span.end
-        # instants around data-wait and dispatch (the trace exporter's
-        # per-step train timeline). Resolved once; every emission is
-        # behind one ``enabled`` attribute check, and nothing recorded
-        # ever feeds the compiled step (50-step bitwise parity pinned).
+        # observability: observe-only spans (obs/tracing.span) around the
+        # data wait, the dispatch and the hooks of every step: on the
+        # profiler's clock whenever a session runs, and in this recorder
+        # when it is enabled. Resolved once; nothing recorded ever feeds
+        # the compiled step (50-step bitwise parity pinned).
         self.rec = recorder if recorder is not None else obs_events.current()
         from distributed_tensorflow_guide_tpu.utils.profiling import (
             DispatchStats,
@@ -146,15 +147,18 @@ class TrainLoop:
         t0 = time.perf_counter()
         if self._last_return is not None:
             self.dispatch_stats.host_gap_s += t0 - self._last_return
-        self.state, metrics = step_fn(self.state, batch)
+        with span(self.rec, "loop.dispatch", step=self.step):
+            self.state, metrics = step_fn(self.state, batch)
         self._last_return = time.perf_counter()
         self.dispatch_stats.dispatch_s += self._last_return - t0
         self.dispatch_stats.dispatches += 1
         return metrics
 
     def _after_step(self, metrics) -> None:
-        for h in self.hooks:
-            h.after_step(self.step, metrics)
+        # where a hook that reads a metric's value waits for the device
+        with span(self.rec, "loop.hooks", step=self.step):
+            for h in self.hooks:
+                h.after_step(self.step, metrics)
         self.step += 1
         self.dispatch_stats.steps += 1
 
@@ -238,41 +242,22 @@ class TrainLoop:
                 while not self._stop:
                     if wd and self.data_deadline_s:
                         wd.arm("data iterator", self.data_deadline_s)
-                    if rec.enabled:
-                        rec.emit("span.begin", cat="train", actor="loop",
-                                 payload={"name": "data_wait",
-                                          "track": "loop",
-                                          "step": self.step})
                     try:
-                        batch = next(it)
+                        with span(rec, "loop.data_wait", step=self.step):
+                            batch = next(it)
                     except StopIteration:
                         break
                     finally:
-                        if rec.enabled:
-                            rec.emit("span.end", cat="train", actor="loop",
-                                     payload={"name": "data_wait",
-                                              "track": "loop"})
                         if wd:
                             wd.disarm()
                             wd.check()
                     if wd and self.step_deadline_s:
                         wd.arm("train step", self.step_deadline_s)
-                    if rec.enabled:
-                        rec.emit("span.begin", cat="train", actor="loop",
-                                 payload={"name": "dispatch",
-                                          "track": "loop",
-                                          "step": self.step})
-                    try:
-                        if self.steps_per_call > 1:
-                            self._run_packed(batch)
-                        else:
-                            self._after_step(
-                                self._dispatch(self.step_fn, batch))
-                    finally:
-                        if rec.enabled:
-                            rec.emit("span.end", cat="train", actor="loop",
-                                     payload={"name": "dispatch",
-                                              "track": "loop"})
+                    if self.steps_per_call > 1:
+                        self._run_packed(batch)
+                    else:
+                        self._after_step(
+                            self._dispatch(self.step_fn, batch))
                     if wd:
                         wd.disarm()
                         wd.check()

@@ -7,14 +7,13 @@ TensorBoard/XProf. This module is a thin, dependency-free veneer:
 
 * :func:`trace` — context manager around ``jax.profiler.trace`` (start/stop
   a trace into a logdir).
-* :func:`annotate` — host-side span annotation (``jax.profiler.TraceAnnotation``),
-  shows up as a named region on the host timeline.
-* :func:`step_annotation` — marks one training step so XProf's step-time
-  analysis can segment the timeline (``StepTraceAnnotation``).
-* :func:`save_memory_profile` — dump a device-memory profile (pprof format).
 * :class:`ProfilerHook` — train-loop hook that traces steps
   ``[start_step, end_step)``; the TF sibling is ``tf.train.ProfilerHook``
   (tensorflow/python/training/basic_session_run_hooks.py).
+
+Named host spans are ``obs/tracing.span``'s business: the program's own
+spans (``dtg.loop.*``, ``dtg.prefetch.*``, ``dtg.engine.*``) land in any
+trace started here, on the device events' clock.
 """
 
 from __future__ import annotations
@@ -103,23 +102,6 @@ def trace(logdir: str | Path, *, create_perfetto_link: bool = False) -> Iterator
     with jax.profiler.trace(logdir, create_perfetto_link=create_perfetto_link):
         yield
     log.info("profiler trace written to %s", logdir)
-
-
-def annotate(name: str, **kwargs):
-    """Named host-side span; nests. Use around data loading, checkpointing,
-    eval — anything host-bound worth seeing on the trace timeline."""
-    return jax.profiler.TraceAnnotation(name, **kwargs)
-
-
-def step_annotation(step: int, name: str = "train"):
-    """Mark one step for XProf step-time analysis."""
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
-
-
-def save_memory_profile(path: str | Path) -> None:
-    """Dump current device memory usage as a pprof profile."""
-    Path(path).parent.mkdir(parents=True, exist_ok=True)
-    jax.profiler.save_device_memory_profile(str(path))
 
 
 class ProfilerHook(BaseHook):

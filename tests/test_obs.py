@@ -337,15 +337,17 @@ def test_train_loop_bitwise_parity_and_spans():
     on = TrainLoop(step, jnp.ones((4,)), data(), hooks=hooks(),
                    recorder=rec).run()
     np.testing.assert_array_equal(np.asarray(off), np.asarray(on))
-    kinds = [e.kind for e in rec.events()]
-    assert kinds.count("span.begin") == kinds.count("span.end") == 100
-    names = [e.payload["name"] for e in rec.events()
-             if e.kind == "span.begin"]
-    assert names.count("data_wait") == names.count("dispatch") == 50
-    steps = [e.payload["step"] for e in rec.events()
-             if e.kind == "span.begin" and
-             e.payload["name"] == "dispatch"]
-    assert steps == list(range(50))
+    begun = [e.payload for e in rec.events() if e.kind == "span.begin"]
+    ended = [e.payload["name"] for e in rec.events()
+             if e.kind == "span.end"]
+    # one of each a step, and the iterator's last, empty, next() never
+    # happens: StopAtStepHook stops the loop before it
+    for name in ("loop.data_wait", "loop.dispatch", "loop.hooks"):
+        assert [p["step"] for p in begun if p["name"] == name] == list(
+            range(50)), name
+        assert ended.count(name) == 50
+    assert len(begun) == len(ended) == 150
+    assert {p["track"] for p in begun} == {"loop"}
 
 
 def test_metrics_hook_and_tb_roundtrip(tmp_path):
@@ -371,6 +373,148 @@ def test_metrics_hook_and_tb_roundtrip(tmp_path):
     assert rows and rows[-1][1]["dtg_train_steps_total"] == 20.0
     assert any("dtg_train_metric_loss" in scalars
                for _, scalars in rows)
+
+
+# ---- the program's spans on the profiler's clock ----------------------------
+
+ENGINE_PHASES = ["engine.schedule", "engine.build", "engine.dispatch",
+                 "engine.fetch", "engine.apply"]
+PROGRAM_SPANS = ["engine.tick", *ENGINE_PHASES, "loop.data_wait",
+                 "loop.dispatch", "loop.hooks", "prefetch.host_fetch",
+                 "prefetch.put"]
+
+
+@jax.jit
+def _toy_step(state, batch):
+    new = state - 0.01 * (2 * state + batch)
+    return new, {"loss": jnp.sum(state ** 2)}
+
+
+def _toy_loop():
+    from distributed_tensorflow_guide_tpu.data.prefetch import (
+        DevicePrefetchIterator,
+    )
+
+    feed = DevicePrefetchIterator(
+        (np.full((4,), float(s), np.float32) for s in range(10_000)))
+    return TrainLoop(_toy_step, jnp.ones((4,)), feed,
+                     hooks=[StopAtStepHook(5)])
+
+
+@pytest.fixture(scope="module")
+def profiled(params, tmp_path_factory):
+    """A tiny engine driven to its end and a 5-step ``TrainLoop`` once with
+    no profiler session and once inside one; the session's ``dtg.`` host
+    events as (name, start_ns, end_ns, stats), in order of start."""
+    plain = _engine(CFG, params)
+    _drive(plain)
+    plain_state = np.asarray(_toy_loop().run())
+    engine_programs = len(serve_engine._STEP_FNS)
+    train_programs = _toy_step._cache_size()
+
+    logdir = tmp_path_factory.mktemp("profile")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the program's own spans, not Python's
+    jax.profiler.start_trace(str(logdir), profiler_options=options)
+    try:
+        eng = _engine(CFG, params)
+        _drive(eng)
+        state = np.asarray(_toy_loop().run())
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = logdir.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = sorted(
+        ((e.name[len("dtg."):], e.start_ns, e.start_ns + e.duration_ns,
+          dict(e.stats))
+         for plane in data.planes for line in plane.lines
+         for e in line.events if e.name.startswith("dtg.")),
+        key=lambda r: (r[1], -r[2]))
+    return SimpleNamespace(
+        spans=spans, same_tokens=eng.completions() == plain.completions(),
+        same_state=np.array_equal(state, plain_state),
+        ticks=eng._tick, launches=eng.steps["prefill"] + eng.steps["decode"],
+        new_programs=(len(serve_engine._STEP_FNS) - engine_programs,
+                      _toy_step._cache_size() - train_programs))
+
+
+@pytest.mark.parametrize("name", PROGRAM_SPANS)
+def test_span_lands_in_the_profilers_trace(profiled, name):
+    found = [r for r in profiled.spans if r[0] == name]
+    want = {"engine.tick": profiled.ticks, "engine.schedule": profiled.ticks,
+            "loop.data_wait": 5, "loop.dispatch": 5, "loop.hooks": 5,
+            # depth 2: two batches before the first step, one after each
+            "prefetch.host_fetch": 7, "prefetch.put": 7}
+    assert len(found) == want.get(name, profiled.launches)
+
+
+def test_tick_phases_nest_in_their_tick_and_do_not_overlap(profiled):
+    ticks = {r[3]["tick"]: r for r in profiled.spans if r[0] == "engine.tick"}
+    assert sorted(ticks) == list(range(profiled.ticks))
+    by_tick: dict = {}
+    for r in profiled.spans:
+        if r[0] in ENGINE_PHASES:
+            by_tick.setdefault(r[3]["tick"], []).append(r)
+    launched = 0
+    for tick, (_, t_start, t_end, stats) in ticks.items():
+        phases = by_tick[tick]
+        names = [r[0] for r in phases]
+        assert names in (ENGINE_PHASES, ENGINE_PHASES[:1]), (tick, names)
+        launched += names == ENGINE_PHASES
+        assert t_start <= phases[0][1] and phases[-1][2] <= t_end
+        for before, after in zip(phases, phases[1:]):
+            assert before[2] <= after[1], (tick, before[0], after[0])
+        assert stats == {"tick": tick}
+    assert launched == profiled.launches
+
+
+def test_span_attrs_read_back_from_the_events_stats(profiled):
+    builds = [r[3] for r in profiled.spans if r[0] == "engine.build"]
+    prefills = [b for b in builds if b["kind"] == "prefill"]
+    decodes = [b for b in builds if b["kind"] == "decode"]
+    assert len(prefills) + len(decodes) == len(builds)
+    assert {b["rid"] for b in prefills} == {0, 1, 2}
+    assert all(b["rows"] == 1 for b in prefills)
+    assert decodes and all(
+        1 <= b["rows"] <= 2 and "rid" not in b for b in decodes)
+    programs = {r[3]["program"] for r in profiled.spans
+                if r[0] == "engine.dispatch"}
+    assert programs == {"prefill_chunk_step", "decode_step"}
+    for name in ("loop.data_wait", "loop.dispatch", "loop.hooks"):
+        assert [r[3]["step"] for r in profiled.spans
+                if r[0] == name] == list(range(5)), name
+    # a put lies inside the data wait that asked for it
+    waits = [r for r in profiled.spans if r[0] == "loop.data_wait"]
+    for r in (r for r in profiled.spans if r[0] == "prefetch.put"):
+        assert any(w[1] <= r[1] and r[2] <= w[2] for w in waits)
+
+
+def test_a_profiler_session_is_invisible_to_the_program(profiled):
+    assert profiled.same_tokens and profiled.same_state
+    assert profiled.new_programs == (0, 0)
+
+
+def test_span_feeds_recorder_and_profiler_alike():
+    """Recorder on: the pair of events with the attrs in ``span.begin``;
+    recorder off: nothing, and the block still runs; an exception leaves
+    through both sinks."""
+    rec = obs_events.FlightRecorder()
+    with pytest.raises(KeyError):
+        with obs_trace.span(rec, "engine.build", cat="serve", tick=3,
+                            kind="decode"):
+            raise KeyError("x")
+    begin, end = rec.events()
+    assert (begin.kind, end.kind) == ("span.begin", "span.end")
+    assert begin.cat == end.cat == "serve"
+    assert begin.actor == end.actor == "engine"
+    assert begin.payload == {"name": "engine.build", "track": "engine",
+                             "tick": 3, "kind": "decode"}
+    assert end.payload == {"name": "engine.build", "track": "engine"}
+    assert begin.t is None and end.mono >= begin.mono
+    ran = []
+    with obs_trace.span(obs_events.NULL_RECORDER, "loop.hooks", step=1):
+        ran.append(1)
+    assert ran == [1] and len(rec.events()) == 2
 
 
 # ---- black boxes: watchdog trip + seeded chaos storm ------------------------

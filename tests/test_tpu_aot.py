@@ -120,9 +120,12 @@ def test_flash_public_api_compiles_forward_and_backward(v5e, as_on_tpu):
         return jnp.sum(FA.flash_attention(q, k, v, causal=True)
                        .astype(jnp.float32))
 
+    # the counts are the process's: other files' tests in this worker may
+    # have fallen back already, so compare with what stood before
+    before = FA.fallback_stats()
     assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]),
                               jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
-    assert not FA.fallback_stats()
+    assert FA.fallback_stats() == before
 
 
 @pytest.mark.parametrize("cache", ["bf16", "int8"])
