@@ -10,9 +10,12 @@ module removes the hardcode:
 
 * a **persistent tuning table** keyed on (kernel, shape, dtype, platform):
   ``blocks_for`` is what call sites ask (never sweeps, never writes — the
-  tested ``DEFAULT_BLOCKS`` fallback on a miss); ``ensure_tuned`` sweeps
-  the candidate grid ON CHIP and records the winner (exact-shape entry
-  plus a batch/head-generic one, so one capture serves nearby batches);
+  ``DEFAULT_BLOCKS`` fallback on a miss, which is tested for correctness
+  and is no tuned choice: see ``resolution_stats``); ``ensure_tuned``
+  sweeps the candidate grid ON CHIP and records the winner (exact-shape
+  entry plus a batch/head-generic one, so one capture serves nearby
+  batches). The table git tracks (``autotune_table_v1.json``) holds the
+  v5e sweep at (8, 16, 1024, 64, bfloat16, causal);
 * a **per-kernel sweep harness**: the four kernels (forward, dq, dkv,
   ring carry-step) are measured SEPARATELY — their arithmetic
   intensities differ (2/3/4 MXU passes per block pair), so one shared
@@ -120,10 +123,25 @@ LANE = 128  # TPU lane width; block edges must be sublane (8) multiples
 # and the VMEM working-set budget below.
 CANDIDATE_EDGES = (64, 128, 256, 512, 1024)
 
-# Per-grid-cell VMEM working-set budget. ~16 MB/core physically; half of it
-# keeps headroom for Mosaic's own temporaries and the double-buffered
-# pipeline the estimate already models.
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# VMEM. A v5e TensorCore has 128 MiB of it; a Mosaic kernel gets what its
+# scoped limit allows, 16 MiB unless the call's compiler parameters carry a
+# ``vmem_limit_bytes`` (no call in ops/ does: asking the four flash calls
+# for 32 MiB cost the gpt2-medium train step 33 MB more of HBM, PR 27). The
+# filter below charges a candidate its modelled per-grid-cell working set
+# (kernel_vmem_bytes): the flash kernels against the whole scoped limit,
+# their model being held to the compiler's verdicts; the decode kernels
+# against half of it, the other half headroom for what their model does not
+# see. The model is an estimate either way, so the sweep lets a candidate
+# that the compiler refuses cost that candidate alone.
+DEFAULT_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+VMEM_BUDGET_BYTES = DEFAULT_SCOPED_VMEM_BYTES // 2
+
+
+def vmem_budget_bytes(kernel: str) -> int:
+    """What the candidate filter lets one grid cell of ``kernel`` use."""
+    if kernel in (DECODE_KERNEL, PAGED_DECODE_KERNEL):
+        return VMEM_BUDGET_BYTES
+    return DEFAULT_SCOPED_VMEM_BYTES
 
 
 class FlashBlocks(NamedTuple):
@@ -195,6 +213,7 @@ def reset() -> None:
     global _loaded_from, _online_override, _online_spent_s
     with _lock:
         _mem.clear()
+        _resolved.clear()
         _loaded_from = None
         _online_override = None
         _online_attempted.clear()
@@ -226,25 +245,53 @@ def _valid(blocks: tuple[int, int], s: int) -> bool:
             and s % bq == 0 and s % bk == 0)
 
 
-def lookup(kernel: str, *, b: int, h: int, s: int, d: int, dtype,
-           causal: bool = True,
-           platform: str | None = None) -> tuple[int, int] | None:
-    """Tuned (blk_q, blk_k) for the key, or None. Tries the exact shape,
-    then the batch/head-generic entry the sweep also records. Entries that
-    no longer divide the shape are ignored (stale-table safety)."""
+def _resolve(kernel: str, *, b: int, h: int, s: int, d: int, dtype,
+             causal: bool = True, platform: str | None = None
+             ) -> tuple[tuple[int, int] | None, str, str]:
+    """(tuned blocks or None, where they came from, the exact key). Tries
+    the exact shape ("table"), then the batch/head-generic entry the sweep
+    also records ("generic"); a miss is (None, "default", key). Entries
+    that no longer divide the shape are ignored (stale-table safety)."""
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r} (one of {KERNELS})")
     plat = _platform(platform)
     _maybe_load(plat)
     dt = _dtype_name(dtype)
-    for key in (_key(kernel, b, h, s, d, dt, causal, plat),
-                _key(kernel, 0, 0, s, d, dt, causal, plat)):
+    exact = _key(kernel, b, h, s, d, dt, causal, plat)
+    for source, key in (("table", exact),
+                        ("generic", _key(kernel, 0, 0, s, d, dt, causal,
+                                         plat))):
         ent = _mem.get(key)
         if ent:
             blocks = (int(ent["blk_q"]), int(ent["blk_k"]))
             if _valid(blocks, s):
-                return blocks
-    return None
+                return blocks, source, exact
+    return None, "default", exact
+
+
+def lookup(kernel: str, *, b: int, h: int, s: int, d: int, dtype,
+           causal: bool = True,
+           platform: str | None = None) -> tuple[int, int] | None:
+    """Tuned (blk_q, blk_k) for the key, or None (see :func:`_resolve`)."""
+    return _resolve(kernel, b=b, h=h, s=s, d=d, dtype=dtype, causal=causal,
+                    platform=platform)[0]
+
+
+# A call site that silently misses the table runs the 128x128 default, many
+# times slower at long sequences on the chip, and nothing else shows it.
+# Every resolution is kept, and logged when a key first resolves or resolves
+# differently; benchmarks and tests read resolution_stats(), as they read
+# flash_attention.fallback_stats() for fallbacks.
+_resolved: dict[tuple[str, str], dict] = {}
+
+
+def resolution_stats() -> dict[tuple[str, str], dict]:
+    """(kernel, exact key) -> ``{"blocks": (blk_q, blk_k), "source": s}``
+    for every key :func:`blocks_for` has resolved in this process: ``s`` is
+    "table" (the exact shape's entry), "generic" (the batch/head-generic
+    entry) or "default" (a miss: ``DEFAULT_BLOCKS``)."""
+    with _lock:
+        return {k: dict(v) for k, v in _resolved.items()}
 
 
 def blocks_for(kernel: str, *, b: int, h: int, s: int, d: int, dtype,
@@ -253,9 +300,20 @@ def blocks_for(kernel: str, *, b: int, h: int, s: int, d: int, dtype,
     """The block sizes a call site should use: the tuned entry when one
     exists, else ``DEFAULT_BLOCKS``. Never sweeps, never writes — safe at
     trace time on any platform."""
-    hit = lookup(kernel, b=b, h=h, s=s, d=d, dtype=dtype, causal=causal,
-                 platform=platform)
-    return hit if hit is not None else DEFAULT_BLOCKS
+    hit, source, key = _resolve(kernel, b=b, h=h, s=s, d=d, dtype=dtype,
+                                causal=causal, platform=platform)
+    blocks = hit if hit is not None else DEFAULT_BLOCKS
+    seen = {"blocks": blocks, "source": source}
+    with _lock:
+        news = _resolved.get((kernel, key)) != seen
+        _resolved[(kernel, key)] = seen
+    if news:
+        # a miss off the CPU (where a miss is the contract) is worth a
+        # warning: the sweep has not seen this shape on this chip
+        missed = source == "default" and not key.endswith("|cpu")
+        log.log(logging.WARNING if missed else logging.INFO,
+                "autotune: %s -> %dx%d (%s)", key, *blocks, source)
+    return blocks
 
 
 def record(kernel: str, *, b: int, h: int, s: int, d: int, dtype,
@@ -615,31 +673,43 @@ def kernel_hbm_bytes(kernel: str, *, b: int, h: int, s: int, d: int,
 def kernel_vmem_bytes(kernel: str, blk_q: int, blk_k: int, dp: int,
                       dtype) -> int:
     """Per-grid-cell VMEM working set: in/out tiles (double-buffered by the
-    Pallas pipeline, hence x2) + f32 scratch + the (blk_q, blk_k) f32
-    score/probability temporaries the kernel body materializes (s and p
-    for fwd/carry; s, p, dp and ds for the backward kernels — the
-    DOMINANT term at large blocks). Used to filter sweep candidates."""
+    Pallas pipeline, hence x2) + f32 scratch + the (blk_q, blk_k)
+    temporaries the kernel body keeps whole: the float32 scores out of the
+    MXU, and what goes back into it in the operands' dtype (p for
+    fwd/carry; p and ds for the backward kernels). What lies between them
+    is elementwise and Mosaic does not keep it whole. Used to filter sweep
+    candidates.
+
+    Held to the compiler (PR 27; the v5e's, ahead of time, at the default
+    scoped limit). At s=1024 and padded head dim 128 every tile up to
+    1024 x 1024 compiles for all four kernels in both dtypes; this model
+    admits all of them in bfloat16 and, in float32, all but the 1024 x 1024
+    of dq, dkv and carry (18.5-21 MiB modelled). At s=2048, edges 512 to
+    2048, padded head dims 128 and 256, both dtypes, it admits 61 of 144
+    and the compiler refuses 4 of those, all at head dim 256 (13-15.5 MiB
+    modelled); it filters 8 that compile. The budget it is compared with
+    is :func:`vmem_budget_bytes`."""
     import numpy as np
 
     io = np.dtype(dtype).itemsize
     q_t, k_t, l_t = blk_q * dp, blk_k * dp, blk_q * LANE
-    score = blk_q * blk_k * 4
+    score = blk_q * blk_k
     if kernel == "flash_fwd":
         tiles = (2 * q_t + 2 * k_t) * io + l_t * 4
         scratch = (2 * l_t + q_t) * 4
-        body = 2 * score
+        body = score * (4 + io)
     elif kernel == "carry_step":
         tiles = (q_t + 2 * k_t) * io + 2 * (2 * l_t + q_t) * 4
         scratch = (2 * l_t + q_t) * 4
-        body = 2 * score
+        body = score * (4 + io)
     elif kernel == "flash_dq":
         tiles = (3 * q_t + 2 * k_t) * io + 2 * l_t * 4
         scratch = q_t * 4
-        body = 4 * score
+        body = score * (4 + 2 * io)
     elif kernel == "flash_dkv":
         tiles = (2 * q_t + 4 * k_t) * io + 2 * l_t * 4
         scratch = 2 * k_t * 4
-        body = 4 * score
+        body = score * (4 + 2 * io)
     elif kernel in ("decode_attend", "decode_paged"):
         # q tile + K/V cache tiles (at the CACHE dtype — int8 is what makes
         # the big edges affordable) + the two (1, blk_k) f32 scale rows;
@@ -663,19 +733,19 @@ def candidate_blocks(kernel: str, *, s: int, d: int,
     sequence and fit the VMEM budget. The decode kernel only sweeps the KV
     edge (its Q edge is the fixed sublane-padded token chunk)."""
     dp = padded_head_dim(d)
+    budget = vmem_budget_bytes(kernel)
     edges = [e for e in CANDIDATE_EDGES if e <= s and s % e == 0]
     if kernel in (DECODE_KERNEL, PAGED_DECODE_KERNEL):
         bq = DECODE_CHUNK_SUBLANES
         return [
             (bq, bk) for bk in edges
             if s % bq == 0
-            and kernel_vmem_bytes(kernel, bq, bk, dp,
-                                  dtype) <= VMEM_BUDGET_BYTES
+            and kernel_vmem_bytes(kernel, bq, bk, dp, dtype) <= budget
         ]
     return [
         (bq, bk)
         for bq in edges for bk in edges
-        if kernel_vmem_bytes(kernel, bq, bk, dp, dtype) <= VMEM_BUDGET_BYTES
+        if kernel_vmem_bytes(kernel, bq, bk, dp, dtype) <= budget
     ]
 
 
@@ -759,22 +829,23 @@ def make_kernel_runner(kernel: str, blocks: tuple[int, int], *, b: int,
 
 def measure_runner(fn: Callable[[], object], *, iters: int = 20,
                    warmup: int = 2) -> float:
-    """Seconds per call; the timed region is closed by a value fetch of
-    the last call's first output (the calls run in order on one device)."""
+    """Seconds per call; the timed region is closed by ``block_until_ready``
+    on the last call's outputs (the calls run in order on one device, and
+    the fence is a real one on the chip: PERF.md, PR 21). Not by fetching a
+    value: a flash output is tens of MB, and bringing it to the host added
+    25-50 ms to every measurement, 0.5-1 ms a call at 50 calls (PR 27)."""
     import time
 
     import jax
-    import numpy as np
 
     out = None
     for _ in range(max(1, warmup)):
         out = fn()
     jax.block_until_ready(out)
-    np.asarray(jax.tree.leaves(out)[0])
     t0 = time.perf_counter()
     for _ in range(iters):
         out = fn()
-    np.asarray(jax.tree.leaves(out)[0])
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters
 
 

@@ -21,9 +21,22 @@ no-ops through q·kᵀ and the p·v contraction, and are sliced off on return.
 
 Block sizes are NOT hardcoded: each kernel (fwd, dq, dkv, and the ring
 carry step) resolves its own (blk_q, blk_k) from the autotune table
-(ops/autotune.py — swept on chip by ``bench_flash_kernel.py --tune``,
-tested 128x128 default on a miss; explicit ``blk_q``/``blk_k`` arguments
-pin it, which is what the parity tests and the sweep itself use).
+(ops/autotune.py; the tracked ``autotune_table_v1.json`` holds what
+``bench_flash_kernel.py --tune`` measured on a v5e). A shape the table has
+not seen gets 128x128, which is tested and slow: at (8, 16, 1024, 64) on a
+v5e it is 8,192 grid steps of one MXU tile's work each, 3.4-4.4 ms a call
+where the tuned tile, the whole 1024x1024 square, takes 0.57-0.75
+(PERF.md, PR 27). ``autotune.resolution_stats()`` says which a call got.
+Explicit ``blk_q``/``blk_k`` arguments pin it, which is what the parity
+tests and the sweep itself use.
+
+Precision: the products take q, k, v and do in the dtype they arrive in and
+accumulate in float32 (``preferred_element_type``); the probabilities and
+their gradients are cast to that dtype right before the second products.
+Everything between the products (scale, mask, max, exp, sums, ``lse``,
+``delta``, the scratch accumulators) is float32. So bfloat16 inputs get
+single-pass MXU products, and float32 inputs get float32 arithmetic
+throughout.
 
 On CPU (tests, dryrun) the same kernels run via ``interpret=True``.
 """
@@ -75,10 +88,9 @@ def _masked_scores(q_ref, k_ref, i, j, *, scale, causal, blk_q, blk_k):
     The single definition shared by forward and both backward kernels so the
     recomputed probabilities can never drift from the forward pass.
     """
-    q = q_ref[0, 0].astype(jnp.float32)  # (blk_q, Dp)
-    k = k_ref[0, 0].astype(jnp.float32)  # (blk_k, Dp)
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q_ref[0, 0], k_ref[0, 0],  # (blk_q, Dp) x (blk_k, Dp)
+        (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale  # (blk_q, blk_k)
     if causal:
@@ -108,7 +120,7 @@ def _softmax_update(m_scr, l_scr, acc_scr, s, v, *, masked: bool):
         p = jnp.where(s <= NEG_INF / 2, 0.0, p)
     l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -138,10 +150,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(should_run)
     def _():
-        v = v_ref[0, 0].astype(jnp.float32)
         s = _masked_scores(q_ref, k_ref, i, j, scale=scale, causal=causal,
                            blk_q=blk_q, blk_k=blk_k)
-        _softmax_update(m_scr, l_scr, acc_scr, s, v, masked=causal)
+        _softmax_update(m_scr, l_scr, acc_scr, s, v_ref[0, 0], masked=causal)
 
     @pl.when(j == n_kv - 1)
     def _():
@@ -227,10 +238,9 @@ def _carry_fwd_kernel(q_ref, k_ref, v_ref, m_in, l_in, acc_in,
 
     @pl.when(should_run)
     def _():
-        v = v_ref[0, 0].astype(jnp.float32)
         s = _masked_scores(q_ref, k_ref, i, j, scale=scale, causal=diag,
                            blk_q=blk_q, blk_k=blk_k)
-        _softmax_update(m_scr, l_scr, acc_scr, s, v, masked=diag)
+        _softmax_update(m_scr, l_scr, acc_scr, s, v_ref[0, 0], masked=diag)
 
     @pl.when(j == n_kv - 1)
     def _():
@@ -320,9 +330,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(should_run)
     def _():
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]  # (blk_q, 1)
         delta = delta_ref[0, 0][:, :1]
         s = _masked_scores(q_ref, k_ref, i, j, scale=scale, causal=causal,
@@ -334,7 +344,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         )  # (blk_q, blk_k)
         ds = p * (dp - delta) * scale
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -361,16 +371,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(should_run)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]
         delta = delta_ref[0, 0][:, :1]
         s = _masked_scores(q_ref, k_ref, i, j, scale=scale, causal=causal,
                            blk_q=blk_q, blk_k=blk_k)
         p = jnp.exp(s - lse)  # (blk_q, blk_k)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # pᵀ·dO → (blk_k, Dp)
         dp = jax.lax.dot_general(
@@ -379,7 +389,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
         ds = p * (dp - delta) * scale  # (blk_q, blk_k)
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # dsᵀ·q → (blk_k, Dp)
 
@@ -639,7 +649,7 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_blocks(b: int, h: int, s: int, d: int, dtype,
                  causal: bool = True) -> FlashBlocks:
     """Per-kernel tuned blocks for one flash call shape — each of the three
-    kernels consults its OWN autotune entry (tested default: 128x128).
+    kernels consults its OWN autotune entry (128x128 on a miss).
 
     With :func:`carry_blocks` and :func:`bwd_blocks`, these helpers are
     the ONLY lookup paths — key construction (logical head dim, dtype,
@@ -726,8 +736,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     excluded). Differentiable via hand-written backward kernels.
 
     Block sizes: by default each of the three kernels (fwd, dq, dkv) takes
-    its own entry from the autotune table (ops/autotune.py; tested default
-    fallback 128x128). Passing ``blk_q``/``blk_k`` pins ALL kernels to that
+    its own entry from the autotune table (ops/autotune.py; 128x128 on a
+    miss). Passing ``blk_q``/``blk_k`` pins ALL kernels to that
     one pair — the override the parity tests and the sweep use.
     """
     b, s, hn, d = q.shape

@@ -125,7 +125,7 @@ def test_candidates_all_valid_and_within_vmem_budget():
                 assert s % bq == 0 and s % bk == 0 and bq % 8 == 0
                 assert autotune.kernel_vmem_bytes(
                     kern, bq, bk, 128, jnp.bfloat16
-                ) <= autotune.VMEM_BUDGET_BYTES
+                ) <= autotune.vmem_budget_bytes(kern)
 
 
 def test_roofline_models_sanity():
@@ -272,3 +272,84 @@ def test_cpu_flash_trace_structure_via_walker():
     assert census["dot_general"] >= 2  # qk^T and pv
     assert census["pallas_call"] == 0
     assert not walker.collective_census(jaxpr)
+
+
+# ---- the tracked table and the resolution counter (PR 27) ------------------
+
+V5E = "tpu:tpu-v5-lite"
+TRAIN_CELL = dict(b=8, h=16, s=1024, d=64, dtype=jnp.bfloat16)  # gpt2-medium
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+@pytest.fixture()
+def tracked_table(monkeypatch):
+    """The table git tracks, read as a v5e reads it."""
+    monkeypatch.delenv("DTG_AUTOTUNE_TABLE")
+    autotune.reset()
+    assert autotune.table_path() == autotune.TRACKED_TABLE
+    return json.loads(autotune.TRACKED_TABLE.read_text())
+
+
+@pytest.mark.parametrize("kernel", FLASH_KERNELS)
+@pytest.mark.parametrize("shape", ["exact", "generic"])
+def test_tracked_table_holds_the_train_cells_flash_entries(
+        tracked_table, kernel, shape):
+    """The sweep's winners at (8, 16, 1024, 64, bfloat16, causal) on a v5e
+    are committed, under the exact key and the batch/head-generic one, and
+    each is a tile the sweep may pick."""
+    kw = dict(TRAIN_CELL, causal=True, platform=V5E)
+    if shape == "generic":
+        kw.update(b=3, h=5)  # only the b0|h0 entry can serve this
+    b, h = (kw["b"], kw["h"]) if shape == "exact" else (0, 0)
+    assert autotune._key(kernel, b, h, 1024, 64, "bfloat16", True,
+                         V5E) in tracked_table
+    blocks = autotune.lookup(kernel, **kw)
+    assert blocks is not None
+    assert 1024 % blocks[0] == 0 and 1024 % blocks[1] == 0
+    assert blocks in autotune.candidate_blocks(kernel, s=1024, d=64,
+                                               dtype=jnp.bfloat16)
+    assert blocks != autotune.DEFAULT_BLOCKS  # or the sweep bought nothing
+
+
+def test_tracked_table_is_not_read_on_the_cpu(tracked_table):
+    """Even with no $DTG_AUTOTUNE_TABLE in the way, the platform the tests
+    run on resolves the train cell's shape to the default."""
+    assert tracked_table  # parsed, and not empty
+    for kernel in FLASH_KERNELS:
+        assert autotune.blocks_for(
+            kernel, **TRAIN_CELL) == autotune.DEFAULT_BLOCKS
+    assert autotune._loaded_from is None
+
+
+def test_resolution_stats_tell_table_generic_and_default(caplog):
+    import logging
+
+    kw = dict(s=256, d=64, dtype=jnp.float32, platform="tpu")
+    autotune.record("flash_fwd", b=2, h=4, blocks=(64, 128), **kw)
+    with caplog.at_level(logging.INFO, logger="dtg.ops.autotune"):
+        assert autotune.blocks_for("flash_fwd", b=2, h=4, **kw) == (64, 128)
+        assert autotune.blocks_for("flash_fwd", b=1, h=1, **kw) == (64, 128)
+        assert autotune.blocks_for(
+            "flash_dq", b=2, h=4, **kw) == autotune.DEFAULT_BLOCKS
+        autotune.blocks_for("flash_fwd", b=2, h=4, **kw)  # seen: no new line
+
+    def key(kernel, b, h):
+        return kernel, autotune._key(kernel, b, h, 256, 64, "float32", True,
+                                     "tpu")
+
+    assert autotune.resolution_stats() == {
+        key("flash_fwd", 2, 4): {"blocks": (64, 128), "source": "table"},
+        key("flash_fwd", 1, 1): {"blocks": (64, 128), "source": "generic"},
+        key("flash_dq", 2, 4): {"blocks": (128, 128), "source": "default"},
+    }
+    lines = [(r.levelname, r.getMessage()) for r in caplog.records]
+    assert [lv for lv, _ in lines] == ["INFO", "INFO", "WARNING"]
+    assert all(s in m for (_, m), s in zip(
+        lines, ("(table)", "(generic)", "(default)")))
+    # a key that resolves differently later (a sweep recorded it) says so
+    autotune.record("flash_dq", b=2, h=4, blocks=(256, 64), **kw)
+    autotune.blocks_for("flash_dq", b=2, h=4, **kw)
+    assert autotune.resolution_stats()[key("flash_dq", 2, 4)] == {
+        "blocks": (256, 64), "source": "table"}
+    autotune.reset()
+    assert autotune.resolution_stats() == {}

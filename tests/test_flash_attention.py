@@ -24,26 +24,65 @@ def _qkv(b=2, s=256, h=2, d=64, seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
+# Large tiles at the train cell's sequence length, pinned, in both input
+# dtypes: 1024 x 1024 is what the v5e sweep picked for every kernel
+# (ops/autotune_table_v1.json), 512 x 512 and 256 x 512 the best that keep
+# more than one grid step a head, so the causal skip and the carried (m, l,
+# acc) are exercised too. bfloat16 operands go into the products as they
+# arrive (p and ds rounded to bfloat16 before the second products), float32
+# operands keep float32 arithmetic throughout.
+# (id, _qkv's arguments and the pin, forward atol, gradient atol)
+LARGE_TILES = [
+    (f"s1024-{bq}x{bk}-{name}",
+     dict(s=1024, b=1, blk_q=bq, blk_k=bk, dtype=dt), fwd_atol, grad_atol)
+    for bq, bk in ((512, 512), (256, 512), (1024, 1024))
+    for name, dt, fwd_atol, grad_atol in (
+        ("float32", jnp.float32, 1e-4, 1e-3),
+        ("bfloat16", jnp.bfloat16, 8e-2, 6e-2))
+]
+FORWARD_CASES = [pytest.param({}, 2e-2, id="default")] + [
+    pytest.param(kw, atol, id=name) for name, kw, atol, _ in LARGE_TILES]
+GRADIENT_CASES = [pytest.param(dict(s=128, h=1), 2e-2, id="default")] + [
+    pytest.param(kw, atol, id=name) for name, kw, _, atol in LARGE_TILES]
+
+
+def _case(kw):
+    """(q, k, v, the pinned tiles) of one parity case."""
+    kw = dict(kw)
+    pin = {n: kw.pop(n) for n in ("blk_q", "blk_k") if n in kw}
+    return *_qkv(**kw), pin
+
+
+def _f32(x):
+    return np.asarray(x, np.float32)
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_dense(causal):
-    q, k, v = _qkv()
-    out = flash_attention(q, k, v, causal=causal)
+@pytest.mark.parametrize("kw, atol", FORWARD_CASES)
+def test_forward_matches_dense(causal, kw, atol):
+    q, k, v, pin = _case(kw)
+    out = flash_attention(q, k, v, causal=causal, **pin)
+    assert out.dtype == q.dtype
     ref = dense_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(_f32(out), _f32(ref), atol=atol, rtol=atol)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_dense(causal):
-    q, k, v = _qkv(s=128, h=1)
+@pytest.mark.parametrize("kw, atol", GRADIENT_CASES)
+def test_gradients_match_dense(causal, kw, atol):
+    q, k, v, pin = _case(kw)
 
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=causal) ** 2)
+    def loss(fn, **pin):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=causal, **pin).astype(jnp.float32) ** 2)
 
-    g_flash = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.grad(loss(flash_attention, **pin),
+                       argnums=(0, 1, 2))(q, k, v)
     g_dense = jax.grad(loss(dense_attention), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_flash, g_dense):
-        scale = float(jnp.max(jnp.abs(b))) + 1e-6
-        np.testing.assert_allclose(a / scale, b / scale, atol=2e-2)
+        a, b = _f32(a), _f32(b)
+        scale = np.abs(b).max() + 1e-6
+        np.testing.assert_allclose(a / scale, b / scale, atol=atol)
 
 
 def test_head_dim_padding():
@@ -139,6 +178,29 @@ def test_forward_f32_tight_tolerance():
         out = flash_attention(q, k, v, causal=causal)
         ref = dense_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
+
+
+def test_float32_inputs_keep_the_parents_arithmetic_bit_for_bit():
+    """Operands go into the products in the dtype they arrive in, so float32
+    callers get float32 products and nothing of the bfloat16 path: the
+    forward output and the three gradients at 128 x 128 are, bit for bit,
+    what the kernels gave when every operand was upcast before each product
+    (commit 2dd3816, the parent of PR 27, same seed, interpret mode)."""
+    import hashlib
+
+    rng = np.random.RandomState(27)
+    q, k, v = (jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32)
+               for _ in range(3))
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True, blk_q=128, blk_k=128)
+
+    grads = jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) ** 2),
+                     argnums=(0, 1, 2))(q, k, v)
+    got = [hashlib.sha256(np.asarray(a).tobytes()).hexdigest()[:16]
+           for a in (f(q, k, v), *grads)]
+    assert got == ["d2c836fd07da7084", "ad958f1c4ffe6e23",
+                   "a3ccf6055ec9625a", "9347dbec8e7559d4"]
 
 
 def test_bfloat16_gradients_match_dense():

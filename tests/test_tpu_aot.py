@@ -18,6 +18,7 @@ take tens of seconds of host compile and are marked ``slow``.
 """
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from distributed_tensorflow_guide_tpu.ops import autotune
 from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
 from distributed_tensorflow_guide_tpu.ops import flash_attention as FA
 
@@ -95,11 +97,24 @@ def test_compiler_is_in_the_loop(v5e):
                     sds((4096, 2048), jnp.float32))
 
 
+def tracked_tiles(kernel: str) -> tuple[int, int]:
+    """What the table git tracks gives a v5e for this file's shapes (the
+    batch/head-generic entry): the tiles the chip is really handed."""
+    name = {"fwd": "flash_fwd", "dq": "flash_dq", "dkv": "flash_dkv",
+            "carry": "carry_step"}[kernel]
+    table = json.loads(autotune.TRACKED_TABLE.read_text())
+    ent = table[autotune._key(name, 0, 0, S, HD, "bfloat16", True,
+                              "tpu:tpu-v5-lite")]
+    return ent["blk_q"], ent["blk_k"]
+
+
+@pytest.mark.parametrize("tiles", ["default", "table"])
 @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "carry"])
-def test_flash_kernels_compile(v5e, as_on_tpu, kernel):
+def test_flash_kernels_compile(v5e, as_on_tpu, kernel, tiles):
+    blk = BLK if tiles == "default" else tracked_tiles(kernel)
     x = sds((B, H, S, DP), jnp.bfloat16)
     row = sds((B, H, S, FA.LANE), jnp.float32)  # lse / delta / m / l
-    kw = dict(scale=SCALE, blk_q=BLK[0], blk_k=BLK[1])
+    kw = dict(scale=SCALE, blk_q=blk[0], blk_k=blk[1])
     fn, args = {
         "fwd": (functools.partial(FA._fwd_call, causal=True, **kw),
                 (x, x, x)),
