@@ -12,6 +12,9 @@ comes out by the repo's own means:
 * device:  a TPU, alone on it (a bf16 matmul chain lands near the table's
            peak), and ``block_until_ready`` fences;
 * kernels: every Pallas kernel, compiled, against its XLA reference;
+* patterned: the routed feed-forward, the short convolution through its
+           state and the paged kernel under grouped heads, at LFM2's
+           published widths, against float32 XLA;
 * train:   1 + 5 ``TrainLoop`` steps, loss finite and falling, with the
            flash kernels, the fused cross-entropy and donation compiled in;
 * serve:   a dozen staggered requests through ``ServeEngine``, all ``ok``,
@@ -306,6 +309,139 @@ def phase_kernels(*, heads: int = 12, head_dim: int = 64, seq: int = 1024,
     say("kernels", **facts)
     bad = {k: v for k, v in errs.items() if not v <= KERNEL_TOL}
     assert not bad, f"kernels off their XLA reference: {bad}"
+    return facts
+
+
+def phase_patterned(*, d: int = 2048, ff: int = 1536, experts: int = 64,
+                    top_k: int = 4, rows: int = 256, heads: int = 32,
+                    kv_heads: int = 8, head_dim: int = 64, seq: int = 1024,
+                    block_size: int = 128, chunk: int = 128) -> dict:
+    """What a patterned model adds (PR 28), each in bfloat16 against float32
+    XLA at full product precision from the same bfloat16 numbers, at LFM2's
+    published widths: the routed feed-forward (sorted grouped products
+    against every expert computed for every row and masked), the
+    short-convolution mixer in two chunks through its state leaf against
+    one pass over the whole sequence, and the paged kernel with 4 query
+    heads a pool head against the pool's heads copied."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        ShortConv,
+        TransformerConfig,
+    )
+    from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+    from distributed_tensorflow_guide_tpu.ops.routed_ffn import (
+        route,
+        routed_ffn,
+    )
+    from distributed_tensorflow_guide_tpu.serve.paged_cache import gather_view
+
+    errs: dict[str, float] = {}
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def reference(fn, *args):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(fn)(*args)
+
+    # -- the routed layer ----------------------------------------------------
+    keys = jax.random.split(jax.random.PRNGKey(4), 7)
+    x = jax.random.normal(keys[0], (rows, d), f32).astype(bf16)
+    router = 0.02 * jax.random.normal(keys[1], (d, experts), f32)
+    bias = 0.01 * jax.random.normal(keys[2], (experts,), f32)
+    w_gate, w_up = (0.02 * jax.random.normal(k, (experts, d, ff), f32).astype(
+        bf16) for k in keys[3:5])
+    w_down = 0.02 * jax.random.normal(keys[5], (experts, ff, d), f32).astype(
+        bf16)
+
+    def every_expert(x, router, bias, w_gate, w_up, w_down):
+        chosen, weights = route(x, router, bias, top_k=top_k)
+        mask = jnp.zeros((rows, experts), f32).at[
+            jnp.arange(rows)[:, None], chosen].set(weights)
+        xf = x.astype(f32)
+
+        def one(y, expert):
+            g, u, dn, w = expert
+            h = jax.nn.silu(xf @ g.astype(f32)) * (xf @ u.astype(f32))
+            return y + w[:, None] * (h @ dn.astype(f32)), None
+
+        return lax.scan(one, jnp.zeros((rows, d), f32),
+                        (w_gate, w_up, w_down, mask.T))[0]
+
+    routed = jax.jit(lambda *a: routed_ffn(*a, top_k=top_k))
+    args = (x, router, bias, w_gate, w_up, w_down)
+    if jax.default_backend() == "tpu":  # one native grouped product each
+        assert "ragged-dot" in routed.lower(*args).compile().as_text()
+    y, load = routed(*args)
+    assert int(load.sum()) == rows * top_k  # dropless
+    errs["routed_ffn"] = rel_err(y, reference(every_expert, *args))
+
+    # -- the short convolution through its state -----------------------------
+    cfg = TransformerConfig(
+        vocab_size=128, num_layers=1, num_heads=heads, d_model=d, d_ff=ff,
+        max_len=seq, dtype=bf16, layers=(("short_conv", "dense"),),
+        norm="rmsnorm", ffn_gate="silu", rope_theta=1e6,
+        num_kv_heads=kv_heads)
+    whole = ShortConv(dataclasses.replace(cfg, dtype=f32))
+    paged = ShortConv(dataclasses.replace(
+        cfg, decode=True, paged_num_blocks=2, paged_block_size=block_size))
+    xs = jax.random.normal(keys[6], (1, 2 * chunk, d), f32).astype(bf16)
+    params = jax.jit(whole.init)(jax.random.PRNGKey(5), xs)["params"]
+    params = jax.tree.map(lambda p: p.astype(bf16).astype(f32), params)
+    slot = jnp.asarray([2], jnp.int32)
+    state = {"conv": jnp.ones((4, cfg.conv_kernel - 1, d), bf16)}
+
+    @jax.jit
+    def in_chunks(params, xs, state):
+        outs = []
+        for i in range(2):  # the second chunk reads the first one's state
+            out, mut = paged.apply(
+                {"params": params, "state": state},
+                xs[:, i * chunk:(i + 1) * chunk],
+                jnp.asarray([i * chunk], jnp.int32), state_rows=slot,
+                valid=jnp.asarray([chunk], jnp.int32), mutable=["state"])
+            state = mut["state"]
+            outs.append(out)
+        return jnp.concatenate(outs, axis=1)
+
+    errs["short_conv"] = rel_err(
+        in_chunks(params, xs, state),
+        reference(lambda p, x: whole.apply({"params": p}, x.astype(f32)),
+                  params, xs))
+
+    # -- the paged kernel, 4 query heads a pool head -------------------------
+    B, n_blk = 8, seq // block_size
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(6), 3)
+    shape = (B * n_blk + 1, kv_heads, block_size, head_dim)
+    kpool = jax.random.normal(kk, shape, f32).astype(bf16)
+    vpool = jax.random.normal(kv, shape, f32).astype(bf16)
+    q = jax.random.normal(kq, (B, 1, heads, head_dim), f32).astype(bf16)
+    tables = jnp.asarray(np.random.RandomState(1).permutation(
+        B * n_blk).reshape(B, n_blk).astype(np.int32))
+    lengths = jnp.asarray(np.linspace(1, seq, B).astype(np.int32))
+    grouped = jax.jit(lambda q, kp, vp: DA.paged_decode_attention(
+        q, kp, vp, tables, lengths, block_size=block_size))
+    assert has_pallas_call(grouped.lower(q, kpool, vpool).as_text())
+
+    def copied(q, kp, vp):
+        group = heads // kv_heads
+        return decode_reference(
+            q, gather_view(jnp.repeat(kp, group, 1), tables, seq_axis=2),
+            gather_view(jnp.repeat(vp, group, 1), tables, seq_axis=2),
+            (lengths - 1)[:, None])
+
+    errs["paged_decode_grouped"] = rel_err(
+        grouped(q, kpool, vpool), reference(copied, q, kpool, vpool))
+
+    facts = {"tolerance": KERNEL_TOL,
+             "rel_err": {k: round(v, 5) for k, v in errs.items()}}
+    say("patterned", **facts)
+    bad = {k: v for k, v in errs.items() if not v <= KERNEL_TOL}
+    assert not bad, f"a patterned model's parts off float32 XLA: {bad}"
     return facts
 
 
@@ -617,6 +753,7 @@ def main() -> int:
     phase_device(devices)
     phase_kernels(heads=cfg.num_heads, head_dim=cfg.head_dim,
                   seq=cfg.max_len)
+    phase_patterned()
     state, tokens = phase_train(cfg, devices)
     phase_serve(cfg, state, tokens, devices)
     table = autotune.table_path()

@@ -39,6 +39,9 @@ from distributed_tensorflow_guide_tpu.utils.activation_sharding import (
 
 Dtype = Any
 
+MIXERS = ("attention", "short_conv")
+FFNS = ("dense", "routed")
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -173,8 +176,44 @@ class TransformerConfig:
     # (default) keeps every historical trace byte-identical.
     moe_experts: int | None = None
     moe_capacity: int | None = None
+    # The model as a PATTERN of layers (PR 28). ``layers`` set → layer ``i``
+    # is ``(mixer, ffn)``: a mixer kind (``"attention"`` | ``"short_conv"``)
+    # over a feed-forward kind (``"dense"`` | ``"routed"``), and the sizes
+    # below are the model's own; ``num_layers`` must equal its length. None
+    # (default) is GPT-2's block everywhere and keeps every historical
+    # trace byte-identical: the sizes below are then refused, not ignored.
+    layers: tuple | None = None
+    # "layernorm" | "rmsnorm"; ``norm_eps`` None is flax's default (1e-6)
+    norm: str = "layernorm"
+    norm_eps: float | None = None
+    # None: down(gelu(up)) with the historical bias; "silu": the gated form
+    # down(silu(gate) * up), no biases
+    ffn_gate: str | None = None
+    # None: a learned table of ``max_len`` positions added to the
+    # embedding; a number: rotary positions inside attention over the whole
+    # head (no table: ``max_len`` is then only the cache's length)
+    rope_theta: float | None = None
+    # fewer key/value heads than query heads (None: one each); the paged
+    # pool's leaves hold this many heads
+    num_kv_heads: int | None = None
+    # RMSNorm over the head size on every query and key head, before the
+    # rotation
+    qk_norm: bool = False
+    # short_conv mixer: taps of the depthwise causal convolution; a
+    # sequence carries ``conv_kernel - 1`` positions of state
+    conv_kernel: int = 3
+    # routed feed-forward (ops/routed_ffn.py): ``routed_experts`` in all,
+    # ``routed_top_k`` a token, each the gated form at ``routed_d_ff``;
+    # this program holds experts ``[routed_first, routed_first +
+    # routed_count)`` (None: all) and assignments to the others add nothing
+    routed_experts: int | None = None
+    routed_top_k: int = 1
+    routed_d_ff: int | None = None
+    routed_first: int = 0
+    routed_count: int | None = None
 
     def __post_init__(self):
+        self._check_pattern()
         if self.attn_impl not in ("auto", "dense", "flash"):
             raise ValueError(
                 "attn_impl must be 'auto', 'dense' or 'flash', "
@@ -283,6 +322,89 @@ class TransformerConfig:
                 raise ValueError(
                     f"{lever} and lora_rank are mutually exclusive"
                 )
+
+    def _check_pattern(self) -> None:
+        sizes = dict(norm=self.norm != "layernorm",
+                     norm_eps=self.norm_eps is not None,
+                     ffn_gate=self.ffn_gate is not None,
+                     rope_theta=self.rope_theta is not None,
+                     num_kv_heads=self.num_kv_heads is not None,
+                     qk_norm=self.qk_norm,
+                     routed_experts=self.routed_experts is not None)
+        if self.layers is None:
+            given = sorted(k for k, v in sizes.items() if v)
+            if given:
+                raise ValueError(
+                    f"{given} are sizes of a patterned model: give "
+                    "``layers`` too (None keeps GPT-2's block as it was)")
+            return
+        if len(self.layers) != self.num_layers:
+            raise ValueError(
+                f"layers has {len(self.layers)} entries, num_layers is "
+                f"{self.num_layers}")
+        for kinds in self.layers:
+            if (len(kinds) != 2 or kinds[0] not in MIXERS
+                    or kinds[1] not in FFNS):
+                raise ValueError(
+                    f"a layer is (mixer in {MIXERS}, ffn in {FFNS}), "
+                    f"got {kinds!r}")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm {self.norm!r}: layernorm or rmsnorm")
+        if self.ffn_gate not in (None, "silu"):
+            raise ValueError(f"ffn_gate {self.ffn_gate!r}: None or 'silu'")
+        kv = self.kv_heads
+        if kv < 1 or self.num_heads % kv:
+            raise ValueError(
+                f"num_kv_heads {kv} must divide num_heads {self.num_heads}")
+        if self.rope_theta is not None and self.head_dim % 2:
+            raise ValueError("rotary positions need an even head size")
+        if self.conv_kernel < 2:
+            raise ValueError("conv_kernel must be >= 2")
+        if any(f == "routed" for _, f in self.layers):
+            e, k = self.routed_experts, self.routed_top_k
+            if e is None or self.routed_d_ff is None:
+                raise ValueError(
+                    "a routed layer needs routed_experts and routed_d_ff")
+            if not 1 <= k <= e:
+                raise ValueError(f"routed_top_k {k} not in [1, {e}]")
+            first, count = self.routed_first, self.routed_held
+            if first < 0 or count < 1 or first + count > e:
+                raise ValueError(
+                    f"held experts [{first}, {first + count}) lie outside "
+                    f"[0, {e})")
+            if self.ffn_gate != "silu":
+                raise ValueError("the routed experts are the gated form: "
+                                 "ffn_gate must be 'silu'")
+        # what the patterned path has no wiring for is refused by name
+        unwired = dict(lora_rank=self.lora_rank, weight_dtype=self.weight_dtype,
+                       moe_experts=self.moe_experts, tp_axis=self.tp_axis,
+                       kv_dtype=self.kv_dtype,
+                       quantized_matmuls=self.quantized_matmuls or None,
+                       fp8_matmuls=self.fp8_matmuls or None,
+                       num_classes=self.num_classes)
+        bad = sorted(k for k, v in unwired.items() if v is not None)
+        if bad:
+            raise ValueError(
+                f"a patterned model (layers=...) has no wiring for {bad}")
+
+    @property
+    def kv_heads(self) -> int:
+        return (self.num_heads if self.num_kv_heads is None
+                else self.num_kv_heads)
+
+    @property
+    def routed_held(self) -> int:
+        """How many experts this program holds."""
+        return (self.routed_experts - self.routed_first
+                if self.routed_count is None else self.routed_count)
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a sequence carries state beside its keys and values
+        (a short_conv mixer's last positions): what moves blocks of keys
+        and values alone cannot move such a sequence."""
+        return self.layers is not None and any(
+            m == "short_conv" for m, _ in self.layers)
 
     @property
     def paged(self) -> bool:
@@ -511,8 +633,57 @@ class QuantTrainDense(nn.Module):
         return y.reshape(x.shape[:-self.in_axes] + feats)
 
 
+def _norm(cfg: TransformerConfig, name: str):
+    """The model's normalisation. With no size of a patterned model given
+    this is the historical ``nn.LayerNorm`` call, kept verbatim."""
+    if cfg.norm == "layernorm" and cfg.norm_eps is None:
+        return nn.LayerNorm(dtype=cfg.dtype, name=name)
+    eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+    cls = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    return cls(epsilon=eps, dtype=cfg.dtype, name=name)
+
+
+def rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over the whole head: ``x`` (B, S, H, hd) at
+    ``positions`` (B, S) or (1, S). The halves ``[x1, x2]`` turn as ``x *
+    cos + [-x2, x1] * sin`` with frequencies ``theta ** (-2i / hd)``, angles
+    in float32 (a bfloat16 angle loses the position past a few hundred)."""
+    hd = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angles = positions.astype(jnp.float32)[..., None] * freqs  # (B, S, hd/2)
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[:, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[:, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    turned = jnp.concatenate([-x2, x1], axis=-1)
+    return (x.astype(jnp.float32) * cos + turned * sin).astype(x.dtype)
+
+
 class MultiHeadAttention(nn.Module):
     cfg: TransformerConfig
+
+    def _grouped_qkv(self, x, index):
+        """The patterned model's projection: ``h`` query heads and
+        ``kv_heads`` key and value heads out of one kernel, each query and
+        key head normalised (``qk_norm``) and then turned by its position
+        (``rope_theta``)."""
+        cfg = self.cfg
+        h, kv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+        qkv = nn.DenseGeneral(
+            (h + 2 * kv, hd), axis=-1, dtype=cfg.dtype,
+            kernel_init=_dense_init("embed", "heads", "kv"),
+            use_bias=False, name="qkv")(x)
+        q, k, v = qkv[:, :, :h], qkv[:, :, h:h + kv], qkv[:, :, h + kv:]
+        if cfg.qk_norm:
+            eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+            q = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="k_norm")(k)
+        if cfg.rope_theta is not None:
+            positions = jnp.arange(x.shape[1])[None, :]
+            if cfg.decode:
+                positions = positions + jnp.reshape(index, (-1, 1))
+            q = rotate(q, positions, cfg.rope_theta)
+            k = rotate(k, positions, cfg.rope_theta)
+        return q, k, v
 
     @nn.compact
     def __call__(self, x: jax.Array, index=None, *,
@@ -521,7 +692,9 @@ class MultiHeadAttention(nn.Module):
         h, hd = cfg.num_heads, cfg.head_dim
         if cfg.tp_axis:  # Megatron f: identity fwd, psum bwd (see tp_axis doc)
             x = tp_identity(x, cfg.tp_axis)
-        if cfg.weight_dtype:
+        if cfg.layers is not None:
+            q, k, v = self._grouped_qkv(x, index)
+        elif cfg.weight_dtype:
             qkv = WeightQuantDense(
                 (3, h, hd), in_axes=1, bits=_WQ_BITS[cfg.weight_dtype],
                 dtype=cfg.dtype, name="qkv",
@@ -549,7 +722,8 @@ class MultiHeadAttention(nn.Module):
             if adapter is not None:
                 qkv = qkv + _lora_delta(qkv_a, qkv_b, x,
                                         adapter).reshape(qkv.shape)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (B, S, H, hd)
+        if cfg.layers is None:
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (B,S,H,hd)
         # "seq_inner": inside a sub-layer the sequence dim is deliberately
         # a DIFFERENT logical axis from the residual stream's "seq" — under
         # Megatron-SP rules "seq" maps to the model axis (sequence-sharded
@@ -560,6 +734,11 @@ class MultiHeadAttention(nn.Module):
         k = _constrain(k, ("batch", "seq_inner", "heads", "kv"))
         v = _constrain(v, ("batch", "seq_inner", "heads", "kv"))
 
+        group = h // k.shape[2]
+        if group > 1 and not (cfg.decode and cfg.paged):
+            # the training view: every query head beside its own copy of
+            # its group's keys and values (the paged path never copies)
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if cfg.decode and cfg.paged:
             out = self._paged_decode_attend(q, k, v, index, block_tables)
         elif cfg.decode:
@@ -772,7 +951,11 @@ class MultiHeadAttention(nn.Module):
             scatter_chunk,
         )
 
-        B, C, h, hd = q.shape
+        B, C, hq, hd = q.shape
+        # the pool holds the key/value heads; ``hq // h`` query heads
+        # share each (1 for GPT-2's block: every line below as it was)
+        h = k.shape[2]
+        group = hq // h
         N, bs = cfg.paged_num_blocks, cfg.paged_block_size
         idx = jnp.asarray(index)
         if idx.ndim == 0:
@@ -791,6 +974,8 @@ class MultiHeadAttention(nn.Module):
                                      block_size=bs, seq_axis=1)
             keys = gather_view(ck.value, block_tables, seq_axis=1)
             vals = gather_view(cv.value, block_tables, seq_axis=1)
+            if group > 1:
+                return self._grouped_dense(q, keys, vals, idx, "bkhd")
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys) / jnp.sqrt(
                 hd).astype(cfg.dtype)
             q_pos = idx[:, None] + jnp.arange(C)  # (B, C)
@@ -864,6 +1049,8 @@ class MultiHeadAttention(nn.Module):
         # the non-paged kernel-layout branch, per-request mask rows
         keys = gather_view(ck.value, block_tables, seq_axis=2)
         vals = gather_view(cv.value, block_tables, seq_axis=2)
+        if group > 1:
+            return self._grouped_dense(q, keys, vals, idx, "bhkd")
         scores = jnp.einsum("bqhd,bhkd->bhqk", q,
                             keys.astype(cfg.dtype)) / jnp.sqrt(
             hd).astype(cfg.dtype)
@@ -884,12 +1071,44 @@ class MultiHeadAttention(nn.Module):
                           vals.astype(cfg.dtype))
 
 
+    def _grouped_dense(self, q, keys, vals, idx, layout: str):
+        """The gathered dense math for ``group`` query heads a key/value
+        head: ``q`` (B, C, h * group, hd) against the logical views
+        ``keys``/``vals`` in ``layout`` ("bkhd" or "bhkd"), under the same
+        per-row mask as the one-group lines beside its callers."""
+        cfg = self.cfg
+        B, C, hq, hd = q.shape
+        h = keys.shape[layout.index("h")]
+        qg = q.reshape(B, C, h, hq // h, hd)
+        scores = jnp.einsum(f"bqhgd,{layout}->bhgqk", qg,
+                            keys.astype(cfg.dtype)) / jnp.sqrt(
+            hd).astype(cfg.dtype)
+        q_pos = idx[:, None] + jnp.arange(C)  # (B, C)
+        mask = jnp.arange(cfg.max_len)[None, None, :] <= q_pos[:, :, None]
+        scores = jnp.where(mask[:, None, None], scores.astype(jnp.float32),
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, -1).astype(cfg.dtype)
+        out = jnp.einsum(f"bhgqk,{layout}->bqhgd", probs,
+                         vals.astype(cfg.dtype))
+        return out.reshape(B, C, hq, hd)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x: jax.Array, *, adapter=None) -> jax.Array:
         cfg = self.cfg
+        if cfg.ffn_gate == "silu":
+            # the gated form (a patterned model's): no biases
+            def dense(features, names, name):
+                return nn.Dense(features, dtype=cfg.dtype, use_bias=False,
+                                kernel_init=_dense_init(*names), name=name)
+
+            y = (nn.silu(dense(cfg.d_ff, ("embed", "mlp"), "gate")(x))
+                 * dense(cfg.d_ff, ("embed", "mlp"), "up")(x))
+            y = _constrain(y, ("batch", "seq_inner", "mlp"))
+            return dense(cfg.d_model, ("mlp", "embed"), "down")(y)
         if cfg.tp_axis:  # Megatron f
             x = tp_identity(x, cfg.tp_axis)
         if cfg.weight_dtype:
@@ -1110,16 +1329,133 @@ class MoEMLP(nn.Module):
         return y.reshape(b, s, d).astype(x.dtype)
 
 
-class Block(nn.Module):
-    """Pre-LN transformer block: x + attn(LN(x)); x + mlp(LN(x))."""
+class ShortConv(nn.Module):
+    """The short-convolution mixer (LFM2): ``[B, C, u] = split3(W_in x)``,
+    ``z = B * u``, ``c_t = sum_j w[:, j] * z_{t - (K - 1) + j}`` (depthwise,
+    causal, zeros before position 0), ``out = W_out (C * c)``.
+
+    A sequence carries ``z`` at its last ``K - 1`` positions. In decode mode
+    that is one row of the ``state`` collection's ``conv`` leaf, ``(rows,
+    K - 1, d)``: batch row ``b`` reads and writes row ``state_rows[b]``
+    (the engine's slot), a chunk at position 0 reads zeros, and a chunk of
+    which ``valid[b]`` positions are real leaves the state of its last
+    real position (``valid[b] == 0``: the row's state as it was)."""
 
     cfg: TransformerConfig
 
     @nn.compact
+    def __call__(self, x: jax.Array, index=None, *, state_rows=None,
+                 valid=None) -> jax.Array:
+        cfg = self.cfg
+        d, taps = cfg.d_model, cfg.conv_kernel
+        B, S, _ = x.shape
+        bcu = nn.Dense(3 * d, dtype=cfg.dtype, use_bias=False,
+                       kernel_init=_dense_init("embed", "mlp"),
+                       name="in_proj")(x)
+        gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
+        z = gate_b * u
+        w = self.param("conv_w", _dense_init("embed", "conv"), (d, taps),
+                       jnp.float32).astype(cfg.dtype)
+        if cfg.decode:
+            if index is None or state_rows is None or valid is None:
+                raise ValueError("short_conv in decode mode needs the "
+                                 "index, the state rows and the valid "
+                                 "counts")
+            state = self.variable("state", "conv", jnp.zeros,
+                                  (B, taps - 1, d), cfg.dtype)
+            held = state.value[state_rows]  # (B, K - 1, d)
+            fresh = jnp.reshape(index, (-1, 1, 1)) == 0
+            before = jnp.where(fresh, jnp.zeros_like(held), held)
+        else:
+            before = jnp.zeros((B, taps - 1, d), z.dtype)
+        ext = jnp.concatenate([before.astype(z.dtype), z], axis=1)
+        c = sum(w[:, j] * ext[:, j:j + S] for j in range(taps))
+        if cfg.decode:
+            # ext[n : n + K - 1] is z at the last K - 1 real positions
+            after = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
+                e, n, taps - 1, axis=0))(ext, valid)
+            after = jnp.where(jnp.reshape(valid, (-1, 1, 1)) > 0,
+                              after.astype(held.dtype), held)
+            state.value = state.value.at[state_rows].set(after)
+        return nn.Dense(d, dtype=cfg.dtype, use_bias=False,
+                        kernel_init=_dense_init("mlp", "embed"),
+                        name="out_proj")(gate_c * c)
+
+
+class RoutedMLP(nn.Module):
+    """The routed feed-forward of a patterned model: ``ops/routed_ffn.py``
+    over this module's router, selection bias and the banks of the experts
+    the program holds. Sows the router's census (``load``, (E,)) into the
+    ``routed_stats`` collection, a no-op unless the caller makes it
+    mutable (the serve step does)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *, live=None) -> jax.Array:
+        from distributed_tensorflow_guide_tpu.ops.routed_ffn import (
+            routed_ffn,
+        )
+
+        cfg = self.cfg
+        e, held = cfg.routed_experts, cfg.routed_held
+        d, ff = cfg.d_model, cfg.routed_d_ff
+        b, s, _ = x.shape
+        router = self.param("router", _dense_init("embed", "expert"),
+                            (d, e), jnp.float32)
+        bias = self.param("expert_bias", nn.initializers.zeros_init(),
+                          (e,), jnp.float32)
+        up_names, down_names = (("expert", "embed", "mlp"),
+                                ("expert", "mlp", "embed"))
+        y, load = routed_ffn(
+            x.reshape(b * s, d).astype(cfg.dtype), router, bias,
+            _ExpertBank((held, d, ff), up_names, name="w_gate")(),
+            _ExpertBank((held, d, ff), up_names, name="w_up")(),
+            _ExpertBank((held, ff, d), down_names, name="w_down")(),
+            top_k=cfg.routed_top_k, first=cfg.routed_first,
+            live=None if live is None else live.reshape(b * s))
+        self.sow("routed_stats", "load", load)
+        return y.reshape(b, s, d)
+
+
+class Block(nn.Module):
+    """Pre-LN transformer block: x + attn(LN(x)); x + mlp(LN(x)).
+    ``kinds`` (a patterned model's layer) names the mixer and the
+    feed-forward the same two lines run."""
+
+    cfg: TransformerConfig
+    kinds: tuple | None = None
+
+    def _patterned(self, x, index, block_tables, state_rows, valid):
+        cfg, (mixer, ffn) = self.cfg, self.kinds
+        h = _norm(cfg, "ln1")(x)
+        if mixer == "attention":
+            with jax.named_scope("dtg.attn"):
+                x = x + MultiHeadAttention(cfg, name="attn")(
+                    h, index, block_tables=block_tables)
+        else:
+            with jax.named_scope("dtg.short_conv"):
+                x = x + ShortConv(cfg, name="conv")(
+                    h, index, state_rows=state_rows, valid=valid)
+        h2 = _norm(cfg, "ln2")(x)
+        if ffn == "routed":
+            live = None
+            if valid is not None:
+                live = jnp.arange(x.shape[1])[None, :] < valid[:, None]
+            with jax.named_scope("dtg.routed"):
+                x = x + RoutedMLP(cfg, name="mlp")(h2, live=live)
+        else:
+            x = x + MLP(cfg, name="mlp")(h2)
+        return _constrain(x, ("batch", "seq", "embed"))
+
+    @nn.compact
     def __call__(self, x: jax.Array, index=None, *,
                  block_tables=None, adapter=None,
-                 moe_mask=None) -> jax.Array:
+                 moe_mask=None, state_rows=None, valid=None) -> jax.Array:
         cfg = self.cfg
+        if self.kinds is not None:
+            return self._patterned(x, index, block_tables, state_rows,
+                                   valid)
         # Attention-only selective remat (core/precision.py): checkpoint the
         # attention sub-layer here so EVERY consumer — the flat Transformer,
         # all four pipeline schedules — gets the same HBM/FLOP trade without
@@ -1157,9 +1493,43 @@ class Transformer(nn.Module):
 
     cfg: TransformerConfig
 
+    def _patterned(self, x, index, block_tables, state_rows, valid,
+                   return_hidden):
+        """The forward of a model given as a pattern of layers, from the
+        embedded tokens on: learned positions only if the model has no
+        rotary ones, each layer its own mixer and feed-forward, the
+        model's normalisation, the same head."""
+        cfg = self.cfg
+        if cfg.decode and not cfg.paged:
+            raise ValueError(
+                "a patterned model decodes through the paged engine only "
+                "(serve/engine.py): it has no one-shot cache")
+        if cfg.rope_theta is None:
+            positions = jnp.arange(x.shape[1])[None, :]
+            if cfg.decode:
+                positions = positions + jnp.reshape(index, (-1, 1))
+            x = x + nn.Embed(cfg.max_len, cfg.d_model, dtype=cfg.dtype,
+                             embedding_init=_dense_init("seq", "embed"),
+                             name="pos_emb")(positions)
+        x = _constrain(x, ("batch", "seq", "embed"))
+        block = Block
+        if cfg.resolved_remat_mode == "block":
+            block = nn.remat(Block, prevent_cse=False)
+        for i, kinds in enumerate(cfg.layers):
+            x = block(cfg, kinds=tuple(kinds), name=f"block_{i}")(
+                x, index, block_tables=block_tables,
+                state_rows=state_rows, valid=valid)
+        x = _norm(cfg, "ln_f")(x)
+        if return_hidden:
+            return x
+        return nn.Dense(cfg.vocab_size, dtype=jnp.float32, use_bias=False,
+                        kernel_init=_dense_init("embed", "vocab"),
+                        name="lm_head")(x)
+
     @nn.compact
     def __call__(self, tokens: jax.Array, index=None, *,
                  block_tables=None, adapter=None, moe_mask=None,
+                 state_rows=None, valid=None,
                  return_hidden: bool = False) -> jax.Array:
         # tokens (B, S) int32; ``index`` only in cfg.decode mode: the
         # absolute position of tokens[:, 0] (prefill passes 0, the decode
@@ -1179,6 +1549,9 @@ class Transformer(nn.Module):
             embedding_init=_dense_init("vocab", "embed"),
             name="tok_emb",
         )(tokens)
+        if cfg.layers is not None:
+            return self._patterned(x, index, block_tables, state_rows,
+                                   valid, return_hidden)
         positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.decode:
             # the serve engine passes a PER-REQUEST (B,) index vector

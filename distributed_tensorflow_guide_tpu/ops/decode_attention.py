@@ -490,8 +490,9 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
     """Length-aware cache attention reading a paged pool through tables.
 
     ``q``: (B, C, H, hd) public layout. ``key_pool``/``value_pool``:
-    (num_blocks, H, block_size, hd) kernel layout (int8 with
-    (num_blocks, H, 1, block_size) f32 scale pools when quantized).
+    (num_blocks, Hkv, block_size, hd) kernel layout (int8 with
+    (num_blocks, Hkv, 1, block_size) f32 scale pools when quantized);
+    ``Hkv`` divides ``H`` and ``H // Hkv`` query heads share a pool head.
     ``block_tables``: (B, blocks_per_seq) int32 physical block ids.
     ``lengths``: (B,) int32 per-request live lengths AFTER the chunk's
     write — request b's chunk occupies logical positions
@@ -532,13 +533,24 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
         last_live = (len_ref[b] + blk_k - 1) // blk_k - 1
         return jnp.minimum(j, last_live)
 
+    # grouped heads: the pool holds H // group key/value heads and grid
+    # head h reads pool head h // group (group 1: the index as it was)
+    group = H // key_pool.shape[1]
+    if group < 1 or group * key_pool.shape[1] != H:
+        raise ValueError(
+            f"{H} query heads are no multiple of the pool's "
+            f"{key_pool.shape[1]} key/value heads")
+
+    def pool_head(h):
+        return h if group == 1 else h // group
+
     def kv_map(b, h, j, len_ref, bt_ref):
         lj = live_j(b, j, len_ref)
-        return (bt_ref[b, lj // sub], h, lj % sub, 0)
+        return (bt_ref[b, lj // sub], pool_head(h), lj % sub, 0)
 
     def sc_map(b, h, j, len_ref, bt_ref):
         lj = live_j(b, j, len_ref)
-        return (bt_ref[b, lj // sub], h, 0, lj % sub)
+        return (bt_ref[b, lj // sub], pool_head(h), 0, lj % sub)
 
     q_spec = _vmem_spec((1, 1, cp, hd),
                         lambda b, h, j, L, T: (b, h, 0, 0))

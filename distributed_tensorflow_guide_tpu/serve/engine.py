@@ -134,24 +134,46 @@ def paged_cache_shapes(pcfg: TransformerConfig, slots: int):
     from what the step programs trace. Pool leaves are (num_blocks, ...)
     — independent of the batch width, which is what lets the S-slot
     decode program and the B=1 prefill program share one pool."""
+    return _serving_shapes(pcfg, slots).get("cache", {})
+
+
+def _serving_shapes(pcfg: TransformerConfig, slots: int):
+    """Abstract trees of every collection a ``slots``-row decode call of the
+    serving model makes: ``cache`` (the block pool) and, for a model whose
+    sequences carry state beside keys and values, ``state``."""
     model = Transformer(pcfg)
     n_blk = pcfg.max_len // pcfg.paged_block_size
-    variables = jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jnp.zeros((slots, 1), jnp.int32),
-        jnp.zeros((slots,), jnp.int32),
-        block_tables=jnp.zeros((slots, n_blk), jnp.int32))
-    return variables["cache"]
+    rows = jnp.zeros((slots,), jnp.int32)
+    extra = ({"state_rows": rows, "valid": rows}
+             if pcfg.layers is not None else {})
+    return jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32), rows,
+            block_tables=jnp.zeros((slots, n_blk), jnp.int32), **extra))
+
+
+def slot_state(pcfg: TransformerConfig, slots: int, device=None):
+    """The zeroed per-slot state leaves beside the block pool (``{}`` for a
+    model with none): row ``i`` of every leaf is slot ``i``'s, which both
+    step programs address (the prefill program is told its slot as it is
+    told its block table). A slot's row needs no clearing between
+    requests: a chunk that starts at position 0 reads zeros."""
+    return _zeros(_serving_shapes(pcfg, slots).get("state", {}), device)
+
+
+def _zeros(shapes, device):
+    """A zeroed tree of ``shapes``, committed to ``device`` (None leaves it
+    uncommitted where JAX's default puts it)."""
+    with jax.default_device(device):
+        tree = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    return tree if device is None else jax.device_put(tree, device)
 
 
 def paged_cache_pool(pcfg: TransformerConfig, slots: int, device=None):
     """Allocate the zeroed block pool, committed to ``device`` so that the
     pool-only programs (spill gather/scatter) run there too; None leaves it
     uncommitted where JAX's default puts it."""
-    with jax.default_device(device):
-        pool = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            paged_cache_shapes(pcfg, slots))
-    return pool if device is None else jax.device_put(pool, device)
+    return _zeros(paged_cache_shapes(pcfg, slots), device)
 
 
 def params_device(params):
@@ -210,6 +232,20 @@ def _pool_scatter(pool, idx, rows):
                   for leaf, r in zip(leaves, rows)])
 
 
+def _routed_counters(load: np.ndarray) -> dict:
+    """One launch's routed census, (routed layers, experts), as the two
+    numbers its ``engine.apply`` span carries: ``experts_touched``, the
+    distinct experts a routed layer read (mean over the layers), and
+    ``load_ratio``, the busiest expert's assignments over the mean
+    expert's (mean over the layers; 1 is even). ``{}`` with no routed
+    layer or no live row."""
+    if not load.size or not load.sum():
+        return {}
+    return {"experts_touched": float((load > 0).sum(axis=1).mean()),
+            "load_ratio": float((load.max(axis=1)
+                                 / load.mean(axis=1)).mean())}
+
+
 def _moe_fold(stats):
     """Fold the model's per-layer ``moe_stats`` sow tree into
     ``(load (E,), overflow (E,), overflow_tok (T,))`` — each summed over
@@ -232,6 +268,16 @@ def _moe_fold(stats):
 
     walk(stats)
     return sum(load), sum(overflow), sum(of_tok)
+
+
+def _routed_fold(stats):
+    """The routed layers' sown census, ``{"block_<i>": {"mlp": {"load":
+    (E,)}}}``, as one (routed layers, experts) int32 array with the layers
+    in the model's order; (0, 0) for a model with none."""
+    layers = sorted(stats, key=lambda name: int(name.rpartition("_")[2]))
+    if not layers:
+        return jnp.zeros((0, 0), jnp.int32)
+    return jnp.stack([stats[name]["mlp"]["load"][0] for name in layers])
 
 
 _STEP_FNS = {}
@@ -261,7 +307,43 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
     lora = pcfg.lora_rank is not None
     moe = pcfg.moe
 
-    if lora:
+    patterned = pcfg.layers is not None
+    if patterned:
+        # still exactly two jitted programs. A patterned model's pair
+        # threads two states, the block pool and the per-slot state leaves
+        # (``{}`` where no mixer has any), and hands back the routed
+        # layers' census of the launch, (routed layers, experts): idle
+        # decode rows and a chunk's padding rows are told apart by
+        # ``valid``, route nowhere and leave their slot's state alone.
+        def decode_step(params, pool, state, tables, written, last_tok,
+                        keys):
+            logits, mut = model.apply(
+                {"params": params, "cache": pool, "state": state},
+                last_tok[:, None], written, block_tables=tables,
+                state_rows=jnp.arange(written.shape[0]),
+                valid=(written > 0).astype(jnp.int32),
+                mutable=["cache", "state", "routed_stats"])
+            pos_keys = jax.vmap(jax.random.fold_in)(keys, written + 1)
+            nxt = sample_rows(logits[:, -1], pos_keys, temperature, top_k)
+            return (nxt, mut.get("cache", pool), mut.get("state", state),
+                    _routed_fold(mut.get("routed_stats", {})))
+
+        def prefill_chunk_step(params, pool, state, tables, start, chunk,
+                               valid, key, slot):
+            logits, mut = model.apply(
+                {"params": params, "cache": pool, "state": state},
+                chunk, start, block_tables=tables,
+                state_rows=jnp.reshape(slot, (1,)),
+                valid=jnp.reshape(valid, (1,)),
+                mutable=["cache", "state", "routed_stats"])
+            last = lax.dynamic_index_in_dim(logits[0], valid - 1, axis=0,
+                                            keepdims=False)
+            tok = _sample(last[None],
+                          jax.random.fold_in(key, start[0] + valid),
+                          temperature, top_k)[0]
+            return (tok, mut.get("cache", pool), mut.get("state", state),
+                    _routed_fold(mut.get("routed_stats", {})))
+    elif lora:
         # still exactly two jitted programs: the LoRA engine's pair takes
         # two extra operands — the shared (A, B) delta banks and the
         # per-slot adapter-id vector — and every slot's delta is gathered
@@ -358,14 +440,16 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
     # donation intent is (1,) — the pool — for both programs; the CPU
     # backend doesn't implement input-output aliasing, same gate as
     # make_generate_fn
+    donated = (1, 2) if patterned else (1,)  # the pool (and the state)
     decode_jit = jax.jit(decode_step,
-                         donate_argnums=(1,) if donate else ())
+                         donate_argnums=donated if donate else ())
     prefill_jit = jax.jit(prefill_chunk_step,
-                          donate_argnums=(1,) if donate else ())
+                          donate_argnums=donated if donate else ())
     fns = SimpleNamespace(
         decode=decode_jit, prefill=prefill_jit, model=model, cfg=pcfg,
-        n_blk=n_blk, declared_donate_argnums=(1,), donates_pool=donate,
-        temperature=temperature, top_k=top_k, lora=lora, moe=moe)
+        n_blk=n_blk, declared_donate_argnums=donated, donates_pool=donate,
+        temperature=temperature, top_k=top_k, lora=lora, moe=moe,
+        patterned=patterned)
     _STEP_FNS[memo_key] = fns
     return fns
 
@@ -442,6 +526,17 @@ class ServeEngine:
                 raise ValueError(
                     "persist_cache requires host_blocks > 0 (restored "
                     "cache contents land in the host tier)")
+        if self.fns.cfg.stateful and (prefix_cache or host_blocks):
+            # a prefix hit or a swap-in hands a request blocks of keys and
+            # values and skips the prefill that would have made its other
+            # state: served so, its first token would already be wrong
+            raise ValueError(
+                "this model's sequences carry state beside their keys and "
+                "values (a short_conv mixer's last positions), and the "
+                "prefix cache, the host tier and KV adoption move blocks "
+                "of keys and values alone: prefix_cache and host_blocks "
+                "must stay off (a preempted or migrated request "
+                "re-prefills, which rebuilds the state)")
         self.persist_cache = bool(persist_cache)
         self.store = (BlockStore(capacity=host_blocks) if host_blocks
                       else None)
@@ -476,6 +571,8 @@ class ServeEngine:
         else:
             self.adapters = None
         self.pool = paged_cache_pool(self.fns.cfg, slots, self.device)
+        self.state = (slot_state(self.fns.cfg, slots, self.device)
+                      if self.fns.patterned else None)
         self._trash_row = table_row(
             [], self.fns.n_blk, self.sched.pool.trash_block)
         if self.store is not None:
@@ -639,6 +736,11 @@ class ServeEngine:
         re-prefills — same stream bitwise either way, by the position-
         derived sampling keys).  The record's ``payload_bytes`` is what
         the fleet charges against the DCN roofline."""
+        if with_kv and self.fns.cfg.stateful:
+            raise ValueError(
+                "export_stream(with_kv=True): this model's sequences carry "
+                "state beside their keys and values, which the blocks do "
+                "not hold; export with_kv=False (the target re-prefills)")
         keep = self.sched.migratable_blocks(rid) if with_kv else []
         payloads = self._cache_d2h_many(keep) if keep else []
         record = self.sched.detach_stream(rid)
@@ -653,6 +755,11 @@ class ServeEngine:
         landing pad) and the stream resumes by the normal swap-in path
         at its next admission — ``submitted`` is never recounted (the
         scheduler's attach bypasses submit by contract)."""
+        if record.get("payloads") and self.fns.cfg.stateful:
+            raise ValueError(
+                "adopt_stream: KV payloads cannot resume a sequence of "
+                "this model (its other state is not in them); export "
+                "with_kv=False")
         if record.get("payloads") and self.store is None:
             raise RuntimeError(
                 "adopting KV payloads needs ServeEngine(host_blocks>0) "
@@ -729,13 +836,18 @@ class ServeEngine:
                       **ids):
                 toks, self.pool, *moe = self._launch(
                     lambda: fn(*args), tag="serve_" + program)
+            routed = {}
             with span(rec, "engine.fetch", cat="serve", **ids):
                 toks = np.asarray(toks)
-                if moe:  # ([overflowed slots,] expert load, overflow)
+                if self.fns.patterned:
+                    self.state, load = moe
+                    moe = []
+                    routed = _routed_counters(np.asarray(load))
+                elif moe:  # ([overflowed slots,] expert load, overflow)
                     moe = [np.asarray(x) for x in moe]
                     self._moe_load += moe[-2].astype(np.int64)
                     self._moe_overflow += moe[-1].astype(np.int64)
-            with span(rec, "engine.apply", cat="serve", **ids):
+            with span(rec, "engine.apply", cat="serve", **ids, **routed):
                 if kind == PREFILL:
                     produced = sd.apply_prefill(arg, int(toks))
                 else:
@@ -842,6 +954,10 @@ class ServeEngine:
                            self.sched.pool.trash_block)[None]
         # host operands go in as numpy: the launch moves them straight to
         # the device the committed params and pool are on
+        if self.fns.patterned:
+            return (self.params, self.pool, self.state, tables,
+                    np.full((1,), start, np.int32), chunk, np.int32(valid),
+                    np.asarray(s.rng, np.uint32), np.int32(i))
         args = (self.params, self.pool, tables,
                 np.full((1,), start, np.int32), chunk,
                 np.int32(valid), np.asarray(s.rng, np.uint32))
@@ -866,6 +982,9 @@ class ServeEngine:
             last_tok[i] = s.pending
             keys[i] = s.rng
             adapter_ids[i] = s.adapter
+        if self.fns.patterned:
+            return (self.params, self.pool, self.state, tables, written,
+                    last_tok, keys)
         args = (self.params, self.pool, tables, written, last_tok, keys)
         if self.fns.lora:
             args += (self.adapters, adapter_ids)

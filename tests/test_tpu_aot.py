@@ -182,6 +182,54 @@ def test_paged_decode_kernel_compiles(v5e, as_on_tpu, cache, block_size,
     assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]), fn, *args))
 
 
+# ---- a patterned model's parts, at LFM2's published widths (PR 28) ----------
+
+
+@pytest.mark.parametrize("chunk", [1, 128])
+def test_paged_decode_kernel_compiles_under_grouped_heads(v5e, as_on_tpu,
+                                                          chunk):
+    """32 query heads over a pool of 8: a decode step of 64 rows, and one
+    prefill chunk, in blocks of 128 as the LFM2 cell runs them."""
+    b, heads, kv_heads, block_size, n_blk = (64 if chunk == 1 else 1, 32, 8,
+                                             128, 16)
+    q = sds((b, chunk, heads, HD), jnp.bfloat16)
+    pool = sds((1024, kv_heads, block_size, HD), jnp.bfloat16)
+
+    def fn(q, k, v, tables, lengths):
+        return DA.paged_decode_attention(q, k, v, tables, lengths,
+                                         block_size=block_size)
+
+    assert_mosaic(compile_for(
+        SingleDeviceSharding(v5e[0]), fn, q, pool, pool,
+        sds((b, n_blk), jnp.int32), sds((b,), jnp.int32)))
+
+
+@pytest.mark.parametrize("rows,first,held", [(64, 0, 64), (128, 0, 64),
+                                             (128, 8, 8)])
+def test_routed_ffn_compiles_to_native_grouped_products(v5e, rows, first,
+                                                        held):
+    """A decode launch's and a prefill chunk's rows over all 64 experts,
+    and a chip's share of 8: three grouped products, each one call of the
+    compiler's own whose operations follow the rows, not the experts."""
+    from distributed_tensorflow_guide_tpu.ops.routed_ffn import routed_ffn
+
+    d, ff, experts, top_k = 2048, 1536, 64, 4
+
+    def fn(x, router, bias, w_gate, w_up, w_down):
+        return routed_ffn(x, router, bias, w_gate, w_up, w_down,
+                          top_k=top_k, first=first)
+
+    compiled = compile_for(
+        SingleDeviceSharding(v5e[0]), fn, sds((rows, d), jnp.bfloat16),
+        sds((d, experts), jnp.float32), sds((experts,), jnp.float32),
+        sds((held, d, ff), jnp.bfloat16), sds((held, d, ff), jnp.bfloat16),
+        sds((held, ff, d), jnp.bfloat16))
+    assert compiled.as_text().count("%ragged-dot-none") >= 3
+    flops = compiled.cost_analysis()["flops"]
+    products = rows * top_k * 3 * 2 * d * ff
+    assert products <= flops < 1.5 * products  # not `held` times them
+
+
 # ---- whole programs, at chip_smoke.py's sizes -------------------------------
 
 
