@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import json
 import sys
@@ -199,7 +200,10 @@ def phase_kernels(*, heads: int = 12, head_dim: int = 64, seq: int = 1024,
     from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
     from distributed_tensorflow_guide_tpu.ops import flash_attention as FA
     from distributed_tensorflow_guide_tpu.ops.attention import dense_attention
-    from distributed_tensorflow_guide_tpu.serve.paged_cache import gather_view
+    from distributed_tensorflow_guide_tpu.serve.paged_cache import (
+        gather_view,
+        write_chunk,
+    )
 
     assert not FA._interpret(), "Pallas kernels would run in interpret mode"
     errs: dict[str, float] = {}
@@ -260,6 +264,16 @@ def phase_kernels(*, heads: int = 12, head_dim: int = 64, seq: int = 1024,
         reference(decode_reference, q1, k8, v8, q_pos, ks, vs))
 
     # -- decode attention, paged pool: a decode step and a prefill chunk ---
+    # The pool has one layout, (num_blocks, H, hd, block_size): a block's
+    # slots on the lane axis. With a head dim under 128 the device keeps
+    # such an array that way whatever shape it is declared with (a minor
+    # axis of 64 fills half of an (8, 128) tile), so a pool declared
+    # (.., block_size, hd) was relaid out, whole, for every write and every
+    # kernel call (PERF.md, PR 29). Drawn as rows of hd values, which is
+    # what quantize_kv scales, and turned once.
+    def as_pool(x):
+        return jnp.swapaxes(x, 2, 3)
+
     n_blk = S // block_size
     num_blocks = B * n_blk + 1  # + the trash block
     kk, kv, kq, kc = jax.random.split(jax.random.PRNGKey(3), 4)
@@ -286,14 +300,14 @@ def phase_kernels(*, heads: int = 12, head_dim: int = 64, seq: int = 1024,
         q_pos = (lens - C)[:, None] + jnp.arange(C)[None, :]
         scales = () if ksp is None else (gather_view(ksp, tab, seq_axis=3),
                                          gather_view(vsp, tab, seq_axis=3))
-        return decode_reference(q, gather_view(kp, tab, seq_axis=2),
-                                gather_view(vp, tab, seq_axis=2), q_pos,
-                                *scales)
+        return decode_reference(
+            q, as_pool(gather_view(kp, tab, seq_axis=3)),
+            as_pool(gather_view(vp, tab, seq_axis=3)), q_pos, *scales)
 
     paged_jit = jax.jit(paged)
-    pools = {"bf16": (kpool.astype(jnp.bfloat16),
-                      vpool.astype(jnp.bfloat16)),
-             "int8": (k8p, v8p, ksp, vsp)}
+    pools = {"bf16": (as_pool(kpool.astype(jnp.bfloat16)),
+                      as_pool(vpool.astype(jnp.bfloat16))),
+             "int8": (as_pool(k8p), as_pool(v8p), ksp, vsp)}
     for dtype, pool in pools.items():
         kp, vp, *sc = pool
         for name, (qq, tab, lens) in {
@@ -303,6 +317,24 @@ def phase_kernels(*, heads: int = 12, head_dim: int = 64, seq: int = 1024,
             assert has_pallas_call(paged_jit.lower(*args).as_text())
             errs[f"paged_{name}_{dtype}"] = rel_err(
                 paged_jit(*args), reference(paged_ref, *args))
+
+    # -- the write into that pool: the Pallas form against the loop --------
+    # a decode step's one slot a row, and a chunk that starts inside a
+    # block; every block but the trash block the same to the bit
+    for name, rows, starts in (
+            ("decode", B, lengths - 1),
+            ("chunk", 1, jnp.asarray([S // 2 + 3], jnp.int32))):
+        new = jax.random.normal(
+            jax.random.PRNGKey(4), (rows, H, hd, 1 if name == "decode"
+                                    else prefill_chunk), f32
+        ).astype(jnp.bfloat16)
+        loop, kernel = (jax.jit(functools.partial(
+            write_chunk, block_size=block_size, kernel=k))(
+                pools["bf16"][0], new, tables[:rows], starts)
+            for k in (False, True))
+        errs[f"paged_write_{name}"] = float(
+            jnp.any(loop[:-1] != kernel[:-1]))
+        assert bool(jnp.any(loop != pools["bf16"][0]))
 
     facts = {"interpret": False, "tolerance": KERNEL_TOL,
              "rel_err": {k: round(v, 5) for k, v in errs.items()}}
@@ -416,7 +448,7 @@ def phase_patterned(*, d: int = 2048, ff: int = 1536, experts: int = 64,
     # -- the paged kernel, 4 query heads a pool head -------------------------
     B, n_blk = 8, seq // block_size
     kk, kv, kq = jax.random.split(jax.random.PRNGKey(6), 3)
-    shape = (B * n_blk + 1, kv_heads, block_size, head_dim)
+    shape = (B * n_blk + 1, kv_heads, head_dim, block_size)  # pool layout
     kpool = jax.random.normal(kk, shape, f32).astype(bf16)
     vpool = jax.random.normal(kv, shape, f32).astype(bf16)
     q = jax.random.normal(kq, (B, 1, heads, head_dim), f32).astype(bf16)
@@ -429,10 +461,10 @@ def phase_patterned(*, d: int = 2048, ff: int = 1536, experts: int = 64,
 
     def copied(q, kp, vp):
         group = heads // kv_heads
-        return decode_reference(
-            q, gather_view(jnp.repeat(kp, group, 1), tables, seq_axis=2),
-            gather_view(jnp.repeat(vp, group, 1), tables, seq_axis=2),
-            (lengths - 1)[:, None])
+        return decode_reference(q, *(
+            jnp.swapaxes(gather_view(jnp.repeat(p, group, 1), tables,
+                                     seq_axis=3), 2, 3)
+            for p in (kp, vp)), (lengths - 1)[:, None])
 
     errs["paged_decode_grouped"] = rel_err(
         grouped(q, kpool, vpool), reference(copied, q, kpool, vpool))

@@ -128,8 +128,12 @@ def register_kernel_cost(name: str, model: Callable[[Any], dict]) -> None:
 
 
 def _pallas_name(eqn) -> str:
+    """The kernel function's name: where this jax keeps it, the kernel
+    jaxpr's debug info (a ``functools.partial`` gives its function's)."""
     nsi = eqn.params.get("name_and_src_info")
-    return getattr(nsi, "name", None) or eqn.params.get("name") or "?"
+    dbg = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+    return (getattr(nsi, "name", None) or eqn.params.get("name")
+            or getattr(dbg, "func_name", None) or "?")
 
 
 def _pallas_grid(eqn) -> int:

@@ -21,6 +21,7 @@ static shapes throughout (fixed seq_len — no dynamic padding).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -933,13 +934,19 @@ class MultiHeadAttention(nn.Module):
         ``block_tables`` (B, blocks_per_seq) maps each request's logical
         positions to physical blocks and ``index`` is a PER-REQUEST (B,)
         write-position vector (continuous batching: every slot sits at
-        its own length). Writes scatter the chunk through the table;
-        reads either stream the pool directly through the Pallas
-        block-table kernel (``decode_impl="pallas"``) or gather the
+        its own length). Writes go through the table into the donated
+        pool in place; reads either stream the pool directly through the
+        Pallas block-table kernel (``decode_impl="pallas"``) or gather the
         logical views and run the exact dense math of the non-paged
         branches — the per-row mask zeroes whatever junk the trash block
         and unwritten slots carry, which is what keeps the fallback
         token-identical to the one-shot path on CPU.
+
+        Off the historical dense branch the pool has ONE layout,
+        ``(N, h, hd, block_size)``: a block's slots on the lane axis, which
+        is how the device keeps such an array whatever shape it is declared
+        with (ops/decode_attention.py, the paged section's comment), so
+        neither the write nor the kernel's read relays a leaf out.
         """
         cfg = self.cfg
         if index is None or block_tables is None:
@@ -949,6 +956,7 @@ class MultiHeadAttention(nn.Module):
         from distributed_tensorflow_guide_tpu.serve.paged_cache import (
             gather_view,
             scatter_chunk,
+            write_chunk,
         )
 
         B, C, hq, hd = q.shape
@@ -969,9 +977,9 @@ class MultiHeadAttention(nn.Module):
             cv = self.variable("cache", "cached_value", jnp.zeros,
                                (N, bs, h, hd), cfg.dtype)
             ck.value = scatter_chunk(ck.value, k, block_tables, idx,
-                                     block_size=bs, seq_axis=1)
+                                     block_size=bs)
             cv.value = scatter_chunk(cv.value, v, block_tables, idx,
-                                     block_size=bs, seq_axis=1)
+                                     block_size=bs)
             keys = gather_view(ck.value, block_tables, seq_axis=1)
             vals = gather_view(cv.value, block_tables, seq_axis=1)
             if group > 1:
@@ -993,34 +1001,29 @@ class MultiHeadAttention(nn.Module):
 
         cache_dtype = jnp.int8 if quantized else cfg.dtype
         ck = self.variable("cache", "cached_key", jnp.zeros,
-                           (N, h, bs, hd), cache_dtype)
+                           (N, h, hd, bs), cache_dtype)
         cv = self.variable("cache", "cached_value", jnp.zeros,
-                           (N, h, bs, hd), cache_dtype)
-        kT = jnp.transpose(k, (0, 2, 1, 3))  # (B, H, C, hd)
-        vT = jnp.transpose(v, (0, 2, 1, 3))
+                           (N, h, hd, bs), cache_dtype)
+        # beside the Pallas read, the Pallas write (a grid step a block,
+        # not a loop step: serve/paged_cache.py write_chunk)
+        write = functools.partial(
+            write_chunk, tables=block_tables, index=idx, block_size=bs,
+            kernel=(impl == "pallas"
+                    and DA.paged_write_fits((h, hd, bs), cache_dtype)))
         ks = vs = None
         if quantized:
             ks = self.variable("cache", "key_scale", jnp.zeros,
                                (N, h, 1, bs), jnp.float32)
             vs = self.variable("cache", "value_scale", jnp.zeros,
                                (N, h, 1, bs), jnp.float32)
-            k8, k_sc = DA.quantize_kv(kT)
-            v8, v_sc = DA.quantize_kv(vT)
-            ck.value = scatter_chunk(ck.value, k8, block_tables, idx,
-                                     block_size=bs, seq_axis=2)
-            cv.value = scatter_chunk(cv.value, v8, block_tables, idx,
-                                     block_size=bs, seq_axis=2)
-            ks.value = scatter_chunk(ks.value, k_sc[:, :, None, :],
-                                     block_tables, idx,
-                                     block_size=bs, seq_axis=3)
-            vs.value = scatter_chunk(vs.value, v_sc[:, :, None, :],
-                                     block_tables, idx,
-                                     block_size=bs, seq_axis=3)
-        else:
-            ck.value = scatter_chunk(ck.value, kT, block_tables, idx,
-                                     block_size=bs, seq_axis=2)
-            cv.value = scatter_chunk(cv.value, vT, block_tables, idx,
-                                     block_size=bs, seq_axis=2)
+            k, k_sc = DA.quantize_kv(k)  # (B, C, H, hd), (B, C, H)
+            v, v_sc = DA.quantize_kv(v)
+            for leaf, scale in ((ks, k_sc), (vs, v_sc)):
+                leaf.value = write(
+                    leaf.value, jnp.transpose(scale, (0, 2, 1))[:, :, None])
+        for leaf, rows in ((ck, k), (cv, v)):
+            leaf.value = write(
+                leaf.value, jnp.transpose(rows, (0, 2, 3, 1)))  # (B,H,hd,C)
 
         lengths = idx + C  # (B,) live length after the write
         if impl == "pallas":
@@ -1045,13 +1048,13 @@ class MultiHeadAttention(nn.Module):
                         f"usable KV edge (resolved {blk_k}); falling back "
                         "to the gathered dense path (slower)")
 
-        # dense gather fallback on the kernel layout: identical math to
+        # dense gather fallback on the pool layout: identical math to
         # the non-paged kernel-layout branch, per-request mask rows
-        keys = gather_view(ck.value, block_tables, seq_axis=2)
-        vals = gather_view(cv.value, block_tables, seq_axis=2)
+        keys = gather_view(ck.value, block_tables, seq_axis=3)
+        vals = gather_view(cv.value, block_tables, seq_axis=3)
         if group > 1:
-            return self._grouped_dense(q, keys, vals, idx, "bhkd")
-        scores = jnp.einsum("bqhd,bhkd->bhqk", q,
+            return self._grouped_dense(q, keys, vals, idx, "bhdk")
+        scores = jnp.einsum("bqhd,bhdk->bhqk", q,
                             keys.astype(cfg.dtype)) / jnp.sqrt(
             hd).astype(cfg.dtype)
         if quantized:
@@ -1067,14 +1070,13 @@ class MultiHeadAttention(nn.Module):
         if quantized:
             probs = probs * v_scale
         probs = probs.astype(cfg.dtype)
-        return jnp.einsum("bhqk,bhkd->bqhd", probs,
+        return jnp.einsum("bhqk,bhdk->bqhd", probs,
                           vals.astype(cfg.dtype))
-
 
     def _grouped_dense(self, q, keys, vals, idx, layout: str):
         """The gathered dense math for ``group`` query heads a key/value
         head: ``q`` (B, C, h * group, hd) against the logical views
-        ``keys``/``vals`` in ``layout`` ("bkhd" or "bhkd"), under the same
+        ``keys``/``vals`` in ``layout`` ("bkhd" or "bhdk"), under the same
         per-row mask as the one-group lines beside its callers."""
         cfg = self.cfg
         B, C, hq, hd = q.shape

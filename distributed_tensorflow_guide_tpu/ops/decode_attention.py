@@ -372,24 +372,37 @@ def make_decode_runner(blk_k: int, *, b: int, h: int, s: int, d: int,
 # block and elide their DMA exactly as in the contiguous kernel. blk_k
 # must divide the pool block size: a tile never straddles two physical
 # blocks, which is what keeps the index map a pure table lookup.
+#
+# The pool is stored ``(num_blocks, Hkv, hd, block_size)``: a block's slots
+# lie along the LANE axis and the head dim along the sublanes. That is the
+# device's choice, not taste: with a head dim under 128 the TPU runtime
+# keeps a ``(N, H, block_size, hd)`` array with the block axis minor anyway
+# (a minor axis of 64 would leave half of every (8, 128) tile empty), while
+# a Pallas call takes its operands row-major — so a pool declared the other
+# way round is relaid out, whole, in front of every call (ROADMAP S8: 44% of
+# a serving launch). Declared as it lies, the kernel reads it in place: the
+# key tile arrives already transposed (``s = q @ kT``) and the value tile is
+# contracted over its lanes (``acc += p @ vT^T``).
 
 
 def paged_decode_blk_k_for(*, b: int, h: int, s: int, d: int, dtype,
                            block_size: int,
                            platform: str | None = None) -> int:
     """KV edge for the paged kernel: the ``decode_paged`` table entry when
-    one exists AND divides the pool block size, else the largest tested
-    default that does (``_default_blk_k(block_size)``, via the online
-    front door; a non-dividing stale result is re-clipped to it)."""
+    one exists AND tiles the pool block (:func:`_tiles_block`), else the
+    largest tested default that does (``_default_paged_blk_k``, via the
+    online front door; a stale result that does not is re-clipped to
+    it)."""
     hit = autotune.lookup(PAGED_DECODE_KERNEL, b=b, h=h, s=s, d=d,
                           dtype=dtype, causal=False, platform=platform)
-    if hit is not None and block_size % hit[1] == 0:
+    if hit is not None and _tiles_block(block_size, hit[1]):
         return hit[1]
     blk = autotune.ensure_tuned_online(
         PAGED_DECODE_KERNEL, b=b, h=h, s=s, d=d, dtype=dtype, causal=False,
         block_size=block_size, platform=platform,
-        fallback=lambda: _default_blk_k(block_size))
-    return blk if block_size % blk == 0 else _default_blk_k(block_size)
+        fallback=lambda: _default_paged_blk_k(block_size))
+    return (blk if _tiles_block(block_size, blk)
+            else _default_paged_blk_k(block_size))
 
 
 def ensure_paged_decode_tuned(*, b: int, h: int, s: int, d: int, dtype,
@@ -401,9 +414,9 @@ def ensure_paged_decode_tuned(*, b: int, h: int, s: int, d: int, dtype,
     them as failed candidates."""
 
     def measure(kern, blocks):
-        if block_size % blocks[1]:
+        if not _tiles_block(block_size, blocks[1]):
             raise ValueError(
-                f"blk_k {blocks[1]} does not divide block_size "
+                f"blk_k {blocks[1]} does not tile block_size "
                 f"{block_size}")
         fn = make_paged_decode_runner(blocks[1], b=b, h=h, s=s, d=d,
                                       dtype=dtype, block_size=block_size)
@@ -417,10 +430,28 @@ def ensure_paged_decode_tuned(*, b: int, h: int, s: int, d: int, dtype,
 
 def paged_supported(s: int, block_size: int, blk_k: int,
                     chunk: int = 1) -> bool:
-    """:func:`supported` plus the pool constraint: the KV edge divides the
-    physical block size (tiles never straddle blocks)."""
-    return (supported(s, blk_k, chunk) and block_size % blk_k == 0
+    """:func:`supported` plus the pool constraints: the KV edge tiles the
+    physical block (:func:`_tiles_block`) and blocks tile the view."""
+    return (supported(s, blk_k, chunk) and _tiles_block(block_size, blk_k)
             and s % block_size == 0)
+
+
+def _tiles_block(block_size: int, blk_k: int) -> bool:
+    """A kernel tile never straddles two physical blocks (``blk_k`` divides
+    the block size), and its ``blk_k`` slots are a LANE extent of the
+    stored pool: a tile smaller than a block is a multiple of 128 lanes,
+    else it is the whole block."""
+    return (block_size % blk_k == 0
+            and (blk_k == block_size or blk_k % LANE == 0))
+
+
+def _default_paged_blk_k(block_size: int) -> int:
+    """The paged cascade: the largest tested default edge that tiles the
+    block, else the block itself (sweep-free, like ``_default_blk_k``)."""
+    for cand in (DEFAULT_DECODE_BLK_K, 128):
+        if cand < block_size and _tiles_block(block_size, cand):
+            return cand
+    return block_size
 
 
 def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
@@ -445,10 +476,10 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
     @pl.when(j * blk_k < length)
     def _():
         q = q_ref[0, 0].astype(jnp.float32)  # (Cp, hd)
-        k = k_ref[0, 0].astype(jnp.float32)  # (blk_k, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        kT = k_ref[0, 0].astype(jnp.float32)  # (hd, blk_k): slots on lanes
+        vT = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, kT, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # (Cp, blk_k)
         if quantized:
@@ -473,7 +504,7 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
         if quantized:
             p = p * vs_ref[0, 0]
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, vT, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -490,15 +521,17 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
     """Length-aware cache attention reading a paged pool through tables.
 
     ``q``: (B, C, H, hd) public layout. ``key_pool``/``value_pool``:
-    (num_blocks, Hkv, block_size, hd) kernel layout (int8 with
-    (num_blocks, Hkv, 1, block_size) f32 scale pools when quantized);
-    ``Hkv`` divides ``H`` and ``H // Hkv`` query heads share a pool head.
+    (num_blocks, Hkv, hd, block_size) — the ONE pool layout, a block's
+    slots on the lane axis as the device stores them (the section comment
+    above says why; int8 with (num_blocks, Hkv, 1, block_size) f32 scale
+    pools when quantized); ``Hkv`` divides ``H`` and ``H // Hkv`` query
+    heads share a pool head.
     ``block_tables``: (B, blocks_per_seq) int32 physical block ids.
     ``lengths``: (B,) int32 per-request live lengths AFTER the chunk's
     write — request b's chunk occupies logical positions
-    [lengths[b] - C, lengths[b]). Only reads; the caller scatters the
-    chunk first (models/transformer.py _paged_decode_attend).
-    Returns (B, C, H, hd) in q's dtype.
+    [lengths[b] - C, lengths[b]). Only reads; the caller writes the
+    chunk first (models/transformer.py _paged_decode_attend, through
+    serve/paged_cache.py write_chunk). Returns (B, C, H, hd) in q's dtype.
     """
     B, C, H, hd = q.shape
     n_blk = block_tables.shape[1]
@@ -545,20 +578,18 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
         return h if group == 1 else h // group
 
     def kv_map(b, h, j, len_ref, bt_ref):
-        lj = live_j(b, j, len_ref)
-        return (bt_ref[b, lj // sub], pool_head(h), lj % sub, 0)
-
-    def sc_map(b, h, j, len_ref, bt_ref):
+        # keys, values and scale rows alike: a tile is ``blk_k`` lanes of
+        # one (block, pool head)
         lj = live_j(b, j, len_ref)
         return (bt_ref[b, lj // sub], pool_head(h), 0, lj % sub)
 
     q_spec = _vmem_spec((1, 1, cp, hd),
                         lambda b, h, j, L, T: (b, h, 0, 0))
-    kv_spec = _vmem_spec((1, 1, blk_k, hd), kv_map)
+    kv_spec = _vmem_spec((1, 1, hd, blk_k), kv_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qk, key_pool, value_pool]
     if quantized:
-        sc_spec = _vmem_spec((1, 1, 1, blk_k), sc_map)
+        sc_spec = _vmem_spec((1, 1, 1, blk_k), kv_map)
         in_specs += [sc_spec, sc_spec]
         operands += [key_scale_pool, value_scale_pool]
 
@@ -584,6 +615,74 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
     return jnp.transpose(out[:, :, :C], (0, 2, 1, 3))
 
 
+def _paged_write_kernel(phys_ref, first_ref, new_ref, pool_ref, out_ref, *,
+                        chunk: int):
+    # slot s of this block is position first + s of the row's chunk
+    at = first_ref[pl.program_id(0)] + jax.lax.broadcasted_iota(
+        jnp.int32, out_ref.shape, 3)
+    out_ref[...] = jnp.where((at >= 0) & (at < chunk), new_ref[...],
+                             pool_ref[...])
+
+
+def paged_write(pool, new, phys, first, *, chunk: int):
+    """The write that goes with :func:`paged_decode_attention`: put a
+    chunk's slots into the blocks they fall in, in the pool's own buffer.
+
+    ``pool``: (num_blocks, H, d, block_size), aliased to the result.
+    ``phys``/``first``: (n,) int32, one entry a touched block: its id,
+    and where its first slot lies in its row's chunk of ``chunk``
+    positions (negative when the chunk starts inside the block). ``new``:
+    (n, H, d, block_size), the chunk's values at each block's slots
+    (whatever elsewhere), or (n, H, d, 1) for a chunk of one position,
+    broadcast over the block. One grid step a block: read it, select,
+    write it back; blocks the grid does not visit keep their contents
+    because the buffer is the same. ``serve/paged_cache.py write_chunk``
+    prepares the operands and holds the semantics.
+
+    Two entries may name the same block only where its contents do not
+    matter (the trash block): a step's read may run before the step
+    before it has written back.
+
+    The call sits in a scope of its own, so a trace names it
+    ``paged_write.<n>`` and not after the attention method around it: the
+    benchmark finds the attention kernel by that method's name.
+    """
+    n = phys.shape[0]
+    block = (1,) + pool.shape[1:]
+    pool_spec = _vmem_spec(block, lambda i, P, F: (P[i], 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[
+            _vmem_spec((1,) + new.shape[1:], lambda i, P, F: (i, 0, 0, 0)),
+            pool_spec,
+        ],
+        out_specs=pool_spec,
+    )
+    with jax.named_scope("paged_write"):
+        return pl.pallas_call(
+            functools.partial(_paged_write_kernel, chunk=chunk),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+            input_output_aliases={3: 0},  # the pool: phys, first, new, pool
+            interpret=_interpret(),
+        )(jnp.asarray(phys, jnp.int32), jnp.asarray(first, jnp.int32), new,
+          pool)
+
+
+def paged_write_fits(block: tuple[int, ...], dtype) -> bool:
+    """Whether :func:`paged_write`'s grid step fits the VMEM a decode
+    kernel may use: a ``block`` (H, d, block_size) of the pool read, one
+    written and one of new values, each double-buffered. Where it does not,
+    the caller keeps ``write_chunk``'s loop."""
+    import math
+
+    import numpy as np
+
+    return (6 * math.prod(block) * np.dtype(dtype).itemsize
+            <= autotune.VMEM_BUDGET_BYTES)
+
+
 def make_paged_decode_runner(blk_k: int, *, b: int, h: int, s: int,
                              d: int, dtype, block_size: int,
                              chunk: int = 1, seed: int = 0):
@@ -604,17 +703,22 @@ def make_paged_decode_runner(blk_k: int, *, b: int, h: int, s: int,
                            jnp.float32)
     tables = jnp.arange(b * n_blk, dtype=jnp.int32).reshape(b, n_blk)
     lengths = jnp.full((b,), s, jnp.int32)
+
+    def as_pool(x):  # (N, H, bs, hd) as drawn -> the pool's (N, H, hd, bs)
+        return jnp.swapaxes(x, 2, 3)
+
     if quantized:
         k8, ks = quantize_kv(kf)
         v8, vs = quantize_kv(vf)
-        ops = (q, k8, v8, ks[:, :, None, :], vs[:, :, None, :])
+        ops = (q, as_pool(k8), as_pool(v8), ks[:, :, None, :],
+               vs[:, :, None, :])
 
         def call(q, k8, v8, ks, vs):
             return paged_decode_attention(
                 q, k8, v8, tables, lengths, key_scale_pool=ks,
                 value_scale_pool=vs, block_size=block_size, blk_k=blk_k)
     else:
-        ops = (q, kf.astype(dtype), vf.astype(dtype))
+        ops = (q, as_pool(kf.astype(dtype)), as_pool(vf.astype(dtype)))
 
         def call(q, k, v):
             return paged_decode_attention(
@@ -630,19 +734,28 @@ def make_paged_decode_runner(blk_k: int, *, b: int, h: int, s: int,
 # --------------------------------------------------------------------------
 
 
-def _attn_kernel_cost(eqn):
+def _block_dims(block_mapping) -> tuple[int, ...]:
+    """A BlockSpec's extents as plain ints (this jax wraps each blocked
+    dimension in a ``Blocked``)."""
+    return tuple(int(getattr(d, "block_size", d))
+                 for d in block_mapping.block_shape)
+
+
+def _attn_kernel_cost(eqn, *, slots_axis: int = 2):
     """Cost of one (paged or dense) decode-attention ``pallas_call`` for
     the static auditor — derived from the equation's grid and BlockSpecs,
     with the HBM side delegated to :func:`decode_kernel_hbm_bytes` so the
     auditor and the kernel microbench price the same call identically.
     The q/out chunk is counted at its lane-PADDED size (the BlockSpec is
     all the jaxpr knows); the dense static-shape ceiling, like the
-    closed form's default."""
+    closed form's default. ``slots_axis`` is where the key block keeps
+    its ``blk_k`` slots: 2 of the contiguous cache's (1, 1, blk_k, hd), 3
+    of the paged pool's (1, 1, hd, blk_k)."""
     gm = eqn.params["grid_mapping"]
     b, h, n_kv = (int(g) for g in gm.grid)
     bms = list(gm.block_mappings)
-    _, _, cp, hd = (int(d) for d in bms[0].block_shape)   # q block
-    blk_k = int(bms[1].block_shape[2])                    # k block
+    _, _, cp, hd = _block_dims(bms[0])                    # q block
+    blk_k = _block_dims(bms[1])[slots_axis]               # k block
     s = n_kv * blk_k
     k_aval = eqn.invars[gm.num_index_operands + 1].aval
     q_aval = eqn.outvars[0].aval
@@ -661,6 +774,24 @@ def _attn_kernel_cost(eqn):
     }
 
 
+def _paged_write_cost(eqn):
+    """Cost of one :func:`paged_write` call: every grid step reads a pool
+    block and its new values and writes the block back; the rest of the
+    aliased pool is not touched (the auditor's fallback would charge the
+    whole leaf, read and written)."""
+    import math
+
+    import numpy as np
+
+    gm = eqn.params["grid_mapping"]
+    n = int(gm.grid[0])
+    new, block, _ = (math.prod(_block_dims(bm))
+                     for bm in gm.block_mappings)
+    io = np.dtype(eqn.outvars[0].aval.dtype).itemsize
+    return {"flops": 0.0, "read": float(n * (new + block) * io),
+            "write": float(n * block * io)}
+
+
 def _register_kernel_costs():
     # analysis.cost is jax-free at import; the dependency edge ops ->
     # analysis is acyclic (analysis never imports ops at module scope)
@@ -669,7 +800,9 @@ def _register_kernel_costs():
     )
 
     register_kernel_cost("_decode_kernel", _attn_kernel_cost)
-    register_kernel_cost("_paged_decode_kernel", _attn_kernel_cost)
+    register_kernel_cost("_paged_decode_kernel",
+                         functools.partial(_attn_kernel_cost, slots_axis=3))
+    register_kernel_cost("_paged_write_kernel", _paged_write_cost)
 
 
 _register_kernel_costs()

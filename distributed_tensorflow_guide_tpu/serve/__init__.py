@@ -26,6 +26,7 @@ from distributed_tensorflow_guide_tpu.serve.paged_cache import (
     gather_view,
     scatter_chunk,
     table_row,
+    write_chunk,
 )
 from distributed_tensorflow_guide_tpu.serve.prefix_index import (
     PrefixIndex,
@@ -54,4 +55,5 @@ __all__ = [
     "paged_config",
     "scatter_chunk",
     "table_row",
+    "write_chunk",
 ]

@@ -14,20 +14,35 @@ The split of responsibilities keeps every compiled shape static:
 
 * **host Python** (:class:`BlockPool`) allocates, frees and evicts blocks
   — a free-list the scheduler drives between steps; nothing here traces;
-* **device code** (:func:`gather_view` / :func:`scatter_chunk`) reads and
-  writes through the table *inside* the compiled step: a gather by block
-  id materializes a request's logical cache view, a scatter by
-  ``table[pos // bs] * bs + pos % bs`` writes a chunk — both are plain
-  static-shape XLA ops, so the engine's step program never retraces as
-  the resident population changes.
+* **device code** (:func:`gather_view`, :func:`write_chunk`,
+  :func:`scatter_chunk`) reads and writes through the table *inside* the
+  compiled step: a gather by block id materializes a request's logical
+  cache view, a write puts a chunk's positions ``p`` into slot ``p % bs``
+  of block ``table[p // bs]`` — all plain static-shape XLA ops, so the
+  engine's step program never retraces as the resident population
+  changes.
 
 Unallocated logical blocks point at the reserved **trash block** (the
 pool's last id): inactive decode slots write there and the attention
 mask hides anything read from it, so the device program needs no branch
-on liveness.  The helpers are layout-agnostic (``seq_axis`` names the
-blocked axis) because the cache collection has three leaf layouts —
-legacy ``(B, S, H, hd)``, kernel ``(B, H, S, hd)`` and the quantized
-scale rows ``(B, H, 1, S)``.
+on liveness.
+
+The pool has two leaf layouts, and a serving configuration uses one:
+
+* **the pool layout** ``(N, H, d, block_size)`` — keys and values with
+  ``d`` the head dim, the int8 cache's scale rows with ``d = 1`` — of every
+  path but the historical one: a block's slots lie on the LANE axis. That
+  is the device's tile, not taste. With a head dim under 128 the TPU keeps
+  a ``(N, H, block_size, hd)`` array with the block axis minor whatever
+  the program declares (a minor axis of 64 fills half of an (8, 128)
+  tile), while a Pallas kernel takes its operands row-major and XLA's
+  scatter picks a third order: declared the other way round, every leaf
+  was copied whole three to four times a launch (ROADMAP S8). Declared as
+  it lies, :func:`write_chunk` updates the donated leaf in place and
+  ``ops/decode_attention.py paged_decode_attention`` reads it as stored;
+* **the legacy layout** ``(N, block_size, H, hd)`` of the unquantized
+  ``decode_impl="dense"`` path (what the CPU runs; the hermeticity pin),
+  written by :func:`scatter_chunk`.
 """
 
 from __future__ import annotations
@@ -36,6 +51,7 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 # --------------------------------------------------------------------------
 # device-side: gather / scatter through a block table
@@ -61,29 +77,95 @@ def gather_view(pool, tables, *, seq_axis: int):
     return g.reshape(merged)
 
 
-def scatter_chunk(pool, chunk, tables, index, *, block_size: int,
-                  seq_axis: int):
-    """Write per-request chunks into the pool through the block tables.
+def scatter_chunk(pool, chunk, tables, index, *, block_size: int):
+    """Write per-request chunks into a LEGACY-layout pool ``(N, bs, *rest)``
+    through the block tables.
 
-    ``chunk`` is ``(B, *dims)`` with C positions along ``seq_axis``;
-    request b's chunk lands at logical positions ``[index[b],
-    index[b] + C)``, i.e. physical row ``tables[b, p // bs] * bs +
-    p % bs`` of the block-flattened pool.  Rows of requests whose table
-    points at the trash block land there harmlessly (never read back).
-    Static shapes; one scatter.
+    ``chunk`` is ``(B, C, *rest)``; request b's chunk lands at logical
+    positions ``[index[b], index[b] + C)``, i.e. physical row ``tables[b,
+    p // bs] * bs + p % bs`` of the block-flattened pool.  Rows of
+    requests whose table points at the trash block land there harmlessly
+    (never read back).  Static shapes; one scatter.
     """
-    B = chunk.shape[0]
-    C = chunk.shape[seq_axis]
+    B, C = chunk.shape[:2]
     pos = index[:, None] + jnp.arange(C)[None, :]  # (B, C)
     phys = jnp.take_along_axis(tables, pos // block_size, axis=1)
     lin = phys * block_size + pos % block_size  # (B, C) flattened rows
-    p = jnp.moveaxis(pool, seq_axis, 1)  # (N, bs, *rest)
-    rest = p.shape[2:]
-    flat = p.reshape((p.shape[0] * block_size,) + rest)
-    rows = jnp.moveaxis(chunk, seq_axis, 1).reshape((B * C,) + rest)
-    flat = flat.at[lin.reshape(-1)].set(rows)
-    p = flat.reshape((pool.shape[0], block_size) + rest)
-    return jnp.moveaxis(p, 1, seq_axis)
+    rest = pool.shape[2:]
+    flat = pool.reshape((pool.shape[0] * block_size,) + rest)
+    flat = flat.at[lin.reshape(-1)].set(chunk.reshape((B * C,) + rest))
+    return flat.reshape(pool.shape)
+
+
+def write_chunk(pool, chunk, tables, index, *, block_size: int,
+                kernel: bool = False):
+    """Write per-request chunks into a pool-layout leaf ``(N, H, d, bs)``
+    through the block tables, IN PLACE when the leaf is donated.
+
+    ``chunk`` is ``(B, H, d, C)``; request b's chunk lands at logical
+    positions ``[index[b], index[b] + C)``: position ``p`` in slot ``p %
+    bs`` of block ``tables[b, p // bs]``, the contents
+    :func:`scatter_chunk` gives a legacy pool, for any chunk length and
+    start (a chunk may straddle blocks).  Rows whose table points at the
+    trash block land there harmlessly, and so does a position past the
+    table's last block.
+
+    The unit is one ``(row, touched block)`` pair: read the block, take
+    the chunk's slots where they fall in it, put it back.  The plain form
+    is a loop of ``dynamic_update_slice``, whose updates are the only
+    operations of a leaf's size and which XLA does in the leaf's buffer
+    (a scatter would not be: on a TPU it picks a layout of its own and
+    copies the leaf there and back).  ``kernel=True`` hands the same pairs
+    to ``ops/decode_attention.py paged_write``, one grid step each over
+    the aliased leaf: what the Pallas decode path uses, because the loop's
+    steps cost the device about twice a grid step (GPT-2 XL's 96 leaves of
+    24 rows: 10.6 ms against 5.7; PERF.md section 6, PR 29).
+    """
+    B, C = chunk.shape[0], chunk.shape[-1]
+    bs, n_blk, trash = block_size, tables.shape[1], pool.shape[0] - 1
+    touched = (C + bs - 2) // bs + 1  # blocks C positions can reach
+    logical = index[:, None] // bs + jnp.arange(touched)  # (B, touched)
+    phys = jnp.where(
+        logical < n_blk,
+        jnp.take_along_axis(tables, jnp.minimum(logical, n_blk - 1), axis=1),
+        trash).reshape(-1)
+    # where each touched block's first slot lies in its row's chunk
+    first = (logical * bs - index[:, None]).reshape(-1)
+    chunk = chunk.astype(pool.dtype)
+    block = (1,) + pool.shape[1:]
+
+    if C == 1:
+        # the one slot a decode row writes, broadcast over the block
+        def window(i):
+            return lax.dynamic_slice(chunk, (i, 0, 0, 0), block[:3] + (1,))
+    else:
+        # a block of slack either side: every touched block's slots are
+        # one static-size slice of the row's chunk
+        padded = jnp.pad(chunk, ((0, 0),) * 3 + ((bs, bs),))
+
+        def window(i):
+            return lax.dynamic_slice(
+                padded, (i // touched, 0, 0, first[i] + bs), block)
+
+    if kernel:
+        from distributed_tensorflow_guide_tpu.ops.decode_attention import (
+            paged_write,
+        )
+
+        new = chunk if C == 1 else jnp.concatenate(
+            [window(i) for i in range(B * touched)])
+        return paged_write(pool, new, phys, first, chunk=C)
+
+    slot = jnp.arange(bs)
+
+    def write(i, pool):
+        at = (phys[i], 0, 0, 0)
+        mine = (first[i] + slot >= 0) & (first[i] + slot < C)
+        old = lax.dynamic_slice(pool, at, block)
+        return lax.dynamic_update_slice(
+            pool, jnp.where(mine, window(i), old), at)
+
+    return lax.fori_loop(0, B * touched, write, pool)
 
 
 # --------------------------------------------------------------------------

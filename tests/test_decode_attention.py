@@ -283,14 +283,15 @@ def test_vmem_model_and_candidates_valid():
 def _paged(k, v, bs, *, ks=None, vs=None, seed=11):
     """Scatter dense (B, H, S, hd) caches into a SHUFFLED physical pool
     plus the block tables mapping them back — non-identity tables are the
-    point: the kernel must resolve every tile through the indirection."""
+    point: the kernel must resolve every tile through the indirection.
+    The pool is in the one pool layout, (N, H, hd, bs): slots on lanes."""
     k, v = np.asarray(k), np.asarray(v)
     b, h, s, hd = k.shape
     n_blk = s // bs
     perm = np.random.RandomState(seed).permutation(b * n_blk)
     nb = b * n_blk + 1  # + the trash block convention
-    kp = np.zeros((nb, h, bs, hd), k.dtype)
-    vp = np.zeros((nb, h, bs, hd), v.dtype)
+    kp = np.zeros((nb, h, hd, bs), k.dtype)
+    vp = np.zeros((nb, h, hd, bs), v.dtype)
     ksp = np.ones((nb, h, 1, bs), np.float32)
     vsp = np.ones((nb, h, 1, bs), np.float32)
     tables = np.zeros((b, n_blk), np.int32)
@@ -298,7 +299,8 @@ def _paged(k, v, bs, *, ks=None, vs=None, seed=11):
         for j in range(n_blk):
             p = int(perm[bi * n_blk + j])
             sl = slice(j * bs, (j + 1) * bs)
-            kp[p], vp[p] = k[bi, :, sl], v[bi, :, sl]
+            kp[p] = k[bi, :, sl].transpose(0, 2, 1)
+            vp[p] = v[bi, :, sl].transpose(0, 2, 1)
             if ks is not None:
                 ksp[p, :, 0] = np.asarray(ks)[bi, :, sl]
                 vsp[p, :, 0] = np.asarray(vs)[bi, :, sl]
@@ -309,21 +311,23 @@ def _paged(k, v, bs, *, ks=None, vs=None, seed=11):
     return out
 
 
-def test_paged_kernel_matches_dense_oracle_through_shuffled_tables():
+@pytest.mark.parametrize("s,bs,blk_k", [(S, 32, 32), (512, 256, 128)])
+def test_paged_kernel_matches_dense_oracle_through_shuffled_tables(s, bs,
+                                                                   blk_k):
     """Single-token decode against the paged pool: per-request lengths,
     shuffled block tables, parity with the dense oracle on the contiguous
-    view the tables encode."""
-    k, v = _cache(10)
+    view the tables encode — a tile the whole block, and two 128-lane
+    tiles a block."""
+    k, v = _cache(10, s=s)
     q = _q(seed=12)
-    bs = 32
     kp, vp, tables = _paged(k, v, bs)
-    for lengths in ([S, S], [42, 97], [1, S]):
+    for lengths in ([s, s], [42, 97], [1, s]):
         got = DA.paged_decode_attention(
             q, kp, vp, tables, jnp.asarray(lengths, jnp.int32),
-            block_size=bs, blk_k=16)
+            block_size=bs, blk_k=blk_k)
         for bi, ln in enumerate(lengths):
             ref = _dense_oracle(q[bi:bi + 1], k[bi:bi + 1],
-                                v[bi:bi + 1], ln - 1)
+                                v[bi:bi + 1], ln - 1, s=s)
             np.testing.assert_allclose(
                 got[bi:bi + 1], ref, atol=1e-5, rtol=1e-5,
                 err_msg=f"req {bi} length {ln}")
@@ -341,7 +345,7 @@ def test_paged_chunk_parity():
     lengths = [60, S]
     got = DA.paged_decode_attention(
         q, kp, vp, tables, jnp.asarray(lengths, jnp.int32),
-        block_size=bs, blk_k=16)
+        block_size=bs, blk_k=32)
     assert got.shape == (B, c, H, HD)
     for bi, ln in enumerate(lengths):
         ref = _dense_oracle(q[bi:bi + 1], k[bi:bi + 1], v[bi:bi + 1],
@@ -365,12 +369,61 @@ def test_paged_int8_parity():
     got = DA.paged_decode_attention(
         q, k8p, v8p, tables, jnp.asarray(lengths, jnp.int32),
         key_scale_pool=ksp, value_scale_pool=vsp, block_size=bs,
-        blk_k=16)
+        blk_k=32)
     for bi, ln in enumerate(lengths):
         ref = _dense_oracle(q[bi:bi + 1], kd[bi:bi + 1], vd[bi:bi + 1],
                             ln - 1)
         np.testing.assert_allclose(got[bi:bi + 1], ref, atol=1e-5,
                                    rtol=1e-5, err_msg=f"req {bi}")
+
+
+@pytest.mark.parametrize("group", [1, 3])
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+def test_paged_kernel_matches_gathered_dense_math(cache, group):
+    """The kernel reading the pool as it is stored, (N, Hkv, hd, bs),
+    against the dense math on the views ``gather_view`` makes of the same
+    pool (what the fallback runs): a bfloat16 and an int8 pool, one pool
+    head a query head and one under three, a 5-token chunk."""
+    from distributed_tensorflow_guide_tpu.serve.paged_cache import (
+        gather_view,
+    )
+
+    r = np.random.RandomState(21)
+    bs, n_blk, c, kv = 32, 4, 5, H // group
+    nb = B * n_blk + 1
+    q = jnp.asarray(r.randn(B, c, H, HD), jnp.bfloat16)
+    kf = jnp.asarray(r.randn(nb, kv, HD, bs), jnp.float32)
+    vf = jnp.asarray(r.randn(nb, kv, HD, bs), jnp.float32)
+    tables = jnp.asarray(r.permutation(nb - 1).reshape(B, n_blk), jnp.int32)
+    lengths = jnp.asarray([77, 33], jnp.int32)
+    if cache == "int8":
+        # quantize_kv scales a vector of hd values: the pool's axis 2
+        kp, ks = DA.quantize_kv(jnp.swapaxes(kf, 2, 3))
+        vp, vs = DA.quantize_kv(jnp.swapaxes(vf, 2, 3))
+        kp, vp = jnp.swapaxes(kp, 2, 3), jnp.swapaxes(vp, 2, 3)
+        ks, vs = ks[:, :, None, :], vs[:, :, None, :]  # (N, Hkv, 1, bs)
+        scales = dict(key_scale_pool=ks, value_scale_pool=vs)
+    else:
+        kp, vp, scales = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}
+    got = DA.paged_decode_attention(q, kp, vp, tables, lengths,
+                                    block_size=bs, **scales)
+    assert got.shape == (B, c, H, HD) and got.dtype == jnp.bfloat16
+
+    def view(pool):  # (B, Hkv, d, S) -> a pool head beside each query head
+        return jnp.repeat(gather_view(pool, tables, seq_axis=3)
+                          .astype(jnp.float32), group, axis=1)
+
+    keys, vals = view(kp), view(vp)
+    if cache == "int8":
+        keys, vals = keys * view(ks), vals * view(vs)
+    scores = jnp.einsum("bqhd,bhdk->bhqk", q.astype(jnp.float32),
+                        keys) / jnp.sqrt(HD)
+    q_pos = (lengths - c)[:, None] + jnp.arange(c)  # (B, C)
+    mask = jnp.arange(n_blk * bs)[None, None, :] <= q_pos[:, :, None]
+    scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
+    want = jnp.einsum("bhqk,bhdk->bqhd", jax.nn.softmax(scores, -1), vals)
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2,
+                               rtol=2e-2)
 
 
 def test_paged_dead_blocks_cannot_leak():
@@ -383,7 +436,7 @@ def test_paged_dead_blocks_cannot_leak():
     kp, vp, tables = _paged(k, v, bs)
     lengths = jnp.asarray([42, 10], jnp.int32)
     want = DA.paged_decode_attention(q, kp, vp, tables, lengths,
-                                     block_size=bs, blk_k=16)
+                                     block_size=bs, blk_k=32)
     kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
     for bi in range(B):
         ln = int(lengths[bi])
@@ -392,40 +445,49 @@ def test_paged_dead_blocks_cannot_leak():
             if j * bs >= ln:  # fully dead block
                 kp2[p], vp2[p] = 1e6, -1e6
             elif (j + 1) * bs > ln:  # partially live: poison the tail
-                kp2[p, :, ln - j * bs:] = 1e6
-                vp2[p, :, ln - j * bs:] = -1e6
+                kp2[p, :, :, ln - j * bs:] = 1e6
+                vp2[p, :, :, ln - j * bs:] = -1e6
     got = DA.paged_decode_attention(q, jnp.asarray(kp2),
                                     jnp.asarray(vp2), tables, lengths,
-                                    block_size=bs, blk_k=16)
+                                    block_size=bs, blk_k=32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_paged_blk_k_resolution_and_supported():
-    # a tuned edge that divides the pool block is honored
-    autotune._mem[autotune._key(autotune.PAGED_DECODE_KERNEL, 0, 0, S,
-                                HD, "float32", False, "cpu")] = {
-        "blk_q": 8, "blk_k": 16}
-    assert DA.paged_decode_blk_k_for(b=B, h=H, s=S, d=HD,
-                                     dtype=jnp.float32,
-                                     block_size=32) == 16
-    # a tuned edge that would straddle physical blocks is ignored: the
-    # divisor ladder picks the largest default that fits the block
-    autotune._mem[autotune._key(autotune.PAGED_DECODE_KERNEL, 0, 0, S,
-                                HD, "float32", False, "cpu")] = {
-        "blk_q": 8, "blk_k": 64}
-    assert DA.paged_decode_blk_k_for(b=B, h=H, s=S, d=HD,
-                                     dtype=jnp.float32,
-                                     block_size=32) == 32
-    assert DA.paged_supported(S, 32, 16)
+    def tuned(blk_k):
+        autotune._mem[autotune._key(autotune.PAGED_DECODE_KERNEL, 0, 0, 512,
+                                    HD, "float32", False, "cpu")] = {
+            "blk_q": 8, "blk_k": blk_k}
+
+    def resolve(block_size):
+        return DA.paged_decode_blk_k_for(b=B, h=H, s=512, d=HD,
+                                         dtype=jnp.float32,
+                                         block_size=block_size)
+
+    # a tuned edge that tiles the pool block is honored: it divides the
+    # block, and a tile smaller than a block is whole 128-lane groups
+    # (the pool keeps a block's slots on the lane axis)
+    tuned(128)
+    assert resolve(256) == 128
+    # one that would straddle physical blocks is ignored, and so is one
+    # that is no lane extent: the block itself is the tile then
+    tuned(64)
+    assert resolve(32) == 32
+    tuned(16)
+    assert resolve(32) == 32
+    assert resolve(512) == 256  # ... or the cascade's largest that tiles it
+    assert DA.paged_supported(S, 32, 32)
+    assert DA.paged_supported(512, 256, 128)
+    assert not DA.paged_supported(S, 32, 16)  # 16 lanes are no tile
     assert not DA.paged_supported(S, 32, 64)  # tile straddles blocks
-    assert not DA.paged_supported(120, 32, 16)  # ragged final block
-    assert not DA.paged_supported(S, 32, 16,
+    assert not DA.paged_supported(120, 32, 32)  # ragged final block
+    assert not DA.paged_supported(S, 32, 32,
                                   chunk=autotune.DECODE_MAX_CHUNK + 1)
     # a straddling blk_k is refused outright at call time
     with pytest.raises(ValueError, match="unsupported"):
         DA.paged_decode_attention(
-            _q(seed=19), jnp.zeros((9, H, 32, HD)),
-            jnp.zeros((9, H, 32, HD)),
+            _q(seed=19), jnp.zeros((9, H, HD, 32)),
+            jnp.zeros((9, H, HD, 32)),
             jnp.zeros((B, 4), jnp.int32), jnp.asarray([1, 1]),
             block_size=32, blk_k=64)
 
@@ -463,6 +525,7 @@ def test_paged_runner_executes_and_matches_oracle():
     assert out.shape == (1, 1, 2, 16)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(keys[0], (1, 1, 2, 16), jnp.float32)
+    # the runner draws (N, H, bs, hd) and hands the kernel its transpose
     kf = jax.random.normal(keys[1], (5, 2, 16, 16), jnp.float32)
     vf = jax.random.normal(keys[2], (5, 2, 16, 16), jnp.float32)
     kd = jnp.concatenate([kf[j] for j in range(4)], axis=1)[None]

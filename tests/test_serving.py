@@ -40,6 +40,7 @@ from distributed_tensorflow_guide_tpu.serve import (
     gather_view,
     scatter_chunk,
     table_row,
+    write_chunk,
 )
 from distributed_tensorflow_guide_tpu.serve.scheduler import Scheduler, _Slot
 from distributed_tensorflow_guide_tpu.testing.chaos import (
@@ -307,8 +308,7 @@ def test_gather_scatter_roundtrip_and_trash_isolation():
     # physical blocks 2 and 0) while request 1's row points at trash
     chunk = jnp.asarray(r.randn(2, 4, H, hd), jnp.float32)
     idx = jnp.asarray([2, 0], jnp.int32)
-    out = scatter_chunk(pool, chunk, tables, idx, block_size=bs,
-                        seq_axis=1)
+    out = scatter_chunk(pool, chunk, tables, idx, block_size=bs)
     got = gather_view(out, tables, seq_axis=1)
     np.testing.assert_array_equal(np.asarray(got[0, 2:6]),
                                   np.asarray(chunk[0]))
@@ -321,6 +321,73 @@ def test_gather_scatter_roundtrip_and_trash_isolation():
     # (request 0 touched only physical blocks 2 and 0)
     np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(pool[1]))
     np.testing.assert_array_equal(np.asarray(out[3]), np.asarray(pool[3]))
+
+
+def _to_legacy(x):
+    """A pool-layout leaf or chunk (.., H, d, slots) as the legacy layout
+    holds it: (.., slots, H, d)."""
+    return jnp.transpose(x, (0, 3, 1, 2))
+
+
+# request 0 owns blocks 2, 0, 3; request 1 owns 1 and then nothing (the
+# trash id is 4); request 2 is an inactive slot, all trash
+_WRITE_TABLES = [[2, 0, 3], [1, 4, 4], [4, 4, 4]]
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["loop", "pallas"])
+@pytest.mark.parametrize("chunk,starts", [
+    (1, [5, 3, 0]),    # a decode step: one slot a row
+    (4, [2, 0, 0]),    # chunk == block size, straddling blocks 2 and 0
+    (6, [3, 0, 6]),    # chunk > block size: three blocks touched
+    (3, [8, 1, 2]),    # chunk < block size, inside one block
+    (5, [7, 2, 6]),    # a tail that runs off request 1's blocks: trash
+])
+def test_write_chunk_gives_the_pool_scatter_chunk_gives(kernel, chunk,
+                                                        starts):
+    """The pool-layout write (its loop and its Pallas form) against
+    scatter_chunk's semantics on the legacy layout, bitwise on every owned
+    block: straddling chunks, chunk != block size, trash-routed rows."""
+    r = np.random.RandomState(chunk)
+    N, bs, H, d = 5, 4, 2, 3
+    pool = jnp.asarray(r.randn(N, H, d, bs), jnp.float32)
+    rows = jnp.asarray(r.randn(3, H, d, chunk), jnp.float32)
+    tables = jnp.asarray(_WRITE_TABLES, jnp.int32)
+    idx = jnp.asarray(starts, jnp.int32)
+    want = scatter_chunk(_to_legacy(pool), _to_legacy(rows), tables, idx,
+                         block_size=bs)
+    got = jax.jit(lambda *a: write_chunk(*a, block_size=bs, kernel=kernel))(
+        pool, rows, tables, idx)
+    np.testing.assert_array_equal(np.asarray(_to_legacy(got))[:N - 1],
+                                  np.asarray(want)[:N - 1])
+    # and by hand: every position of request 0, and the blocks it left
+    written = set()
+    for c in range(chunk):
+        block, slot = divmod(starts[0] + c, bs)
+        written.add(_WRITE_TABLES[0][block])
+        np.testing.assert_array_equal(
+            np.asarray(got[_WRITE_TABLES[0][block], :, :, slot]),
+            np.asarray(rows[0, :, :, c]))
+    for block in {2, 0, 3} - written:
+        np.testing.assert_array_equal(np.asarray(got[block]),
+                                      np.asarray(pool[block]))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["loop", "pallas"])
+def test_write_chunk_past_the_table_lands_in_trash(kernel):
+    """Positions past a row's last table entry touch no owned block (the
+    legacy scatter wrapped them onto block 0: nothing relied on it)."""
+    r = np.random.RandomState(1)
+    N, bs, H, d = 5, 4, 2, 1  # d == 1: the int8 cache's scale rows
+    pool = jnp.asarray(r.randn(N, H, d, bs), jnp.float32)
+    rows = jnp.asarray(r.randn(1, H, d, 6), jnp.float32)
+    tables = jnp.asarray([[2, 0, 3]], jnp.int32)
+    got = write_chunk(pool, rows, tables, jnp.asarray([9], jnp.int32),
+                      block_size=bs, kernel=kernel)
+    np.testing.assert_array_equal(np.asarray(got[3, :, :, 1:]),
+                                  np.asarray(rows[0, :, :, :3]))
+    np.testing.assert_array_equal(np.asarray(got[3, :, :, 0]),
+                                  np.asarray(pool[3, :, :, 0]))
+    np.testing.assert_array_equal(np.asarray(got[:3]), np.asarray(pool[:3]))
 
 
 # ---- paged byte model -------------------------------------------------------

@@ -19,6 +19,7 @@ take tens of seconds of host compile and are marked ``slow``.
 
 import functools
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -167,7 +168,7 @@ def test_paged_decode_kernel_compiles(v5e, as_on_tpu, cache, block_size,
     b = 8 if chunk == 1 else 1  # a decode step, or one prefill chunk
     n_blk = S // block_size
     q = sds((b, chunk, H, HD), jnp.bfloat16)
-    pool = sds((8 * n_blk + 1, H, block_size, HD),
+    pool = sds((8 * n_blk + 1, H, HD, block_size),
                jnp.int8 if cache == "int8" else jnp.bfloat16)
     scale = sds((8 * n_blk + 1, H, 1, block_size), jnp.float32)
 
@@ -193,7 +194,7 @@ def test_paged_decode_kernel_compiles_under_grouped_heads(v5e, as_on_tpu,
     b, heads, kv_heads, block_size, n_blk = (64 if chunk == 1 else 1, 32, 8,
                                              128, 16)
     q = sds((b, chunk, heads, HD), jnp.bfloat16)
-    pool = sds((1024, kv_heads, block_size, HD), jnp.bfloat16)
+    pool = sds((1024, kv_heads, HD, block_size), jnp.bfloat16)
 
     def fn(q, k, v, tables, lengths):
         return DA.paged_decode_attention(q, k, v, tables, lengths,
@@ -202,6 +203,55 @@ def test_paged_decode_kernel_compiles_under_grouped_heads(v5e, as_on_tpu,
     assert_mosaic(compile_for(
         SingleDeviceSharding(v5e[0]), fn, q, pool, pool,
         sds((b, n_blk), jnp.int32), sds((b,), jnp.int32)))
+
+
+def leaf_sized_results(text: str, leaf: tuple[int, ...]) -> list[str]:
+    """The instructions of a compiled program whose result has a pool
+    leaf's shape, in any layout: ``name opcode`` each (parameters and the
+    tuple plumbing of a loop left out: they move nothing)."""
+    dims = ",".join(map(str, leaf))
+    found = re.findall(
+        r"%(\S+) = \w+\[" + dims + r"\]\{[^}]*\} ([\w-]+)\(", text)
+    return [f"{name} {op}" for name, op in found
+            if op not in ("parameter", "get-tuple-element")]
+
+
+@pytest.mark.parametrize("chunk", [1, 128])
+@pytest.mark.parametrize("cell", ["gpt2-xl", "lfm2-24b-a2b"])
+def test_paged_layer_moves_no_leaf(v5e, as_on_tpu, cell, chunk):
+    """One attention layer's cache write and paged kernel at the serving
+    cells' sizes, leaves donated: nothing of a leaf's size happens but the
+    write itself, in the leaf's own buffer (ROADMAP S8: a pool declared
+    (N, h, bs, hd) was copied whole three to four times a leaf here)."""
+    from distributed_tensorflow_guide_tpu.serve.paged_cache import write_chunk
+
+    blocks, heads, kv_heads, rows, n_blk = {
+        "gpt2-xl": (129, 25, 25, 24, 8),
+        "lfm2-24b-a2b": (1024, 32, 8, 64, 16)}[cell]
+    b, block_size = rows if chunk == 1 else 1, 128
+    leaf = (blocks, kv_heads, HD, block_size)
+
+    def layer(kp, vp, q, k, v, tables, index):
+        kp, vp = (write_chunk(p, jnp.transpose(x, (0, 2, 3, 1)), tables,
+                              index, block_size=block_size, kernel=True)
+                  for p, x in ((kp, k), (vp, v)))
+        return kp, vp, DA.paged_decode_attention(
+            q, kp, vp, tables, index + chunk, block_size=block_size)
+
+    kv = sds((b, chunk, kv_heads, HD), jnp.bfloat16)
+    compiled = compile_for(
+        SingleDeviceSharding(v5e[0]), jax.jit(layer, donate_argnums=(0, 1)),
+        sds(leaf, jnp.bfloat16), sds(leaf, jnp.bfloat16),
+        sds((b, chunk, heads, HD), jnp.bfloat16), kv, kv,
+        sds((b, n_blk), jnp.int32), sds((b,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # two writes and the kernel
+    moved = leaf_sized_results(text, leaf)
+    assert len(moved) == 2 and all("custom-call" in m for m in moved), moved
+    mem = compiled.memory_analysis()
+    leaf_bytes = 2 * blocks * kv_heads * HD * block_size
+    assert mem.alias_size_in_bytes == 2 * leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes // 8
 
 
 @pytest.mark.parametrize("rows,first,held", [(64, 0, 64), (128, 0, 64),
