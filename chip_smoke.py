@@ -170,8 +170,8 @@ def phase_device(devices, *, n: int = 8192, chain: int = 16) -> dict:
 
 
 def decode_reference(q, keys, vals, q_pos, k_scale=None, v_scale=None):
-    """The dense kernel-layout decode math of models/transformer.py
-    (``_paged_decode_attend``'s gather fallback), in f32: q (B, C, H, hd),
+    """The dense decode read of models/transformer.py
+    (``_dense_cache_read``), stated again in f32: q (B, C, H, hd),
     keys/vals (B, H, S, hd), q_pos (B, C) absolute positions, scales
     (B, H, 1, S) when the cache is int8."""
     import jax
@@ -298,11 +298,11 @@ def phase_kernels(*, heads: int = 12, head_dim: int = 64, seq: int = 1024,
     def paged_ref(q, kp, vp, tab, lens, ksp=None, vsp=None):
         C = q.shape[1]
         q_pos = (lens - C)[:, None] + jnp.arange(C)[None, :]
-        scales = () if ksp is None else (gather_view(ksp, tab, seq_axis=3),
-                                         gather_view(vsp, tab, seq_axis=3))
+        scales = () if ksp is None else (gather_view(ksp, tab),
+                                         gather_view(vsp, tab))
         return decode_reference(
-            q, as_pool(gather_view(kp, tab, seq_axis=3)),
-            as_pool(gather_view(vp, tab, seq_axis=3)), q_pos, *scales)
+            q, as_pool(gather_view(kp, tab)),
+            as_pool(gather_view(vp, tab)), q_pos, *scales)
 
     paged_jit = jax.jit(paged)
     pools = {"bf16": (as_pool(kpool.astype(jnp.bfloat16)),
@@ -462,8 +462,7 @@ def phase_patterned(*, d: int = 2048, ff: int = 1536, experts: int = 64,
     def copied(q, kp, vp):
         group = heads // kv_heads
         return decode_reference(q, *(
-            jnp.swapaxes(gather_view(jnp.repeat(p, group, 1), tables,
-                                     seq_axis=3), 2, 3)
+            jnp.swapaxes(gather_view(jnp.repeat(p, group, 1), tables), 2, 3)
             for p in (kp, vp)), (lengths - 1)[:, None])
 
     errs["paged_decode_grouped"] = rel_err(

@@ -3,7 +3,7 @@
 The reference is a training tutorial and has no inference path at all;
 this is capability the TPU build adds on top of parity. TPU-first shape:
 
-* **Static shapes everywhere.** The KV cache is a fixed (B, max_len, H,
+* **Static shapes everywhere.** The KV cache is a fixed (B, H, max_len,
   hd) buffer per layer (flax "cache" collection, written with
   ``lax.dynamic_update_slice``); the decode loop is ONE ``lax.scan`` whose
   body processes exactly one token — the whole generate call compiles to
@@ -220,7 +220,7 @@ def make_generate_fn(cfg: TransformerConfig, *, max_new_tokens: int,
     * ``donate_cache`` (default True): the cache is allocated OUTSIDE the
       compiled program and donated into it, so XLA aliases the buffers and
       the per-step ``dynamic_update_slice`` writes land in place — no
-      second live copy of ``layers x (B, max_len, H, hd) x 2`` in HBM.
+      second live copy of ``layers x (B, H, max_len, hd) x 2`` in HBM.
       Safe by construction: each call allocates a fresh cache and nothing
       re-reads it after the call (donation-safety pinned in
       tests/test_generation.py, the buffer-reuse oracle pattern of
@@ -266,7 +266,7 @@ def make_generate_fn(cfg: TransformerConfig, *, max_new_tokens: int,
     # ``adapters`` is the bank tree ("adapters" collection) and
     # ``adapter_id`` selects one row for the whole batch. The bank is
     # closed over (a jit constant — the oracle serves parity tests, not
-    # production traffic), and the adapter-free trace stays verbatim.
+    # production traffic).
     lora = dcfg.lora_rank is not None
     if lora and adapters is None:
         raise ValueError(
@@ -282,11 +282,10 @@ def make_generate_fn(cfg: TransformerConfig, *, max_new_tokens: int,
         raise ValueError("speculative decoding + LoRA is not supported")
 
     def _apply(params, cache, toks, idx):
-        variables = {"params": params, "cache": cache}
-        if not lora:
-            return model.apply(variables, toks, idx, mutable=["cache"])
-        variables["adapters"] = adapters
-        ids = jnp.full((toks.shape[0],), adapter_id, jnp.int32)
+        variables, ids = {"params": params, "cache": cache}, None
+        if lora:
+            variables["adapters"] = adapters
+            ids = jnp.full((toks.shape[0],), adapter_id, jnp.int32)
         return model.apply(variables, toks, idx, adapter=ids,
                            mutable=["cache"])
 

@@ -86,7 +86,7 @@ class TransformerConfig:
     tp_axis: str | None = None
     override_head_dim: int | None = None
     # Autoregressive serving mode (models/generation.py): attention keeps a
-    # (B, max_len, H, hd) KV cache in the flax "cache" collection and the
+    # (B, H, max_len, hd) KV cache in the flax "cache" collection and the
     # caller passes the write ``index``; a call processes an arbitrary
     # chunk (the whole prompt at prefill, 1 token per decode step) with
     # static shapes throughout — the lax.scan decode loop compiles once.
@@ -100,15 +100,14 @@ class TransformerConfig:
     # bandwidth-bound decode step (ops/decode_attention.quantize_kv).
     kv_dtype: str | None = None
     # Decode-attention implementation (decode mode only):
-    # "dense"  — XLA softmax attention over the full fixed-size cache (the
-    #            historical path; the only one that keeps the legacy
-    #            (B, S, H, hd) cache layout when kv_dtype is None).
+    # "dense"  — XLA softmax attention over the full fixed-size cache
+    #            (_dense_cache_read), also what "pallas" takes where the
+    #            kernel does not fit.
     # "pallas" — length-aware streaming kernel (ops/decode_attention.py):
     #            reads only written cache blocks, consumes int8 + scales
-    #            natively, blocks resolved from the autotune table. The
-    #            cache lives in kernel layout (B, H, S, hd).
+    #            natively, blocks resolved from the autotune table.
     # "auto"   — pallas on TPU, dense elsewhere (the flash/ring TPU-only
-    #            convention; CPU tier-1 traces stay byte-identical).
+    #            convention). The cache's layout does not depend on it.
     decode_impl: str = "auto"
     # Paged KV cache (serve/paged_cache.py, decode mode only): both set →
     # the cache collection holds a POOL of ``paged_num_blocks`` blocks of
@@ -635,8 +634,8 @@ class QuantTrainDense(nn.Module):
 
 
 def _norm(cfg: TransformerConfig, name: str):
-    """The model's normalisation. With no size of a patterned model given
-    this is the historical ``nn.LayerNorm`` call, kept verbatim."""
+    """The model's normalisation: GPT-2's ``nn.LayerNorm`` at flax's
+    default epsilon unless the config names another kind or size."""
     if cfg.norm == "layernorm" and cfg.norm_eps is None:
         return nn.LayerNorm(dtype=cfg.dtype, name=name)
     eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
@@ -708,7 +707,6 @@ class MultiHeadAttention(nn.Module):
                 name="qkv",
             )(x)
         else:
-            # the historical call, kept verbatim
             qkv = nn.DenseGeneral(
                 (3, h, hd),
                 axis=-1,
@@ -778,7 +776,6 @@ class MultiHeadAttention(nn.Module):
                 name="proj",
             )(out)
         else:
-            # the historical call, kept verbatim
             out = nn.DenseGeneral(
                 cfg.d_model,
                 axis=(-2, -1),
@@ -807,49 +804,23 @@ class MultiHeadAttention(nn.Module):
         position has been reached), so one code path serves prefill
         (C = prompt length) and decode (C = 1) with fully static shapes.
 
-        Two bandwidth levers hang off the config (decode is HBM-bound —
-        the cache read dominates the step): ``kv_dtype="int8"`` stores the
-        cache quantized with per-slot-per-head f32 scales and folds
-        dequantization into the two contractions; ``decode_impl`` selects
-        the length-aware Pallas streaming kernel
-        (ops/decode_attention.py) over the dense full-cache read. The
-        default (dense, unquantized) path is byte-identical to the
-        historical trace — the tier-1 hermeticity pin in
-        tests/test_generation.py. Any non-default lever moves the cache
-        to the kernel layout (B, H, max_len, hd) so the Pallas path never
-        pays a per-step cache transpose.
+        The cache is ``(B, H, max_len, hd)`` whatever the levers say, the
+        layout the Pallas kernel streams, so that path never pays a
+        per-step cache transpose. Two bandwidth levers hang off the config
+        (decode is HBM-bound — the cache read dominates the step):
+        ``kv_dtype="int8"`` stores the cache quantized with
+        per-slot-per-head f32 scales beside it, ``(B, H, 1, max_len)``;
+        ``decode_impl`` selects the length-aware Pallas streaming kernel
+        (ops/decode_attention.py) over the dense full-cache read
+        (:func:`_dense_cache_read`).
         """
         cfg = self.cfg
         if index is None:
             raise ValueError("cfg.decode=True requires the write index")
+        from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+
         B, C, h, hd = q.shape
         quantized = cfg.kv_dtype == "int8"
-        impl = cfg.resolve_decode_impl()
-        if not quantized and impl == "dense":
-            # the historical path, kept verbatim (hermeticity pin)
-            ck = self.variable("cache", "cached_key", jnp.zeros,
-                               (B, cfg.max_len, h, hd), cfg.dtype)
-            cv = self.variable("cache", "cached_value", jnp.zeros,
-                               (B, cfg.max_len, h, hd), cfg.dtype)
-            ck.value = lax.dynamic_update_slice(ck.value, k,
-                                                (0, index, 0, 0))
-            cv.value = lax.dynamic_update_slice(cv.value, v,
-                                                (0, index, 0, 0))
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, ck.value) / jnp.sqrt(
-                hd).astype(cfg.dtype)
-            q_pos = index + jnp.arange(C)
-            k_pos = jnp.arange(cfg.max_len)
-            mask = k_pos[None, :] <= q_pos[:, None]  # (C, max_len)
-            scores = jnp.where(mask[None, None], scores,
-                               jnp.finfo(cfg.dtype).min)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(
-                cfg.dtype)
-            return jnp.einsum("bhqk,bkhd->bqhd", probs, cv.value)
-
-        from distributed_tensorflow_guide_tpu.ops import (
-            decode_attention as DA,
-        )
-
         cache_dtype = jnp.int8 if quantized else cfg.dtype
         ck = self.variable("cache", "cached_key", jnp.zeros,
                            (B, h, cfg.max_len, hd), cache_dtype)
@@ -857,73 +828,49 @@ class MultiHeadAttention(nn.Module):
                            (B, h, cfg.max_len, hd), cache_dtype)
         kT = jnp.transpose(k, (0, 2, 1, 3))  # (B, H, C, hd)
         vT = jnp.transpose(v, (0, 2, 1, 3))
-        k_scale = v_scale = None
         if quantized:
-            ks = self.variable("cache", "key_scale", jnp.zeros,
-                               (B, h, 1, cfg.max_len), jnp.float32)
-            vs = self.variable("cache", "value_scale", jnp.zeros,
-                               (B, h, 1, cfg.max_len), jnp.float32)
-            k8, k_sc = DA.quantize_kv(kT)
-            v8, v_sc = DA.quantize_kv(vT)
-            ck.value = lax.dynamic_update_slice(ck.value, k8,
-                                                (0, 0, index, 0))
-            cv.value = lax.dynamic_update_slice(cv.value, v8,
-                                                (0, 0, index, 0))
-            ks.value = lax.dynamic_update_slice(ks.value,
-                                                k_sc[:, :, None, :],
-                                                (0, 0, 0, index))
-            vs.value = lax.dynamic_update_slice(vs.value,
-                                                v_sc[:, :, None, :],
-                                                (0, 0, 0, index))
-            k_scale, v_scale = ks.value, vs.value
-        else:
-            ck.value = lax.dynamic_update_slice(ck.value, kT,
-                                                (0, 0, index, 0))
-            cv.value = lax.dynamic_update_slice(cv.value, vT,
-                                                (0, 0, index, 0))
+            kT, k_sc = DA.quantize_kv(kT)
+            vT, v_sc = DA.quantize_kv(vT)
+        ck.value = lax.dynamic_update_slice(ck.value, kT, (0, 0, index, 0))
+        cv.value = lax.dynamic_update_slice(cv.value, vT, (0, 0, index, 0))
+        scales = [None, None]
+        if quantized:
+            scales = []
+            for name, sc in (("key_scale", k_sc), ("value_scale", v_sc)):
+                leaf = self.variable("cache", name, jnp.zeros,
+                                     (B, h, 1, cfg.max_len), jnp.float32)
+                leaf.value = lax.dynamic_update_slice(
+                    leaf.value, sc[:, :, None, :], (0, 0, 0, index))
+                scales.append(leaf.value)
 
-        if impl == "pallas":
+        if cfg.resolve_decode_impl() == "pallas":
             blk_k = DA.decode_blk_k_for(b=B, h=h, s=cfg.max_len, d=hd,
                                         dtype=cache_dtype)
             if DA.supported(cfg.max_len, blk_k, C):
                 return DA.decode_attention(
                     q, ck.value, cv.value, index,
-                    key_scale=k_scale, value_scale=v_scale, blk_k=blk_k)
-            if C <= DA.DECODE_MAX_CHUNK:
-                # a chunk the kernel SHOULD take fell through (no usable
-                # KV block for this max_len) — that is a degradation
-                # worth the fallback registry; an over-cap prefill chunk
-                # routing dense is the designed split, not a fallback
-                from distributed_tensorflow_guide_tpu.ops.flash_attention import (  # noqa: E501
-                    _note_fallback,
-                )
+                    key_scale=scales[0], value_scale=scales[1], blk_k=blk_k)
+            self._note_kernel_missed(
+                "decode_attention", C, blk_k,
+                f"max_len {cfg.max_len} has no usable KV block")
+        return _dense_cache_read(q, ck.value, cv.value, index, "bhkd",
+                                 cfg.dtype, *scales)
 
-                _note_fallback(
-                    cfg.max_len, hd, C, blk_k, origin="decode_attention",
-                    msg=f"decode_attention: max_len {cfg.max_len} has no "
-                        f"usable KV block (resolved {blk_k}); falling "
-                        "back to the dense full-cache path (slower)")
+    def _note_kernel_missed(self, origin: str, C: int, blk_k, why: str):
+        """A chunk the Pallas kernel SHOULD take fell through to the dense
+        read: a degradation worth the fallback registry. An over-cap
+        prefill chunk routing dense is the designed split, not a
+        fallback, and is not recorded."""
+        from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+        from distributed_tensorflow_guide_tpu.ops.flash_attention import (
+            _note_fallback,
+        )
 
-        # dense attention on the kernel layout, dequant folded into the
-        # contractions (the scale is constant along the contracted hd axis
-        # for QK^T and along the probability axis for AV, so it factors
-        # out exactly — no dequantized cache copy is ever materialized)
-        scores = jnp.einsum("bqhd,bhkd->bhqk", q,
-                            ck.value.astype(cfg.dtype)) / jnp.sqrt(
-            hd).astype(cfg.dtype)
-        if quantized:
-            scores = scores.astype(jnp.float32) * k_scale  # (B, H, 1, S)
-        q_pos = index + jnp.arange(C)
-        k_pos = jnp.arange(cfg.max_len)
-        mask = k_pos[None, :] <= q_pos[:, None]  # (C, max_len)
-        scores = jnp.where(mask[None, None], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
-        if quantized:
-            probs = probs * v_scale  # fold v dequant into the AV columns
-        probs = probs.astype(cfg.dtype)
-        return jnp.einsum("bhqk,bhkd->bqhd", probs,
-                          cv.value.astype(cfg.dtype))
+        if C <= DA.DECODE_MAX_CHUNK:
+            _note_fallback(
+                self.cfg.max_len, self.cfg.head_dim, C, blk_k, origin=origin,
+                msg=f"{origin}: {why} (resolved {blk_k}); falling back to "
+                    "the dense read (slower)")
 
     def _paged_decode_attend(self, q, k, v, index, block_tables):
         """Paged-pool variant of :meth:`_decode_attend` — same math,
@@ -935,70 +882,39 @@ class MultiHeadAttention(nn.Module):
         positions to physical blocks and ``index`` is a PER-REQUEST (B,)
         write-position vector (continuous batching: every slot sits at
         its own length). Writes go through the table into the donated
-        pool in place; reads either stream the pool directly through the
-        Pallas block-table kernel (``decode_impl="pallas"``) or gather the
-        logical views and run the exact dense math of the non-paged
-        branches — the per-row mask zeroes whatever junk the trash block
-        and unwritten slots carry, which is what keeps the fallback
-        token-identical to the one-shot path on CPU.
+        pool in place (``write_chunk``); reads either stream the pool
+        directly through the Pallas block-table kernel
+        (``decode_impl="pallas"``) or gather the logical views and run
+        :func:`_dense_cache_read`, the lines the one-shot path runs — the
+        per-row mask zeroes whatever junk the trash block and unwritten
+        slots carry, which is what keeps the engine token-identical to
+        the one-shot path on CPU.
 
-        Off the historical dense branch the pool has ONE layout,
-        ``(N, h, hd, block_size)``: a block's slots on the lane axis, which
-        is how the device keeps such an array whatever shape it is declared
-        with (ops/decode_attention.py, the paged section's comment), so
-        neither the write nor the kernel's read relays a leaf out.
+        The pool has ONE layout, ``(N, h, hd, block_size)`` (the int8
+        cache's scale rows ``(N, h, 1, block_size)``): a block's slots on
+        the lane axis, which is how the device keeps such an array
+        whatever shape it is declared with (ops/decode_attention.py, the
+        paged section's comment), so neither the write nor the kernel's
+        read relays a leaf out.
         """
         cfg = self.cfg
         if index is None or block_tables is None:
             raise ValueError(
                 "paged decode requires the per-request index vector and "
                 "the block tables")
+        from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
         from distributed_tensorflow_guide_tpu.serve.paged_cache import (
             gather_view,
-            scatter_chunk,
             write_chunk,
         )
 
         B, C, hq, hd = q.shape
         # the pool holds the key/value heads; ``hq // h`` query heads
-        # share each (1 for GPT-2's block: every line below as it was)
+        # share each (1 for GPT-2's block)
         h = k.shape[2]
-        group = hq // h
         N, bs = cfg.paged_num_blocks, cfg.paged_block_size
-        idx = jnp.asarray(index)
-        if idx.ndim == 0:
-            idx = jnp.broadcast_to(idx, (B,))
         quantized = cfg.kv_dtype == "int8"
         impl = cfg.resolve_decode_impl()
-        if not quantized and impl == "dense":
-            # legacy-layout pool: gather -> the historical dense math
-            ck = self.variable("cache", "cached_key", jnp.zeros,
-                               (N, bs, h, hd), cfg.dtype)
-            cv = self.variable("cache", "cached_value", jnp.zeros,
-                               (N, bs, h, hd), cfg.dtype)
-            ck.value = scatter_chunk(ck.value, k, block_tables, idx,
-                                     block_size=bs)
-            cv.value = scatter_chunk(cv.value, v, block_tables, idx,
-                                     block_size=bs)
-            keys = gather_view(ck.value, block_tables, seq_axis=1)
-            vals = gather_view(cv.value, block_tables, seq_axis=1)
-            if group > 1:
-                return self._grouped_dense(q, keys, vals, idx, "bkhd")
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys) / jnp.sqrt(
-                hd).astype(cfg.dtype)
-            q_pos = idx[:, None] + jnp.arange(C)  # (B, C)
-            k_pos = jnp.arange(cfg.max_len)
-            mask = k_pos[None, None, :] <= q_pos[:, :, None]  # (B, C, S)
-            scores = jnp.where(mask[:, None], scores,
-                               jnp.finfo(cfg.dtype).min)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(
-                cfg.dtype)
-            return jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
-
-        from distributed_tensorflow_guide_tpu.ops import (
-            decode_attention as DA,
-        )
-
         cache_dtype = jnp.int8 if quantized else cfg.dtype
         ck = self.variable("cache", "cached_key", jnp.zeros,
                            (N, h, hd, bs), cache_dtype)
@@ -1007,25 +923,25 @@ class MultiHeadAttention(nn.Module):
         # beside the Pallas read, the Pallas write (a grid step a block,
         # not a loop step: serve/paged_cache.py write_chunk)
         write = functools.partial(
-            write_chunk, tables=block_tables, index=idx, block_size=bs,
+            write_chunk, tables=block_tables, index=index, block_size=bs,
             kernel=(impl == "pallas"
                     and DA.paged_write_fits((h, hd, bs), cache_dtype)))
-        ks = vs = None
+        scale_pools = [None, None]
         if quantized:
-            ks = self.variable("cache", "key_scale", jnp.zeros,
-                               (N, h, 1, bs), jnp.float32)
-            vs = self.variable("cache", "value_scale", jnp.zeros,
-                               (N, h, 1, bs), jnp.float32)
             k, k_sc = DA.quantize_kv(k)  # (B, C, H, hd), (B, C, H)
             v, v_sc = DA.quantize_kv(v)
-            for leaf, scale in ((ks, k_sc), (vs, v_sc)):
+            scale_pools = []
+            for name, sc in (("key_scale", k_sc), ("value_scale", v_sc)):
+                leaf = self.variable("cache", name, jnp.zeros,
+                                     (N, h, 1, bs), jnp.float32)
                 leaf.value = write(
-                    leaf.value, jnp.transpose(scale, (0, 2, 1))[:, :, None])
+                    leaf.value, jnp.transpose(sc, (0, 2, 1))[:, :, None])
+                scale_pools.append(leaf.value)
         for leaf, rows in ((ck, k), (cv, v)):
             leaf.value = write(
                 leaf.value, jnp.transpose(rows, (0, 2, 3, 1)))  # (B,H,hd,C)
 
-        lengths = idx + C  # (B,) live length after the write
+        lengths = index + C  # (B,) live length after the write
         if impl == "pallas":
             blk_k = DA.paged_decode_blk_k_for(
                 b=B, h=h, s=cfg.max_len, d=hd, dtype=cache_dtype,
@@ -1033,66 +949,53 @@ class MultiHeadAttention(nn.Module):
             if DA.paged_supported(cfg.max_len, bs, blk_k, C):
                 return DA.paged_decode_attention(
                     q, ck.value, cv.value, block_tables, lengths,
-                    key_scale_pool=ks.value if quantized else None,
-                    value_scale_pool=vs.value if quantized else None,
+                    key_scale_pool=scale_pools[0],
+                    value_scale_pool=scale_pools[1],
                     block_size=bs, blk_k=blk_k)
-            if C <= DA.DECODE_MAX_CHUNK:
-                from distributed_tensorflow_guide_tpu.ops.flash_attention import (  # noqa: E501
-                    _note_fallback,
-                )
+            self._note_kernel_missed(
+                "paged_decode_attention", C, blk_k,
+                f"block_size {bs} has no usable KV edge")
+        keys, vals, *scales = (
+            None if leaf is None else gather_view(leaf, block_tables)
+            for leaf in (ck.value, cv.value, *scale_pools))
+        return _dense_cache_read(q, keys, vals, index, "bhdk", cfg.dtype,
+                                 *scales)
 
-                _note_fallback(
-                    cfg.max_len, hd, C, blk_k,
-                    origin="paged_decode_attention",
-                    msg=f"paged_decode_attention: block_size {bs} has no "
-                        f"usable KV edge (resolved {blk_k}); falling back "
-                        "to the gathered dense path (slower)")
 
-        # dense gather fallback on the pool layout: identical math to
-        # the non-paged kernel-layout branch, per-request mask rows
-        keys = gather_view(ck.value, block_tables, seq_axis=3)
-        vals = gather_view(cv.value, block_tables, seq_axis=3)
-        if group > 1:
-            return self._grouped_dense(q, keys, vals, idx, "bhdk")
-        scores = jnp.einsum("bqhd,bhdk->bhqk", q,
-                            keys.astype(cfg.dtype)) / jnp.sqrt(
-            hd).astype(cfg.dtype)
-        if quantized:
-            k_scale = gather_view(ks.value, block_tables, seq_axis=3)
-            v_scale = gather_view(vs.value, block_tables, seq_axis=3)
-            scores = scores.astype(jnp.float32) * k_scale  # (B, H, 1, S)
-        q_pos = idx[:, None] + jnp.arange(C)  # (B, C)
-        k_pos = jnp.arange(cfg.max_len)
-        mask = k_pos[None, None, :] <= q_pos[:, :, None]  # (B, C, S)
-        scores = jnp.where(mask[:, None], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
-        if quantized:
-            probs = probs * v_scale
-        probs = probs.astype(cfg.dtype)
-        return jnp.einsum("bhqk,bhdk->bqhd", probs,
-                          vals.astype(cfg.dtype))
+def _dense_cache_read(q, keys, vals, index, layout: str, dtype,
+                      k_scale=None, v_scale=None):
+    """The dense read of a decode cache, the one statement of it: ``q``
+    (B, C, h * group, hd) against each sequence's keys and values in
+    ``layout`` — ``"bhkd"``, the one-shot cache as it lies, or ``"bhdk"``,
+    the views ``gather_view`` makes of the pool — under the mask ``key_pos
+    <= index + c``, ``index`` the position of the chunk's first row (one
+    for the batch, or one a row).
 
-    def _grouped_dense(self, q, keys, vals, idx, layout: str):
-        """The gathered dense math for ``group`` query heads a key/value
-        head: ``q`` (B, C, h * group, hd) against the logical views
-        ``keys``/``vals`` in ``layout`` ("bkhd" or "bhdk"), under the same
-        per-row mask as the one-group lines beside its callers."""
-        cfg = self.cfg
-        B, C, hq, hd = q.shape
-        h = keys.shape[layout.index("h")]
-        qg = q.reshape(B, C, h, hq // h, hd)
-        scores = jnp.einsum(f"bqhgd,{layout}->bhgqk", qg,
-                            keys.astype(cfg.dtype)) / jnp.sqrt(
-            hd).astype(cfg.dtype)
-        q_pos = idx[:, None] + jnp.arange(C)  # (B, C)
-        mask = jnp.arange(cfg.max_len)[None, None, :] <= q_pos[:, :, None]
-        scores = jnp.where(mask[:, None, None], scores.astype(jnp.float32),
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, -1).astype(cfg.dtype)
-        out = jnp.einsum(f"bhgqk,{layout}->bqhgd", probs,
-                         vals.astype(cfg.dtype))
-        return out.reshape(B, C, hq, hd)
+    Scores and softmax are float32. The int8 cache's dequantisation is
+    folded into the two contractions and no dequantised copy is made: a
+    scale (B, h, 1, S) is constant along the contracted ``hd`` axis of QK^T
+    (``k_scale`` multiplies the score columns) and along the probability
+    axis of AV (``v_scale`` multiplies the probabilities after the
+    normaliser), so it factors out exactly. ``group`` query heads share a
+    key/value head and none is copied; one is the degenerate case."""
+    B, C, hq, hd = q.shape
+    h = keys.shape[1]
+    seq = keys.shape[layout.index("k")]
+    qg = q.reshape(B, C, h, hq // h, hd)
+    scores = (jnp.einsum(f"bqhgd,{layout}->bhgqk", qg, keys.astype(dtype))
+              / jnp.sqrt(hd).astype(dtype)).astype(jnp.float32)
+    if k_scale is not None:
+        scores = scores * k_scale[:, :, None]
+    q_pos = jnp.reshape(index, (-1, 1)) + jnp.arange(C)  # (B or 1, C)
+    mask = jnp.arange(seq)[None, None, :] <= q_pos[:, :, None]
+    scores = jnp.where(mask[:, None, None], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, -1)
+    if v_scale is not None:
+        probs = probs * v_scale[:, :, None]
+    out = jnp.einsum(f"bhgqk,{layout}->bqhgd", probs.astype(dtype),
+                     vals.astype(dtype))
+    return out.reshape(B, C, hq, hd)
 
 
 class MLP(nn.Module):
@@ -1130,7 +1033,6 @@ class MLP(nn.Module):
                 name="up",
             )(x)
         else:
-            # the historical call, kept verbatim
             y = nn.Dense(
                 cfg.d_ff,
                 dtype=cfg.dtype,
@@ -1160,7 +1062,6 @@ class MLP(nn.Module):
                 name="down",
             )(y)
         else:
-            # the historical call, kept verbatim
             y = nn.Dense(
                 cfg.d_model,
                 dtype=cfg.dtype,
@@ -1469,23 +1370,12 @@ class Block(nn.Module):
             attn_cls = nn.remat(MultiHeadAttention, prevent_cse=False)
         attn = attn_cls(cfg, name="attn")
         h = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
-        if block_tables is None and adapter is None:
-            # the historical call, kept verbatim
-            x = x + attn(h, index)
-        elif adapter is None:
-            x = x + attn(h, index, block_tables=block_tables)
-        else:
-            x = x + attn(h, index, block_tables=block_tables,
-                         adapter=adapter)
-        mlp = (MoEMLP(cfg, name="mlp") if cfg.moe
-               else MLP(cfg, name="mlp"))
+        x = x + attn(h, index, block_tables=block_tables, adapter=adapter)
         h2 = nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x)
-        if moe_mask is not None:
-            x = x + mlp(h2, moe_mask=moe_mask)
-        elif adapter is None:  # the historical call, kept verbatim
-            x = x + mlp(h2)
+        if cfg.moe:
+            x = x + MoEMLP(cfg, name="mlp")(h2, moe_mask=moe_mask)
         else:
-            x = x + mlp(h2, adapter=adapter)
+            x = x + MLP(cfg, name="mlp")(h2, adapter=adapter)
         return _constrain(x, ("batch", "seq", "embed"))
 
 
@@ -1557,8 +1447,8 @@ class Transformer(nn.Module):
         positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.decode:
             # the serve engine passes a PER-REQUEST (B,) index vector
-            # (continuous batching: each slot sits at its own length);
-            # the scalar one-shot line stays verbatim (hermeticity pin)
+            # (continuous batching: each slot sits at its own length),
+            # the one-shot path one position for the batch
             if getattr(index, "ndim", 0):
                 positions = positions + index[:, None]
             else:
@@ -1577,19 +1467,9 @@ class Transformer(nn.Module):
         if cfg.resolved_remat_mode == "block":
             block = nn.remat(Block, prevent_cse=False)
         for i in range(cfg.num_layers):
-            if moe_mask is not None:
-                x = block(cfg, name=f"block_{i}")(
-                    x, index, block_tables=block_tables,
-                    moe_mask=moe_mask)
-            elif block_tables is None and adapter is None:
-                # the historical call, kept verbatim
-                x = block(cfg, name=f"block_{i}")(x, index)
-            elif adapter is None:
-                x = block(cfg, name=f"block_{i}")(
-                    x, index, block_tables=block_tables)
-            else:
-                x = block(cfg, name=f"block_{i}")(
-                    x, index, block_tables=block_tables, adapter=adapter)
+            x = block(cfg, name=f"block_{i}")(
+                x, index, block_tables=block_tables, adapter=adapter,
+                moe_mask=moe_mask)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
         if return_hidden:
             return x
@@ -1609,7 +1489,6 @@ class Transformer(nn.Module):
                 dtype=jnp.float32, name="lm_head",
             )(x)
         else:
-            # the historical call, kept verbatim
             logits = nn.Dense(
                 cfg.vocab_size,
                 dtype=jnp.float32,

@@ -24,7 +24,6 @@ from distributed_tensorflow_guide_tpu.serve.paged_cache import (
     BlockStore,
     blocks_for,
     gather_view,
-    scatter_chunk,
     table_row,
     write_chunk,
 )
@@ -53,7 +52,6 @@ __all__ = [
     "init_adapter_bank",
     "paged_cache_pool",
     "paged_config",
-    "scatter_chunk",
     "table_row",
     "write_chunk",
 ]

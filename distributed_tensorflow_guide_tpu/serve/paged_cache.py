@@ -1,7 +1,7 @@
 """Paged KV cache: a fixed pool of fixed-size blocks + per-request tables.
 
 The one-shot serving path (models/generation.py) gives every request a
-private ``(B, max_len, H, hd)`` cache buffer for its whole lifetime —
+private ``(B, H, max_len, hd)`` cache buffer for its whole lifetime —
 HBM is reserved for ``max_len`` slots even while a request has written
 eight.  Production traffic (ROADMAP item 1's "millions of users") makes
 that the binding constraint on batch size, which is the vLLM observation:
@@ -14,35 +14,29 @@ The split of responsibilities keeps every compiled shape static:
 
 * **host Python** (:class:`BlockPool`) allocates, frees and evicts blocks
   — a free-list the scheduler drives between steps; nothing here traces;
-* **device code** (:func:`gather_view`, :func:`write_chunk`,
-  :func:`scatter_chunk`) reads and writes through the table *inside* the
-  compiled step: a gather by block id materializes a request's logical
-  cache view, a write puts a chunk's positions ``p`` into slot ``p % bs``
-  of block ``table[p // bs]`` — all plain static-shape XLA ops, so the
-  engine's step program never retraces as the resident population
-  changes.
+* **device code** (:func:`gather_view`, :func:`write_chunk`) reads and
+  writes through the table *inside* the compiled step: a gather by block
+  id materializes a request's logical cache view, a write puts a chunk's
+  positions ``p`` into slot ``p % bs`` of block ``table[p // bs]`` — all
+  plain static-shape XLA ops, so the engine's step program never retraces
+  as the resident population changes.
 
 Unallocated logical blocks point at the reserved **trash block** (the
 pool's last id): inactive decode slots write there and the attention
 mask hides anything read from it, so the device program needs no branch
 on liveness.
 
-The pool has two leaf layouts, and a serving configuration uses one:
-
-* **the pool layout** ``(N, H, d, block_size)`` — keys and values with
-  ``d`` the head dim, the int8 cache's scale rows with ``d = 1`` — of every
-  path but the historical one: a block's slots lie on the LANE axis. That
-  is the device's tile, not taste. With a head dim under 128 the TPU keeps
-  a ``(N, H, block_size, hd)`` array with the block axis minor whatever
-  the program declares (a minor axis of 64 fills half of an (8, 128)
-  tile), while a Pallas kernel takes its operands row-major and XLA's
-  scatter picks a third order: declared the other way round, every leaf
-  was copied whole three to four times a launch (ROADMAP S8). Declared as
-  it lies, :func:`write_chunk` updates the donated leaf in place and
-  ``ops/decode_attention.py paged_decode_attention`` reads it as stored;
-* **the legacy layout** ``(N, block_size, H, hd)`` of the unquantized
-  ``decode_impl="dense"`` path (what the CPU runs; the hermeticity pin),
-  written by :func:`scatter_chunk`.
+A pool leaf is ``(N, H, d, block_size)`` — keys and values with ``d`` the
+head dim, the int8 cache's scale rows with ``d = 1`` — on every backend
+and under every lever: a block's slots lie on the LANE axis. That is the
+device's tile, not taste. With a head dim under 128 the TPU keeps a ``(N,
+H, block_size, hd)`` array with the block axis minor whatever the program
+declares (a minor axis of 64 fills half of an (8, 128) tile), while a
+Pallas kernel takes its operands row-major and XLA's scatter picks a third
+order: declared the other way round, every leaf was copied whole three to
+four times a launch (ROADMAP S8). Declared as it lies, :func:`write_chunk`
+updates the donated leaf in place and ``ops/decode_attention.py
+paged_decode_attention`` reads it as stored.
 """
 
 from __future__ import annotations
@@ -54,47 +48,24 @@ import numpy as np
 from jax import lax
 
 # --------------------------------------------------------------------------
-# device-side: gather / scatter through a block table
+# device-side: read and write through a block table
 # --------------------------------------------------------------------------
 
 
-def gather_view(pool, tables, *, seq_axis: int):
+def gather_view(pool, tables):
     """Materialize per-request logical cache views from the pool.
 
-    ``pool`` is ``(num_blocks, *dims)`` where ``dims[seq_axis - 1]`` is the
-    block size; ``tables`` is ``(B, blocks_per_seq)`` int32 physical block
-    ids.  Returns ``(B, *dims)`` with the blocked axis expanded to
-    ``blocks_per_seq * block_size`` at ``seq_axis`` — the exact dense view
-    the one-shot cache holds, which is what pins the fallback path
-    token-identical on CPU.
+    ``pool`` is a leaf ``(num_blocks, H, d, block_size)``; ``tables`` is
+    ``(B, blocks_per_seq)`` int32 physical block ids.  Returns ``(B, H, d,
+    blocks_per_seq * block_size)``: each request's blocks side by side in
+    logical order, position ``p`` at index ``p`` of the last axis — what
+    the one-shot cache holds for that sequence with its last two axes
+    swapped, which is what pins the dense read token-identical to the
+    one-shot path on CPU.
     """
-    g = jnp.take(pool, tables, axis=0)  # (B, n_blk, *dims)
-    g = jnp.moveaxis(g, 1, seq_axis)
-    shape = list(g.shape)
-    merged = (shape[:seq_axis]
-              + [shape[seq_axis] * shape[seq_axis + 1]]
-              + shape[seq_axis + 2:])
-    return g.reshape(merged)
-
-
-def scatter_chunk(pool, chunk, tables, index, *, block_size: int):
-    """Write per-request chunks into a LEGACY-layout pool ``(N, bs, *rest)``
-    through the block tables.
-
-    ``chunk`` is ``(B, C, *rest)``; request b's chunk lands at logical
-    positions ``[index[b], index[b] + C)``, i.e. physical row ``tables[b,
-    p // bs] * bs + p % bs`` of the block-flattened pool.  Rows of
-    requests whose table points at the trash block land there harmlessly
-    (never read back).  Static shapes; one scatter.
-    """
-    B, C = chunk.shape[:2]
-    pos = index[:, None] + jnp.arange(C)[None, :]  # (B, C)
-    phys = jnp.take_along_axis(tables, pos // block_size, axis=1)
-    lin = phys * block_size + pos % block_size  # (B, C) flattened rows
-    rest = pool.shape[2:]
-    flat = pool.reshape((pool.shape[0] * block_size,) + rest)
-    flat = flat.at[lin.reshape(-1)].set(chunk.reshape((B * C,) + rest))
-    return flat.reshape(pool.shape)
+    g = jnp.take(pool, tables, axis=0)  # (B, n_blk, H, d, bs)
+    g = jnp.moveaxis(g, 1, 3)
+    return g.reshape(g.shape[:3] + (-1,))
 
 
 def write_chunk(pool, chunk, tables, index, *, block_size: int,
@@ -104,9 +75,8 @@ def write_chunk(pool, chunk, tables, index, *, block_size: int,
 
     ``chunk`` is ``(B, H, d, C)``; request b's chunk lands at logical
     positions ``[index[b], index[b] + C)``: position ``p`` in slot ``p %
-    bs`` of block ``tables[b, p // bs]``, the contents
-    :func:`scatter_chunk` gives a legacy pool, for any chunk length and
-    start (a chunk may straddle blocks).  Rows whose table points at the
+    bs`` of block ``tables[b, p // bs]``, for any chunk length and start
+    (a chunk may straddle blocks).  Rows whose table points at the
     trash block land there harmlessly, and so does a position past the
     table's last block.
 

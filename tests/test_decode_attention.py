@@ -410,7 +410,7 @@ def test_paged_kernel_matches_gathered_dense_math(cache, group):
     assert got.shape == (B, c, H, HD) and got.dtype == jnp.bfloat16
 
     def view(pool):  # (B, Hkv, d, S) -> a pool head beside each query head
-        return jnp.repeat(gather_view(pool, tables, seq_axis=3)
+        return jnp.repeat(gather_view(pool, tables)
                           .astype(jnp.float32), group, axis=1)
 
     keys, vals = view(kp), view(vp)

@@ -410,10 +410,10 @@ def test_speculative_validation(params):
 
 
 def test_default_decode_trace_hermetic_on_cpu(params):
-    """The tier-1 hermeticity pin: on the CPU backend the DEFAULT decode
-    config (decode_impl='auto', kv_dtype=None) traces byte-identically to
-    the explicitly-pinned dense/unquantized config — no Pallas call, no
-    quantization, no layout change can leak into CI programs by default."""
+    """On the CPU backend the DEFAULT decode config (decode_impl='auto',
+    kv_dtype=None) traces byte-identically to the explicitly-pinned
+    dense/unquantized config — no Pallas call and no quantization can
+    leak into CI programs by default."""
     from distributed_tensorflow_guide_tpu.analysis.walker import traced_text
 
     tok = jnp.zeros((2, 1), jnp.int32)

@@ -358,5 +358,5 @@ def test_a_gpt2_configuration_builds_the_tree_and_programs_it_did():
     assert not fns.patterned and fns.declared_donate_argnums == (1,)
     pool = E.paged_cache_shapes(fns.cfg, 2)
     assert {k: v.shape for k, v in pool["block_0"]["attn"].items()} == {
-        "cached_key": (5, 8, 2, 8), "cached_value": (5, 8, 2, 8)}
+        "cached_key": (5, 2, 8, 8), "cached_value": (5, 2, 8, 8)}
     assert E.slot_state(fns.cfg, 2) == {}

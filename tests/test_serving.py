@@ -38,7 +38,6 @@ from distributed_tensorflow_guide_tpu.serve import (
     blocks_for,
     build_step_fns,
     gather_view,
-    scatter_chunk,
     table_row,
     write_chunk,
 )
@@ -287,46 +286,40 @@ def test_blocks_for_and_table_row():
     np.testing.assert_array_equal(table_row([], 3, trash=5), [5, 5, 5])
 
 
-# ---- device-side gather / scatter -------------------------------------------
+# ---- device-side gather / write ---------------------------------------------
 
 
 def test_gather_scatter_roundtrip_and_trash_isolation():
-    """scatter_chunk through a table then gather_view back must equal the
+    """write_chunk through a table then gather_view back must equal the
     dense view, and a trash-pointing table row must leave every owned
     block untouched (the inactive-slot write path)."""
     r = np.random.RandomState(0)
-    N, bs, H, hd = 5, 4, 2, 3  # legacy (B, S, H, hd) layout: seq_axis 1
-    pool = jnp.asarray(r.randn(N, bs, H, hd), jnp.float32)
+    N, bs, H, hd = 5, 4, 2, 3  # the pool layout: a block's slots last
+    pool = jnp.asarray(r.randn(N, H, hd, bs), jnp.float32)
     tables = jnp.asarray([[2, 0, 3], [4, 4, 4]], jnp.int32)  # trash id 4
-    view = gather_view(pool, tables, seq_axis=1)
-    assert view.shape == (2, 3 * bs, H, hd)
+    view = gather_view(pool, tables)
+    assert view.shape == (2, H, hd, 3 * bs)
     np.testing.assert_array_equal(
-        np.asarray(view[0, :bs]), np.asarray(pool[2]))
+        np.asarray(view[0, :, :, :bs]), np.asarray(pool[2]))
     np.testing.assert_array_equal(
-        np.asarray(view[1, bs:2 * bs]), np.asarray(pool[4]))
+        np.asarray(view[1, :, :, bs:2 * bs]), np.asarray(pool[4]))
     # write a 4-token chunk for request 0 at logical position 2 (straddles
     # physical blocks 2 and 0) while request 1's row points at trash
-    chunk = jnp.asarray(r.randn(2, 4, H, hd), jnp.float32)
+    chunk = jnp.asarray(r.randn(2, H, hd, 4), jnp.float32)
     idx = jnp.asarray([2, 0], jnp.int32)
-    out = scatter_chunk(pool, chunk, tables, idx, block_size=bs)
-    got = gather_view(out, tables, seq_axis=1)
-    np.testing.assert_array_equal(np.asarray(got[0, 2:6]),
+    out = write_chunk(pool, chunk, tables, idx, block_size=bs)
+    got = gather_view(out, tables)
+    np.testing.assert_array_equal(np.asarray(got[0, :, :, 2:6]),
                                   np.asarray(chunk[0]))
     # request 0's untouched positions survive
-    np.testing.assert_array_equal(np.asarray(got[0, :2]),
-                                  np.asarray(view[0, :2]))
-    np.testing.assert_array_equal(np.asarray(got[0, 6:]),
-                                  np.asarray(view[0, 6:]))
+    np.testing.assert_array_equal(np.asarray(got[0, :, :, :2]),
+                                  np.asarray(view[0, :, :, :2]))
+    np.testing.assert_array_equal(np.asarray(got[0, :, :, 6:]),
+                                  np.asarray(view[0, :, :, 6:]))
     # request 1's trash-routed write left every unwritten block intact
     # (request 0 touched only physical blocks 2 and 0)
     np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(pool[1]))
     np.testing.assert_array_equal(np.asarray(out[3]), np.asarray(pool[3]))
-
-
-def _to_legacy(x):
-    """A pool-layout leaf or chunk (.., H, d, slots) as the legacy layout
-    holds it: (.., slots, H, d)."""
-    return jnp.transpose(x, (0, 3, 1, 2))
 
 
 # request 0 owns blocks 2, 0, 3; request 1 owns 1 and then nothing (the
@@ -342,40 +335,79 @@ _WRITE_TABLES = [[2, 0, 3], [1, 4, 4], [4, 4, 4]]
     (3, [8, 1, 2]),    # chunk < block size, inside one block
     (5, [7, 2, 6]),    # a tail that runs off request 1's blocks: trash
 ])
-def test_write_chunk_gives_the_pool_scatter_chunk_gives(kernel, chunk,
-                                                        starts):
-    """The pool-layout write (its loop and its Pallas form) against
-    scatter_chunk's semantics on the legacy layout, bitwise on every owned
-    block: straddling chunks, chunk != block size, trash-routed rows."""
+def test_write_chunk_puts_each_position_in_its_blocks_slot(kernel, chunk,
+                                                           starts):
+    """The pool write (its loop and its Pallas form) against the sentence
+    that defines it, position by position in numpy: position ``p`` of row
+    ``b`` lands in slot ``p % bs`` of block ``table[b][p // bs]``. Bitwise
+    on every owned block: straddling chunks, chunk != block size,
+    trash-routed rows (whose landing place nothing reads: skipped)."""
     r = np.random.RandomState(chunk)
     N, bs, H, d = 5, 4, 2, 3
+    trash = N - 1
     pool = jnp.asarray(r.randn(N, H, d, bs), jnp.float32)
     rows = jnp.asarray(r.randn(3, H, d, chunk), jnp.float32)
     tables = jnp.asarray(_WRITE_TABLES, jnp.int32)
     idx = jnp.asarray(starts, jnp.int32)
-    want = scatter_chunk(_to_legacy(pool), _to_legacy(rows), tables, idx,
-                         block_size=bs)
+    want = np.array(pool)
+    for b, start in enumerate(starts):
+        for c in range(chunk):
+            block, slot = divmod(start + c, bs)
+            if (block < len(_WRITE_TABLES[b])
+                    and _WRITE_TABLES[b][block] != trash):
+                want[_WRITE_TABLES[b][block], :, :, slot] = np.asarray(
+                    rows[b, :, :, c])
     got = jax.jit(lambda *a: write_chunk(*a, block_size=bs, kernel=kernel))(
         pool, rows, tables, idx)
-    np.testing.assert_array_equal(np.asarray(_to_legacy(got))[:N - 1],
-                                  np.asarray(want)[:N - 1])
-    # and by hand: every position of request 0, and the blocks it left
-    written = set()
-    for c in range(chunk):
-        block, slot = divmod(starts[0] + c, bs)
-        written.add(_WRITE_TABLES[0][block])
-        np.testing.assert_array_equal(
-            np.asarray(got[_WRITE_TABLES[0][block], :, :, slot]),
-            np.asarray(rows[0, :, :, c]))
-    for block in {2, 0, 3} - written:
-        np.testing.assert_array_equal(np.asarray(got[block]),
-                                      np.asarray(pool[block]))
+    # every owned block: the written slots, and all the rest as it was
+    np.testing.assert_array_equal(np.asarray(got)[:trash], want[:trash])
+
+
+@pytest.mark.parametrize("decode_impl", ["dense", "pallas"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["native", "int8"])
+@pytest.mark.parametrize("residency", ["paged", "one_shot"])
+def test_cache_leaves_have_one_shape_whatever_the_lever(residency, kv_dtype,
+                                                        decode_impl):
+    """One cache layout a residency: the pool an engine holds is ``(N, H,
+    hd, block_size)`` and the one-shot cache ``(B, H, max_len, hd)`` under
+    every ``decode_impl`` and ``kv_dtype`` (which decides the dtype and
+    adds the scale rows, nothing else), so what tier-1 runs on the CPU by
+    default is the layout the chip runs."""
+    from distributed_tensorflow_guide_tpu.models.generation import (
+        init_cache,
+    )
+    from distributed_tensorflow_guide_tpu.serve.engine import (
+        paged_cache_shapes,
+        paged_config,
+    )
+
+    cfg = dataclasses.replace(CFG, kv_dtype=kv_dtype,
+                              decode_impl=decode_impl)
+    H, hd = CFG.num_heads, CFG.head_dim
+    if residency == "paged":
+        leaves = paged_cache_shapes(
+            paged_config(cfg, num_blocks=9, block_size=8), 2)
+        want, want_scale = (9, H, hd, 8), (9, H, 1, 8)
+    else:
+        leaves = init_cache(cfg, None, 3)
+        want, want_scale = (3, H, CFG.max_len, hd), (3, H, 1, CFG.max_len)
+    payload = jnp.int8 if kv_dtype == "int8" else CFG.dtype
+    for i in range(CFG.num_layers):
+        layer = dict(leaves[f"block_{i}"]["attn"])
+        for name in ("cached_key", "cached_value"):
+            leaf = layer.pop(name)
+            assert (leaf.shape, leaf.dtype) == (want, payload), name
+        if kv_dtype == "int8":
+            for name in ("key_scale", "value_scale"):
+                leaf = layer.pop(name)
+                assert (leaf.shape, leaf.dtype) == (want_scale,
+                                                    jnp.float32), name
+        assert not layer, f"unexpected cache leaves {sorted(layer)}"
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["loop", "pallas"])
 def test_write_chunk_past_the_table_lands_in_trash(kernel):
-    """Positions past a row's last table entry touch no owned block (the
-    legacy scatter wrapped them onto block 0: nothing relied on it)."""
+    """Positions past a row's last table entry touch no owned block."""
     r = np.random.RandomState(1)
     N, bs, H, d = 5, 4, 2, 1  # d == 1: the int8 cache's scale rows
     pool = jnp.asarray(r.randn(N, H, d, bs), jnp.float32)
@@ -1105,9 +1137,14 @@ def test_multi_lora_batched_decode_bitwise(params):
     cfg_l = dataclasses.replace(CFG, lora_rank=2, lora_adapters=2)
     bank = init_adapter_bank(cfg_l)
     keys = jax.random.split(jax.random.PRNGKey(7), len(jax.tree.leaves(bank)))
+    # a bank large enough for the positive control at the end to bind: a
+    # rank-2 delta is quadratic in the bank's scale, and at 0.05 it moved
+    # the last prompt position's logits by 0.007 where the base's top two
+    # lie 0.02 apart, so six sampled tokens came out the base's and the
+    # control said nothing about the delta (at 0.2 it moves them by 0.2)
     bank = jax.tree.unflatten(
         jax.tree.structure(bank),
-        [0.05 * jax.random.normal(k, l.shape, l.dtype).at[0].set(0.0)
+        [0.2 * jax.random.normal(k, l.shape, l.dtype).at[0].set(0.0)
          for k, l in zip(keys, jax.tree.leaves(bank))])
     eng = ServeEngine(cfg_l, params, slots=2, num_blocks=33, block_size=8,
                       prefill_chunk=8, temperature=0.8, top_k=10,
@@ -1126,7 +1163,9 @@ def test_multi_lora_batched_decode_bitwise(params):
     o1 = np.asarray(gen1(params, PROMPTS[1][None],
                          jax.random.PRNGKey(101)))[0,
                                                    len(PROMPTS[1]):].tolist()
-    assert got[1] == o1 and o1 != _oracle(CFG, params, 1, 0.8, 10)
+    assert got[1] == o1
+    # the control: adapter 1 is not the base model by another name
+    assert o1 != _oracle(CFG, params, 1, 0.8, 10)
     eng.close()
     eng.sched.pool.check_leaks()
 
@@ -1724,7 +1763,7 @@ def test_moe_engine_kill_restore_resumes_bitwise(moe_params, tmp_path):
 
 def test_non_moe_configs_compile_identical_programs(params):
     """The zero-regression gate in miniature: build_step_fns for a
-    non-MoE config takes the historical branch — the jaxprs contain no
+    non-MoE config takes the plain step pair — the jaxprs contain no
     router, no expert contraction, no moe_stats plumbing."""
     fns = build_step_fns(CFG, slots=2, num_blocks=33, block_size=8,
                          prefill_chunk=8)
