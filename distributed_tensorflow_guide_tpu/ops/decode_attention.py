@@ -365,13 +365,18 @@ def make_decode_runner(blk_k: int, *, b: int, h: int, s: int, d: int,
 # per layer shared by every resident request (serve/paged_cache.py); a
 # request's cache is whatever blocks its (blocks_per_seq,) table row names.
 # The kernel below is the same online-softmax stream as _decode_kernel with
-# two changes: the length is PER-REQUEST ((B,) — continuous batching puts
+# three changes: the length is PER-REQUEST ((B,) — continuous batching puts
 # every slot at its own position), and the KV BlockSpec index map resolves
 # physical blocks through the table — both ride in as scalar-prefetch
 # operands, so dead blocks still collapse onto the last live physical
 # block and elide their DMA exactly as in the contiguous kernel. blk_k
 # must divide the pool block size: a tile never straddles two physical
-# blocks, which is what keeps the index map a pure table lookup.
+# blocks, which is what keeps the index map a pure table lookup. And a grid
+# step carries one key tile of ALL of a slot's pool heads (as many as fit
+# VMEM: paged_heads_per_step), each with the query heads that share it: a
+# step costs a fraction of a microsecond whatever it computes, and one head
+# against one tile of a short context computes next to nothing (PERF.md,
+# PR 31), while a block's heads are one contiguous slab of the pool.
 #
 # The pool is stored ``(num_blocks, Hkv, hd, block_size)``: a block's slots
 # lie along the LANE axis and the head dim along the sublanes. That is the
@@ -454,9 +459,65 @@ def _default_paged_blk_k(block_size: int) -> int:
     return block_size
 
 
+def _vmem_tile_bytes(rows: int, lanes: int, dtype) -> int:
+    """What a ``(rows, lanes)`` array of ``dtype`` takes in VMEM: lanes in
+    whole groups of 128, rows in whole sublane groups (8 of four bytes, 16
+    of two, 32 of one)."""
+    import numpy as np
+
+    io = np.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // io)
+    return (-(-rows // sub) * sub) * (-(-lanes // LANE) * LANE) * io
+
+
+def paged_heads_per_step(kv_heads: int, *, group: int, chunk: int, hd: int,
+                         blk_k: int, dtype, q_dtype,
+                         budget: int | None = None) -> int:
+    """How many pool heads one grid step of the paged kernel carries: the
+    largest divisor of ``kv_heads`` whose working set fits ``budget``
+    (``autotune.VMEM_BUDGET_BYTES`` unless given), never under one.
+
+    A pool head's share of a step: its key and value tiles (and the int8
+    cache's two scale rows) and its group's q and o rows, each
+    double-buffered by the pipeline; the float32 softmax state ``m``,
+    ``l`` (lane-wide) and ``acc``; and the body's float32 temporaries
+    (scores, probabilities, the select; the value tile converted). At a
+    decode step that is ~0.2 MB a head, so every head of either serving
+    model rides in one step; a 128-token prefill chunk is ~0.6 MB a head
+    (2.2 MB under a group of four) and takes a divisor of the heads."""
+    import numpy as np
+
+    f32 = jnp.float32
+    rows = -(-group * chunk // DECODE_CHUNK_SUBLANES) * DECODE_CHUNK_SUBLANES
+    kv = 2 * _vmem_tile_bytes(hd, blk_k, dtype)
+    if np.dtype(dtype) == np.dtype(np.int8):
+        kv += 2 * _vmem_tile_bytes(1, blk_k, f32)
+    qo = 2 * _vmem_tile_bytes(rows, hd, q_dtype)
+    scratch = (2 * _vmem_tile_bytes(rows, LANE, f32)
+               + _vmem_tile_bytes(rows, hd, f32))
+    body = (3 * _vmem_tile_bytes(rows, blk_k, f32)
+            + 2 * _vmem_tile_bytes(hd, blk_k, f32))
+    head = 2 * (kv + qo) + scratch + body
+    if budget is None:
+        budget = autotune.VMEM_BUDGET_BYTES
+    fits = [hb for hb in range(1, kv_heads + 1)
+            if kv_heads % hb == 0 and hb * head <= budget]
+    return max(fits, default=1)
+
+
 def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
-                         scale: float, blk_k: int, chunk: int,
+                         scale: float, blk_k: int, chunk: int, rows: int,
                          quantized: bool):
+    """One grid step: ``hb`` pool heads of one slot against one key tile.
+
+    ``q_ref``/``o_ref`` (1, hb, R, hd): a pool head's query rows on the
+    sublanes, row ``r < rows`` being query head ``r // chunk`` of the
+    head's group at chunk position ``r % chunk`` (``rows = group *
+    chunk``; rows from there to ``R`` are padding). ``k_ref``/``v_ref``
+    (1, hb, hd, blk_k) of the pool as stored, the int8 cache's scale rows
+    (1, hb, 1, blk_k); scratch ``m``, ``l`` (hb, R, LANE), ``acc``
+    (hb, R, hd), float32. The online softmax of the one-head kernel over a
+    leading head axis."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -467,52 +528,59 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(j == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[b]  # per-request live length (continuous batching)
 
     @pl.when(j * blk_k < length)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)  # (Cp, hd)
-        kT = k_ref[0, 0].astype(jnp.float32)  # (hd, blk_k): slots on lanes
-        vT = v_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0]  # (hb, R, hd)
+        kT = k_ref[0]  # (hb, hd, blk_k): slots on lanes
+        # the stored operands go into the MXU as they are (an int8 tile as
+        # q's type, which holds every int8 value): the products of two
+        # bfloat16 values are exact in the float32 they accumulate in
+        dt = q.dtype if quantized else jnp.promote_types(q.dtype, kT.dtype)
         s = jax.lax.dot_general(
-            q, kT, (((1,), (0,)), ((), ())),
+            q.astype(dt), kT.astype(dt), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale  # (Cp, blk_k)
+        ) * scale  # (hb, R, blk_k)
         if quantized:
-            s = s * ks_ref[0, 0]  # (1, blk_k) broadcast
-        cp = q.shape[0]
-        rows = jnp.minimum(
-            jax.lax.broadcasted_iota(jnp.int32, (cp, blk_k), 0), chunk - 1)
-        q_pos = (length - chunk) + rows
+            s = s * ks_ref[0]  # (hb, 1, blk_k) broadcast
+        rp = q.shape[1]
+        # padding rows take the last real row's position (finite softmax,
+        # sliced off by the caller); under grouped heads a row's chunk
+        # position is its index within its query head's ``chunk`` rows
+        r = jnp.minimum(
+            jax.lax.broadcasted_iota(jnp.int32, (rp, blk_k), 0), rows - 1)
+        if rows != chunk:
+            r = jax.lax.rem(r, chunk)
+        q_pos = (length - chunk) + r
         k_pos = j * blk_k + jax.lax.broadcasted_iota(
-            jnp.int32, (cp, blk_k), 1)
-        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            jnp.int32, (rp, blk_k), 1)
+        s = jnp.where((k_pos <= q_pos)[None], s, NEG_INF)
+        m_prev = m_scr[:, :, :1]
+        l_prev = l_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        l_scr[:] = jnp.broadcast_to(l_prev * alpha
-                                    + jnp.sum(p, axis=1, keepdims=True),
-                                    l_scr.shape)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=2, keepdims=True), l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         if quantized:
-            p = p * vs_ref[0, 0]
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, vT, (((1,), (1,)), ((), ())),
+            p = p * vs_ref[0]
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v_ref[0].astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )
+        )  # (hb, R, hd): the value tile contracted over its lanes
 
     @pl.when(j == n_kv - 1)
     def _():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
@@ -532,6 +600,11 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
     [lengths[b] - C, lengths[b]). Only reads; the caller writes the
     chunk first (models/transformer.py _paged_decode_attend, through
     serve/paged_cache.py write_chunk). Returns (B, C, H, hd) in q's dtype.
+
+    The grid is ``(B, Hkv // hb, n_kv)``: one step reads one key tile of
+    ``hb`` pool heads, all of them where they fit
+    (:func:`paged_heads_per_step`), once for every query head that shares
+    them.
     """
     B, C, H, hd = q.shape
     n_blk = block_tables.shape[1]
@@ -549,70 +622,71 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
             f"unsupported for view length {S}, block_size {block_size} — "
             "callers gate on paged_supported() and fall back to the "
             "gathered dense path")
-    cp = -(-C // DECODE_CHUNK_SUBLANES) * DECODE_CHUNK_SUBLANES
-    qk = jnp.transpose(q, (0, 2, 1, 3))  # (B, H, C, hd)
-    if cp != C:
-        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, cp - C), (0, 0)))
+    # grouped heads: the pool holds H // group key/value heads, and the
+    # ``group`` query heads of pool head g, g * group + i, ride together
+    # on the sublanes of its step
+    kv_heads = key_pool.shape[1]
+    group = H // kv_heads
+    if group < 1 or group * kv_heads != H:
+        raise ValueError(
+            f"{H} query heads are no multiple of the pool's "
+            f"{kv_heads} key/value heads")
+    rows = group * C
+    rp = -(-rows // DECODE_CHUNK_SUBLANES) * DECODE_CHUNK_SUBLANES
+    qk = jnp.transpose(q.reshape(B, C, kv_heads, group, hd),
+                       (0, 2, 3, 1, 4)).reshape(B, kv_heads, rows, hd)
+    if rp != rows:
+        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
     lengths = jnp.maximum(jnp.asarray(lengths, jnp.int32), 1)
     tables = jnp.asarray(block_tables, jnp.int32)
     scale = 1.0 / (hd ** 0.5)
     n_kv = S // blk_k
     sub = block_size // blk_k  # kernel tiles per physical block
+    hb = paged_heads_per_step(kv_heads, group=group, chunk=C, hd=hd,
+                              blk_k=blk_k, dtype=key_pool.dtype,
+                              q_dtype=q.dtype)
 
-    def live_j(b, j, len_ref):
-        # same revisit trick as the contiguous kernel: dead tiles map to
-        # the last live tile so consecutive identical (block, offset)
-        # pairs elide the DMA
-        last_live = (len_ref[b] + blk_k - 1) // blk_k - 1
-        return jnp.minimum(j, last_live)
-
-    # grouped heads: the pool holds H // group key/value heads and grid
-    # head h reads pool head h // group (group 1: the index as it was)
-    group = H // key_pool.shape[1]
-    if group < 1 or group * key_pool.shape[1] != H:
-        raise ValueError(
-            f"{H} query heads are no multiple of the pool's "
-            f"{key_pool.shape[1]} key/value heads")
-
-    def pool_head(h):
-        return h if group == 1 else h // group
-
-    def kv_map(b, h, j, len_ref, bt_ref):
+    def kv_map(b, g, j, len_ref, bt_ref):
         # keys, values and scale rows alike: a tile is ``blk_k`` lanes of
-        # one (block, pool head)
-        lj = live_j(b, j, len_ref)
-        return (bt_ref[b, lj // sub], pool_head(h), 0, lj % sub)
+        # ``hb`` heads of one block. Dead tiles map to the last live one,
+        # the revisit trick of the contiguous kernel: consecutive
+        # identical (block, offset) pairs elide the DMA
+        last_live = (len_ref[b] + blk_k - 1) // blk_k - 1
+        lj = jnp.minimum(j, last_live)
+        return (bt_ref[b, lj // sub], g, 0, lj % sub)
 
-    q_spec = _vmem_spec((1, 1, cp, hd),
-                        lambda b, h, j, L, T: (b, h, 0, 0))
-    kv_spec = _vmem_spec((1, 1, hd, blk_k), kv_map)
+    q_spec = _vmem_spec((1, hb, rp, hd),
+                        lambda b, g, j, L, T: (b, g, 0, 0))
+    kv_spec = _vmem_spec((1, hb, hd, blk_k), kv_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qk, key_pool, value_pool]
     if quantized:
-        sc_spec = _vmem_spec((1, 1, 1, blk_k), kv_map)
+        sc_spec = _vmem_spec((1, hb, 1, blk_k), kv_map)
         in_specs += [sc_spec, sc_spec]
         operands += [key_scale_pool, value_scale_pool]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, n_kv),
+        grid=(B, kv_heads // hb, n_kv),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            _vmem_scratch((cp, LANE), jnp.float32),
-            _vmem_scratch((cp, LANE), jnp.float32),
-            _vmem_scratch((cp, hd), jnp.float32),
+            _vmem_scratch((hb, rp, LANE), jnp.float32),
+            _vmem_scratch((hb, rp, LANE), jnp.float32),
+            _vmem_scratch((hb, rp, hd), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               blk_k=blk_k, chunk=C, quantized=quantized)
+                               blk_k=blk_k, chunk=C, rows=rows,
+                               quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, cp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, kv_heads, rp, hd), q.dtype),
         interpret=_interpret(),
     )(lengths, tables, *operands)
-    return jnp.transpose(out[:, :, :C], (0, 2, 1, 3))
+    out = out[:, :, :rows].reshape(B, kv_heads, group, C, hd)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, C, H, hd)
 
 
 def _paged_write_kernel(phys_ref, first_ref, new_ref, pool_ref, out_ref, *,
@@ -685,11 +759,14 @@ def paged_write_fits(block: tuple[int, ...], dtype) -> bool:
 
 def make_paged_decode_runner(blk_k: int, *, b: int, h: int, s: int,
                              d: int, dtype, block_size: int,
-                             chunk: int = 1, seed: int = 0):
+                             chunk: int = 1, seed: int = 0,
+                             kv_heads: int | None = None):
     """Zero-arg runner for ONE paged decode-attention call: a full pool
     (every request at length s — the steady-state worst case), identity
     block tables. The unit the paged sweep and the kernel microbench
-    time."""
+    time. ``kv_heads`` is the pool's head count where ``h`` query heads
+    share fewer (as many as query heads unless given)."""
+    kv_heads = h if kv_heads is None else kv_heads
     quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
     q_dtype = jnp.bfloat16 if quantized else dtype
     n_blk = s // block_size
@@ -697,9 +774,9 @@ def make_paged_decode_runner(blk_k: int, *, b: int, h: int, s: int,
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(keys[0], (b, chunk, h, d),
                           jnp.float32).astype(q_dtype)
-    kf = jax.random.normal(keys[1], (num_blocks, h, block_size, d),
+    kf = jax.random.normal(keys[1], (num_blocks, kv_heads, block_size, d),
                            jnp.float32)
-    vf = jax.random.normal(keys[2], (num_blocks, h, block_size, d),
+    vf = jax.random.normal(keys[2], (num_blocks, kv_heads, block_size, d),
                            jnp.float32)
     tables = jnp.arange(b * n_blk, dtype=jnp.int32).reshape(b, n_blk)
     lengths = jnp.full((b,), s, jnp.int32)
@@ -746,29 +823,33 @@ def _attn_kernel_cost(eqn, *, slots_axis: int = 2):
     the static auditor — derived from the equation's grid and BlockSpecs,
     with the HBM side delegated to :func:`decode_kernel_hbm_bytes` so the
     auditor and the kernel microbench price the same call identically.
-    The q/out chunk is counted at its lane-PADDED size (the BlockSpec is
-    all the jaxpr knows); the dense static-shape ceiling, like the
+    The grid is (slots, steps over the key/value heads, key tiles) and a
+    block's axis 1 says how many heads a step carries: one of the
+    contiguous cache's, ``hb`` of the paged pool's, whose q block holds a
+    whole group's rows for each, so the keys are priced once a pool head.
+    The q/out rows are counted at their sublane-PADDED size (the BlockSpec
+    is all the jaxpr knows); the dense static-shape ceiling, like the
     closed form's default. ``slots_axis`` is where the key block keeps
     its ``blk_k`` slots: 2 of the contiguous cache's (1, 1, blk_k, hd), 3
-    of the paged pool's (1, 1, hd, blk_k)."""
+    of the paged pool's (1, hb, hd, blk_k)."""
     gm = eqn.params["grid_mapping"]
-    b, h, n_kv = (int(g) for g in gm.grid)
+    b, h_steps, n_kv = (int(g) for g in gm.grid)
     bms = list(gm.block_mappings)
-    _, _, cp, hd = _block_dims(bms[0])                    # q block
+    _, hb, rows, hd = _block_dims(bms[0])                 # q block
     blk_k = _block_dims(bms[1])[slots_axis]               # k block
-    s = n_kv * blk_k
+    h, s = h_steps * hb, n_kv * blk_k
     k_aval = eqn.invars[gm.num_index_operands + 1].aval
     q_aval = eqn.outvars[0].aval
     total = decode_kernel_hbm_bytes(
-        b=b, h=h, s=s, d=hd, dtype=k_aval.dtype, chunk=cp,
+        b=b, h=h, s=s, d=hd, dtype=k_aval.dtype, chunk=rows,
         q_dtype=q_aval.dtype)
     import numpy as np
 
-    qo_half = b * h * cp * hd * np.dtype(q_aval.dtype).itemsize
+    qo_half = b * h * rows * hd * np.dtype(q_aval.dtype).itemsize
     return {
-        # qk^T + softmax-weighted pv: two (cp, blk_k, hd) contractions
-        # per grid cell over the full static grid
-        "flops": 4.0 * b * h * s * cp * hd,
+        # qk^T + softmax-weighted pv: two (rows, blk_k, hd) contractions
+        # a head and key tile over the full static grid
+        "flops": 4.0 * b * h * s * rows * hd,
         "read": total - qo_half,
         "write": float(qo_half),
     }

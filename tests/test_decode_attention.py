@@ -377,6 +377,49 @@ def test_paged_int8_parity():
                                    rtol=1e-5, err_msg=f"req {bi}")
 
 
+def _random_pool(r, nb, kv, bs, cache):
+    """A pool in its one layout, ``(nb, kv, HD, bs)``, at the cache's
+    type; the int8 cache with its ``scale pool`` keywords."""
+    kf = jnp.asarray(r.randn(nb, kv, HD, bs), jnp.float32)
+    vf = jnp.asarray(r.randn(nb, kv, HD, bs), jnp.float32)
+    if cache != "int8":
+        dt = jnp.dtype(cache)
+        return kf.astype(dt), vf.astype(dt), {}
+    # quantize_kv scales a vector of hd values: the pool's axis 2
+    kp, ks = DA.quantize_kv(jnp.swapaxes(kf, 2, 3))
+    vp, vs = DA.quantize_kv(jnp.swapaxes(vf, 2, 3))
+    return (jnp.swapaxes(kp, 2, 3), jnp.swapaxes(vp, 2, 3),
+            dict(key_scale_pool=ks[:, :, None, :],  # (N, Hkv, 1, bs)
+                 value_scale_pool=vs[:, :, None, :]))
+
+
+def _gathered_dense(q, kp, vp, tables, lengths, *, key_scale_pool=None,
+                    value_scale_pool=None):
+    """The dense math on the views ``gather_view`` makes of a pool (what
+    the fallback runs), a pool head beside each query head of its group;
+    float32 throughout."""
+    from distributed_tensorflow_guide_tpu.serve.paged_cache import (
+        gather_view,
+    )
+
+    c, group = q.shape[1], q.shape[2] // kp.shape[1]
+
+    def view(pool):  # (B, Hkv, d, S) -> a pool head beside each query head
+        return jnp.repeat(gather_view(pool, tables)
+                          .astype(jnp.float32), group, axis=1)
+
+    keys, vals = view(kp), view(vp)
+    if key_scale_pool is not None:
+        keys, vals = keys * view(key_scale_pool), vals * view(
+            value_scale_pool)
+    scores = jnp.einsum("bqhd,bhdk->bhqk", q.astype(jnp.float32),
+                        keys) / jnp.sqrt(q.shape[-1])
+    q_pos = (lengths - c)[:, None] + jnp.arange(c)  # (B, C)
+    mask = jnp.arange(keys.shape[-1])[None, None, :] <= q_pos[:, :, None]
+    scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
+    return jnp.einsum("bhqk,bhdk->bqhd", jax.nn.softmax(scores, -1), vals)
+
+
 @pytest.mark.parametrize("group", [1, 3])
 @pytest.mark.parametrize("cache", ["bf16", "int8"])
 def test_paged_kernel_matches_gathered_dense_math(cache, group):
@@ -384,46 +427,67 @@ def test_paged_kernel_matches_gathered_dense_math(cache, group):
     against the dense math on the views ``gather_view`` makes of the same
     pool (what the fallback runs): a bfloat16 and an int8 pool, one pool
     head a query head and one under three, a 5-token chunk."""
-    from distributed_tensorflow_guide_tpu.serve.paged_cache import (
-        gather_view,
-    )
-
     r = np.random.RandomState(21)
     bs, n_blk, c, kv = 32, 4, 5, H // group
     nb = B * n_blk + 1
     q = jnp.asarray(r.randn(B, c, H, HD), jnp.bfloat16)
-    kf = jnp.asarray(r.randn(nb, kv, HD, bs), jnp.float32)
-    vf = jnp.asarray(r.randn(nb, kv, HD, bs), jnp.float32)
+    kp, vp, scales = _random_pool(
+        r, nb, kv, bs, "bfloat16" if cache == "bf16" else cache)
     tables = jnp.asarray(r.permutation(nb - 1).reshape(B, n_blk), jnp.int32)
     lengths = jnp.asarray([77, 33], jnp.int32)
-    if cache == "int8":
-        # quantize_kv scales a vector of hd values: the pool's axis 2
-        kp, ks = DA.quantize_kv(jnp.swapaxes(kf, 2, 3))
-        vp, vs = DA.quantize_kv(jnp.swapaxes(vf, 2, 3))
-        kp, vp = jnp.swapaxes(kp, 2, 3), jnp.swapaxes(vp, 2, 3)
-        ks, vs = ks[:, :, None, :], vs[:, :, None, :]  # (N, Hkv, 1, bs)
-        scales = dict(key_scale_pool=ks, value_scale_pool=vs)
-    else:
-        kp, vp, scales = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}
     got = DA.paged_decode_attention(q, kp, vp, tables, lengths,
                                     block_size=bs, **scales)
     assert got.shape == (B, c, H, HD) and got.dtype == jnp.bfloat16
-
-    def view(pool):  # (B, Hkv, d, S) -> a pool head beside each query head
-        return jnp.repeat(gather_view(pool, tables)
-                          .astype(jnp.float32), group, axis=1)
-
-    keys, vals = view(kp), view(vp)
-    if cache == "int8":
-        keys, vals = keys * view(ks), vals * view(vs)
-    scores = jnp.einsum("bqhd,bhdk->bhqk", q.astype(jnp.float32),
-                        keys) / jnp.sqrt(HD)
-    q_pos = (lengths - c)[:, None] + jnp.arange(c)  # (B, C)
-    mask = jnp.arange(n_blk * bs)[None, None, :] <= q_pos[:, :, None]
-    scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
-    want = jnp.einsum("bhqk,bhdk->bqhd", jax.nn.softmax(scores, -1), vals)
+    want = _gathered_dense(q, kp, vp, tables, lengths, **scales)
     np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2,
                                rtol=2e-2)
+
+
+@pytest.mark.parametrize("heads_a_step", ["all", "one"])
+@pytest.mark.parametrize("cache", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("chunk", [1, 5, 128])
+@pytest.mark.parametrize("group", [1, 4])
+def test_paged_kernel_all_heads_a_step_matches_dense(monkeypatch, group,
+                                                     chunk, cache,
+                                                     heads_a_step):
+    """A grid step carries a key tile of every pool head (or, where the
+    VMEM budget is too small, of a divisor of them) and the group's query
+    heads ride on its sublanes, row ``r`` being head ``r // C`` at chunk
+    position ``r % C``: against the dense read, over a decode step, a
+    verify chunk and a prefill chunk, one query head a pool head and four,
+    the model's cache types and int8, a shuffled table, and rows whose
+    contexts are the chunk alone (one token at decode), end exactly on a
+    tile's edge, and fill the view."""
+    r = np.random.RandomState(31)
+    rows, kv, bs, n_blk = 3, 2, 32, 8
+    s, nb = n_blk * bs, rows * n_blk + 1
+    q_dtype = jnp.float32 if cache == "float32" else jnp.bfloat16
+    q = jnp.asarray(r.randn(rows, chunk, kv * group, HD), q_dtype)
+    kp, vp, scales = _random_pool(r, nb, kv, bs, cache)
+    tables = jnp.asarray(r.permutation(nb - 1).reshape(rows, n_blk),
+                         jnp.int32)
+    lengths = jnp.asarray([chunk, -(-(chunk + 1) // bs) * bs, s], jnp.int32)
+    sizing = dict(group=group, chunk=chunk, hd=HD, blk_k=bs, dtype=kp.dtype,
+                  q_dtype=q_dtype)
+    assert DA.paged_heads_per_step(kv, **sizing) == kv
+    if heads_a_step == "one":
+        # half of the least budget that holds both heads holds one: the
+        # derived count is a divisor of the heads (of three heads one, of
+        # four two, under the budget two fit), and the grid walks the rest
+        both = next(b for b in range(1 << 12, 1 << 24, 1 << 12)
+                    if DA.paged_heads_per_step(kv, budget=b, **sizing) == kv)
+        assert DA.paged_heads_per_step(3, budget=both, **sizing) == 1
+        assert DA.paged_heads_per_step(4, budget=both, **sizing) == 2
+        assert DA.paged_heads_per_step(kv, budget=both // 2, **sizing) == 1
+        assert DA.paged_heads_per_step(kv, budget=1, **sizing) == 1
+        monkeypatch.setattr(autotune, "VMEM_BUDGET_BYTES", both // 2)
+    got = DA.paged_decode_attention(q, kp, vp, tables, lengths,
+                                    block_size=bs, blk_k=bs, **scales)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    want = _gathered_dense(q, kp, vp, tables, lengths, **scales)
+    tol = 1e-5 if cache == "float32" else 2e-2
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=tol,
+                               rtol=tol)
 
 
 def test_paged_dead_blocks_cannot_leak():
@@ -533,4 +597,19 @@ def test_paged_runner_executes_and_matches_oracle():
     scores = jnp.einsum("bqhd,bhkd->bhqk", q, kd) / jnp.sqrt(16.0)
     ref = jnp.einsum("bhqk,bhkd->bqhd", jax.nn.softmax(scores, -1), vd)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+    # given fewer pool heads than query heads, the runner draws the pool
+    # at that count and both query heads read its one head
+    shared = DA.make_paged_decode_runner(16, b=1, h=2, s=64, d=16,
+                                         dtype=jnp.float32, block_size=16,
+                                         kv_heads=1)()
+    one = jax.random.normal(keys[1], (5, 1, 16, 16), jnp.float32)
+    kd1 = jnp.concatenate([one[j] for j in range(4)], axis=1)[None]
+    vd1 = jnp.concatenate(
+        [jax.random.normal(keys[2], (5, 1, 16, 16), jnp.float32)[j]
+         for j in range(4)], axis=1)[None]
+    scores = jnp.einsum("bqhd,bkd->bhqk", q, kd1[:, 0]) / jnp.sqrt(16.0)
+    ref1 = jnp.einsum("bhqk,bkd->bqhd", jax.nn.softmax(scores, -1),
+                      vd1[:, 0])
+    np.testing.assert_allclose(np.asarray(shared), np.asarray(ref1),
                                atol=1e-5, rtol=1e-5)

@@ -205,6 +205,53 @@ def test_paged_decode_kernel_compiles_under_grouped_heads(v5e, as_on_tpu,
         sds((b, n_blk), jnp.int32), sds((b,), jnp.int32)))
 
 
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+@pytest.mark.parametrize("chunk", [1, 128])
+@pytest.mark.parametrize("cell", ["gpt2-xl", "lfm2-24b-a2b"])
+def test_paged_kernel_grid_at_the_serving_cells_shapes(v5e, as_on_tpu, cell,
+                                                       chunk, cache):
+    """Both serving cells' attention layer, a decode launch and a prefill
+    chunk: ONE Pallas call (the benchmark counts launches as kernel events
+    over layers), whose grid is a step a slot and key tile at decode,
+    every pool head in it (GPT-2 XL 24 x 8 = 192 steps, LFM2 64 x 16 =
+    1,024), and a derived divisor of the heads a step at a 128-token
+    chunk; and the v5e's compiler takes it."""
+    import math
+
+    from distributed_tensorflow_guide_tpu.analysis import walker
+
+    heads, kv_heads, rows, n_blk = {
+        "gpt2-xl": (25, 25, 24, 8), "lfm2-24b-a2b": (32, 8, 64, 16)}[cell]
+    b, block_size = rows if chunk == 1 else 1, 128
+    dtype = jnp.int8 if cache == "int8" else jnp.bfloat16
+    pool = sds((b * n_blk + 1, kv_heads, HD, block_size), dtype)
+    scale = sds((b * n_blk + 1, kv_heads, 1, block_size), jnp.float32)
+
+    def fn(q, k, v, tables, lengths, *scales):
+        ks, vs = scales or (None, None)
+        return DA.paged_decode_attention(
+            q, k, v, tables, lengths, key_scale_pool=ks,
+            value_scale_pool=vs, block_size=block_size)
+
+    args = (sds((b, chunk, heads, HD), jnp.bfloat16), pool, pool,
+            sds((b, n_blk), jnp.int32), sds((b,), jnp.int32))
+    args += (scale, scale) if cache == "int8" else ()
+    calls = [e for e in walker.walk(jax.make_jaxpr(fn)(*args))
+             if walker.prim_name(e) == "pallas_call"]
+    assert len(calls) == 1
+    grid = tuple(int(g) for g in calls[0].params["grid_mapping"].grid)
+    hb = DA.paged_heads_per_step(
+        kv_heads, group=heads // kv_heads, chunk=chunk, hd=HD,
+        blk_k=block_size, dtype=dtype, q_dtype=jnp.bfloat16)
+    assert grid == (b, kv_heads // hb, n_blk)
+    if chunk == 1:
+        assert hb == kv_heads and math.prod(grid) == b * n_blk
+    else:
+        assert 1 <= hb < kv_heads and kv_heads % hb == 0
+    compiled = compile_for(SingleDeviceSharding(v5e[0]), fn, *args)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 def leaf_sized_results(text: str, leaf: tuple[int, ...]) -> list[str]:
     """The instructions of a compiled program whose result has a pool
     leaf's shape, in any layout: ``name opcode`` each (parameters and the
