@@ -40,8 +40,11 @@ from distributed_tensorflow_guide_tpu.utils.activation_sharding import (
 
 Dtype = Any
 
-MIXERS = ("attention", "short_conv")
+MIXERS = ("attention", "short_conv", "mamba2")
 FFNS = ("dense", "routed")
+#: the mixers whose sequences carry state beside their keys and values
+STATE_MIXERS = ("short_conv", "mamba2")
+POSITIONS = ("table", "rotary", "none")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,21 +180,31 @@ class TransformerConfig:
     moe_experts: int | None = None
     moe_capacity: int | None = None
     # The model as a PATTERN of layers (PR 28). ``layers`` set → layer ``i``
-    # is ``(mixer, ffn)``: a mixer kind (``"attention"`` | ``"short_conv"``)
-    # over a feed-forward kind (``"dense"`` | ``"routed"``), and the sizes
-    # below are the model's own; ``num_layers`` must equal its length. None
-    # (default) is GPT-2's block everywhere and keeps every historical
-    # trace byte-identical: the sizes below are then refused, not ignored.
+    # is ``(mixer, ffn)``: a mixer kind (``"attention"`` | ``"short_conv"``
+    # | ``"mamba2"``) over a feed-forward kind (``"dense"`` | ``"routed"``),
+    # either of which may be None: the layer is then the other half alone,
+    # ``x + f(norm(x))`` (not both). The sizes below are the model's own;
+    # ``num_layers`` must equal its length. None (default) is GPT-2's block
+    # everywhere and keeps every historical trace byte-identical: the sizes
+    # below are then refused, not ignored.
     layers: tuple | None = None
     # "layernorm" | "rmsnorm"; ``norm_eps`` None is flax's default (1e-6)
     norm: str = "layernorm"
     norm_eps: float | None = None
-    # None: down(gelu(up)) with the historical bias; "silu": the gated form
-    # down(silu(gate) * up), no biases
+    # the feed-forward's form, a routed layer's experts and shared expert
+    # too. None: down(gelu(up)) with the historical bias; "silu": the gated
+    # form down(silu(gate) * up); "relu2": down(relu(up)^2); the last two
+    # without biases
     ffn_gate: str | None = None
-    # None: a learned table of ``max_len`` positions added to the
-    # embedding; a number: rotary positions inside attention over the whole
-    # head (no table: ``max_len`` is then only the cache's length)
+    # What tells the model a token's position, said once (``position_kind``
+    # is what the code reads): "table", a learned table of ``max_len``
+    # positions added to the embedding; "rotary", a rotation inside
+    # attention over the whole head at ``rope_theta``; "none", nothing (a
+    # model whose state-space mixers order the sequence). None is what
+    # configurations written before the field mean: rotary where
+    # ``rope_theta`` is given, else the table. Without a table ``max_len``
+    # is only the cache's length.
+    positions: str | None = None
     rope_theta: float | None = None
     # fewer key/value heads than query heads (None: one each); the paged
     # pool's leaves hold this many heads
@@ -199,18 +212,43 @@ class TransformerConfig:
     # RMSNorm over the head size on every query and key head, before the
     # rotation
     qk_norm: bool = False
-    # short_conv mixer: taps of the depthwise causal convolution; a
-    # sequence carries ``conv_kernel - 1`` positions of state
+    # short_conv and mamba2 mixers: taps of the depthwise causal
+    # convolution; a sequence carries ``conv_kernel - 1`` positions of state
     conv_kernel: int = 3
+    # mamba2 mixer (ops/ssm_scan.py): ``ssm_heads`` heads of ``ssm_head_dim``
+    # (their product is the mixer's inner width, whatever ``d_model`` is),
+    # ``ssm_groups`` groups sharing B and C of ``ssm_state`` numbers each; a
+    # sequence carries a float32 (heads, head_dim, state) matrix beside the
+    # convolution's positions. Training-view runs scan chunks of
+    # ``ssm_chunk`` positions.
+    ssm_heads: int | None = None
+    ssm_head_dim: int | None = None
+    ssm_groups: int = 1
+    ssm_state: int | None = None
+    ssm_chunk: int = 128
     # routed feed-forward (ops/routed_ffn.py): ``routed_experts`` in all,
-    # ``routed_top_k`` a token, each the gated form at ``routed_d_ff``;
-    # this program holds experts ``[routed_first, routed_first +
-    # routed_count)`` (None: all) and assignments to the others add nothing
+    # ``routed_top_k`` a token, each of ``ffn_gate``'s form at
+    # ``routed_d_ff``, the chosen scores normalised (over their sum plus
+    # ``routed_norm_eps``) and multiplied by ``routed_scale``; this program
+    # holds experts ``[routed_first, routed_first + routed_count)`` (None:
+    # all) and assignments to the others add nothing. ``shared_d_ff``: one
+    # more expert of that width that every token goes through (None: none),
+    # held whole by every program that shares the layer.
+    # ``routed_d_ff_stored`` (None: ``routed_d_ff``) is the width the banks
+    # are STORED at, the caller's tree holding zeros past ``routed_d_ff``
+    # (``relu(0)^2`` and ``silu(0) * 0`` are 0, so the layer is the same):
+    # a TPU keeps an array whose last axis is not whole 128-lane groups
+    # transposed, and the grouped product, which takes its banks
+    # row-major, would copy the up bank back in every launch.
     routed_experts: int | None = None
     routed_top_k: int = 1
     routed_d_ff: int | None = None
     routed_first: int = 0
     routed_count: int | None = None
+    routed_scale: float = 1.0
+    routed_norm_eps: float = 1e-6
+    shared_d_ff: int | None = None
+    routed_d_ff_stored: int | None = None
 
     def __post_init__(self):
         self._check_pattern()
@@ -327,7 +365,11 @@ class TransformerConfig:
         sizes = dict(norm=self.norm != "layernorm",
                      norm_eps=self.norm_eps is not None,
                      ffn_gate=self.ffn_gate is not None,
+                     positions=self.positions is not None,
                      rope_theta=self.rope_theta is not None,
+                     ssm_heads=self.ssm_heads is not None,
+                     shared_d_ff=self.shared_d_ff is not None,
+                     routed_d_ff_stored=self.routed_d_ff_stored is not None,
                      num_kv_heads=self.num_kv_heads is not None,
                      qk_norm=self.qk_norm,
                      routed_experts=self.routed_experts is not None)
@@ -343,23 +385,44 @@ class TransformerConfig:
                 f"layers has {len(self.layers)} entries, num_layers is "
                 f"{self.num_layers}")
         for kinds in self.layers:
-            if (len(kinds) != 2 or kinds[0] not in MIXERS
-                    or kinds[1] not in FFNS):
+            if (len(kinds) != 2 or kinds[0] not in MIXERS + (None,)
+                    or kinds[1] not in FFNS + (None,)
+                    or kinds == (None, None)):
                 raise ValueError(
-                    f"a layer is (mixer in {MIXERS}, ffn in {FFNS}), "
-                    f"got {kinds!r}")
+                    f"a layer is (mixer in {MIXERS}, ffn in {FFNS}), either "
+                    f"of them None but not both, got {kinds!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm {self.norm!r}: layernorm or rmsnorm")
-        if self.ffn_gate not in (None, "silu"):
-            raise ValueError(f"ffn_gate {self.ffn_gate!r}: None or 'silu'")
+        if self.ffn_gate not in (None, "silu", "relu2"):
+            raise ValueError(
+                f"ffn_gate {self.ffn_gate!r}: None, 'silu' or 'relu2'")
         kv = self.kv_heads
         if kv < 1 or self.num_heads % kv:
             raise ValueError(
                 f"num_kv_heads {kv} must divide num_heads {self.num_heads}")
-        if self.rope_theta is not None and self.head_dim % 2:
+        if self.positions not in (None,) + POSITIONS:
+            raise ValueError(
+                f"positions {self.positions!r}: one of {POSITIONS}")
+        if (self.position_kind == "rotary") != (self.rope_theta is not None):
+            raise ValueError(
+                f"positions {self.positions!r} with rope_theta "
+                f"{self.rope_theta!r}: rotary positions, and they alone, "
+                "take a rope_theta")
+        if self.position_kind == "rotary" and self.head_dim % 2:
             raise ValueError("rotary positions need an even head size")
         if self.conv_kernel < 2:
             raise ValueError("conv_kernel must be >= 2")
+        if any(m == "mamba2" for m, _ in self.layers):
+            needed = (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+            if any(v is None or v < 1 for v in needed):
+                raise ValueError("a mamba2 mixer needs ssm_heads, "
+                                 "ssm_head_dim and ssm_state")
+            g = self.ssm_groups
+            if g < 1 or self.ssm_heads % g:
+                raise ValueError(
+                    f"ssm_groups {g} must divide ssm_heads {self.ssm_heads}")
+            if self.ssm_chunk < 1:
+                raise ValueError("ssm_chunk must be >= 1")
         if any(f == "routed" for _, f in self.layers):
             e, k = self.routed_experts, self.routed_top_k
             if e is None or self.routed_d_ff is None:
@@ -372,9 +435,17 @@ class TransformerConfig:
                 raise ValueError(
                     f"held experts [{first}, {first + count}) lie outside "
                     f"[0, {e})")
-            if self.ffn_gate != "silu":
-                raise ValueError("the routed experts are the gated form: "
-                                 "ffn_gate must be 'silu'")
+            if self.ffn_gate is None:
+                raise ValueError("the routed experts have no biases: "
+                                 "ffn_gate must be 'silu' or 'relu2'")
+            stored = self.routed_d_ff_stored
+            if stored is not None and stored < self.routed_d_ff:
+                raise ValueError(
+                    f"routed_d_ff_stored {stored} is under routed_d_ff "
+                    f"{self.routed_d_ff}")
+        elif self.shared_d_ff is not None:
+            raise ValueError("shared_d_ff is a routed layer's shared "
+                             "expert: no layer here is routed")
         # what the patterned path has no wiring for is refused by name
         unwired = dict(lora_rank=self.lora_rank, weight_dtype=self.weight_dtype,
                        moe_experts=self.moe_experts, tp_axis=self.tp_axis,
@@ -399,12 +470,26 @@ class TransformerConfig:
                 if self.routed_count is None else self.routed_count)
 
     @property
+    def position_kind(self) -> str:
+        """``positions`` with its default filled in: one of ``POSITIONS``."""
+        if self.positions is not None:
+            return self.positions
+        return "table" if self.rope_theta is None else "rotary"
+
+    @property
+    def state_mixers(self) -> tuple:
+        """The kinds of mixer in the pattern whose sequences carry state
+        beside their keys and values (a short_conv mixer's last positions,
+        a mamba2 mixer's those and its state matrix), in ``STATE_MIXERS``'
+        order."""
+        return tuple(k for k in STATE_MIXERS if any(
+            m == k for m, _ in self.layers or ()))
+
+    @property
     def stateful(self) -> bool:
-        """Whether a sequence carries state beside its keys and values
-        (a short_conv mixer's last positions): what moves blocks of keys
+        """Whether a sequence carries such state: what moves blocks of keys
         and values alone cannot move such a sequence."""
-        return self.layers is not None and any(
-            m == "short_conv" for m, _ in self.layers)
+        return bool(self.state_mixers)
 
     @property
     def paged(self) -> bool:
@@ -677,7 +762,7 @@ class MultiHeadAttention(nn.Module):
             eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
             q = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="q_norm")(q)
             k = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="k_norm")(k)
-        if cfg.rope_theta is not None:
+        if cfg.position_kind == "rotary":
             positions = jnp.arange(x.shape[1])[None, :]
             if cfg.decode:
                 positions = positions + jnp.reshape(index, (-1, 1))
@@ -1000,18 +1085,25 @@ def _dense_cache_read(q, keys, vals, index, layout: str, dtype,
 
 class MLP(nn.Module):
     cfg: TransformerConfig
+    width: int | None = None  # a patterned model's only: None is ``d_ff``
 
     @nn.compact
     def __call__(self, x: jax.Array, *, adapter=None) -> jax.Array:
         cfg = self.cfg
-        if cfg.ffn_gate == "silu":
-            # the gated form (a patterned model's): no biases
+        if cfg.ffn_gate is not None:
+            # a patterned model's forms: no biases, ``width`` wide (a
+            # routed layer's shared expert is this module at its own width)
+            width = cfg.d_ff if self.width is None else self.width
+
             def dense(features, names, name):
                 return nn.Dense(features, dtype=cfg.dtype, use_bias=False,
                                 kernel_init=_dense_init(*names), name=name)
 
-            y = (nn.silu(dense(cfg.d_ff, ("embed", "mlp"), "gate")(x))
-                 * dense(cfg.d_ff, ("embed", "mlp"), "up")(x))
+            y = dense(width, ("embed", "mlp"), "up")(x)
+            if cfg.ffn_gate == "silu":
+                y = nn.silu(dense(width, ("embed", "mlp"), "gate")(x)) * y
+            else:  # "relu2"
+                y = jnp.square(nn.relu(y))
             y = _constrain(y, ("batch", "seq_inner", "mlp"))
             return dense(cfg.d_model, ("mlp", "embed"), "down")(y)
         if cfg.tp_axis:  # Megatron f
@@ -1232,6 +1324,59 @@ class MoEMLP(nn.Module):
         return y.reshape(b, s, d).astype(x.dtype)
 
 
+def _state_rows_of(leaf, rows):
+    """Rows ``rows`` of a per-slot state leaf: None is every row in order
+    (the decode program: batch row ``b`` is slot ``b``), which reads the
+    leaf as it stands; one row (a prefill chunk's slot) is a slice."""
+    if rows is None:
+        return leaf
+    if rows.shape[0] == 1:
+        return lax.dynamic_slice_in_dim(leaf, rows[0], 1, axis=0)
+    return leaf[rows]
+
+
+def _state_rows_set(leaf, rows, new):
+    """``leaf`` with ``rows`` replaced by ``new``, so written that a
+    donated leaf is updated where it lies: all rows elementwise, one row as
+    a slice update (a scatter of one row may copy the leaf first)."""
+    new = new.astype(leaf.dtype)
+    if rows is None:
+        return new
+    if rows.shape[0] == 1:
+        return lax.dynamic_update_slice_in_dim(leaf, new, rows[0], axis=0)
+    return leaf.at[rows].set(new)
+
+
+def _conv_with_state(z, w, state, index, state_rows, valid, bias=None):
+    """A depthwise causal convolution of ``z`` (B, S, C) with ``w`` (C, K)
+    whose first ``K - 1`` inputs are a sequence's last ones: ``state`` is
+    the flax variable holding them, (rows, K - 1, C), or None for the
+    training view (zeros before position 0). A chunk at position 0 reads
+    zeros, and a chunk of which ``valid[b]`` positions are real leaves the
+    inputs of its last real positions (``valid[b] == 0``: the row's state
+    as it was). Returns the convolution, (B, S, C)."""
+    B, S, _ = z.shape
+    taps = w.shape[1]
+    if state is None:
+        before = jnp.zeros((B, taps - 1, z.shape[2]), z.dtype)
+    else:
+        held = _state_rows_of(state.value, state_rows)  # (B, K - 1, C)
+        fresh = jnp.reshape(index, (-1, 1, 1)) == 0
+        before = jnp.where(fresh, jnp.zeros_like(held), held)
+    ext = jnp.concatenate([before.astype(z.dtype), z], axis=1)
+    c = sum(w[:, j] * ext[:, j:j + S] for j in range(taps))
+    if bias is not None:
+        c = c + bias
+    if state is not None:
+        # ext[n : n + K - 1] is z at the last K - 1 real positions
+        after = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
+            e, n, taps - 1, axis=0))(ext, valid)
+        after = jnp.where(jnp.reshape(valid, (-1, 1, 1)) > 0,
+                          after.astype(held.dtype), held)
+        state.value = _state_rows_set(state.value, state_rows, after)
+    return c
+
+
 class ShortConv(nn.Module):
     """The short-convolution mixer (LFM2): ``[B, C, u] = split3(W_in x)``,
     ``z = B * u``, ``c_t = sum_j w[:, j] * z_{t - (K - 1) + j}`` (depthwise,
@@ -1240,9 +1385,7 @@ class ShortConv(nn.Module):
     A sequence carries ``z`` at its last ``K - 1`` positions. In decode mode
     that is one row of the ``state`` collection's ``conv`` leaf, ``(rows,
     K - 1, d)``: batch row ``b`` reads and writes row ``state_rows[b]``
-    (the engine's slot), a chunk at position 0 reads zeros, and a chunk of
-    which ``valid[b]`` positions are real leaves the state of its last
-    real position (``valid[b] == 0``: the row's state as it was)."""
+    (the engine's slot; None: row ``b``), as ``_conv_with_state`` says."""
 
     cfg: TransformerConfig
 
@@ -1259,38 +1402,133 @@ class ShortConv(nn.Module):
         z = gate_b * u
         w = self.param("conv_w", _dense_init("embed", "conv"), (d, taps),
                        jnp.float32).astype(cfg.dtype)
+        state = None
         if cfg.decode:
-            if index is None or state_rows is None or valid is None:
+            if index is None or valid is None:
                 raise ValueError("short_conv in decode mode needs the "
-                                 "index, the state rows and the valid "
-                                 "counts")
+                                 "index and the valid counts")
             state = self.variable("state", "conv", jnp.zeros,
                                   (B, taps - 1, d), cfg.dtype)
-            held = state.value[state_rows]  # (B, K - 1, d)
-            fresh = jnp.reshape(index, (-1, 1, 1)) == 0
-            before = jnp.where(fresh, jnp.zeros_like(held), held)
-        else:
-            before = jnp.zeros((B, taps - 1, d), z.dtype)
-        ext = jnp.concatenate([before.astype(z.dtype), z], axis=1)
-        c = sum(w[:, j] * ext[:, j:j + S] for j in range(taps))
-        if cfg.decode:
-            # ext[n : n + K - 1] is z at the last K - 1 real positions
-            after = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
-                e, n, taps - 1, axis=0))(ext, valid)
-            after = jnp.where(jnp.reshape(valid, (-1, 1, 1)) > 0,
-                              after.astype(held.dtype), held)
-            state.value = state.value.at[state_rows].set(after)
+        c = _conv_with_state(z, w, state, index, state_rows, valid)
         return nn.Dense(d, dtype=cfg.dtype, use_bias=False,
                         kernel_init=_dense_init("mlp", "embed"),
                         name="out_proj")(gate_c * c)
 
 
+class Mamba2(nn.Module):
+    """The Mamba-2 state-space mixer (``ops/ssm_scan.py`` has the
+    recurrence). With ``H`` heads of ``P``, ``G`` groups and a state of
+    ``N``: ``[z | xBC | dt] = W_in u`` (``HP | HP + 2GN | H``); ``xBC =
+    silu(conv(xBC) + b)``, depthwise and causal over ``conv_kernel`` taps;
+    ``x, B, C = split(xBC)``; ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``, both float32; ``y = scan(x, dt, A, B, C) + D x``; then
+    gate and norm, ``y = RMSNorm_groups(y * silu(z)) * g`` (each of the
+    ``G`` groups of ``HP / G`` channels normalised alone); ``out = W_out
+    y``. No biases but the convolution's.
+
+    A sequence carries two things, each a row of a leaf of the ``state``
+    collection in decode mode: ``conv`` (rows, K - 1, HP + 2GN) in the
+    activations' dtype, the convolution's last inputs, and ``ssm`` (rows,
+    H, P, N) float32, the state matrices (float32 whatever the activations
+    are: a sum over every position the sequence has had). Rows, fresh
+    chunks and padding as in ``_conv_with_state``; for the scan a padding
+    position or an idle row has ``dt = 0``, which leaves the state as it
+    was. A run of one position (the decode program) takes the recurrence's
+    one step, a longer one the chunked form, a chunk a call."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u: jax.Array, index=None, *, state_rows=None,
+                 valid=None) -> jax.Array:
+        from distributed_tensorflow_guide_tpu.ops.ssm_scan import (
+            ssm_chunked,
+            ssm_step,
+        )
+
+        cfg = self.cfg
+        H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        inner, wide, taps = H * P, H * P + 2 * G * N, cfg.conv_kernel
+        B, S, _ = u.shape
+        zxd = nn.Dense(inner + wide + H, dtype=cfg.dtype, use_bias=False,
+                       kernel_init=_dense_init("embed", "mlp"),
+                       name="in_proj")(u)
+        z, xbc, dt = jnp.split(zxd, [inner, inner + wide], axis=-1)
+        conv_w = self.param("conv_w", _dense_init("mlp", "conv"),
+                            (wide, taps), jnp.float32).astype(cfg.dtype)
+        conv_b = self.param("conv_b", nn.initializers.zeros_init(), (wide,),
+                            jnp.float32).astype(cfg.dtype)
+        per_head = nn.with_logical_partitioning
+        dt_bias = self.param("dt_bias", per_head(
+            nn.initializers.zeros_init(), ("heads",)), (H,), jnp.float32)
+        a_log = self.param("A_log", per_head(
+            nn.initializers.zeros_init(), ("heads",)), (H,), jnp.float32)
+        skip = self.param("D", per_head(
+            nn.initializers.ones_init(), ("heads",)), (H,), jnp.float32)
+        conv_state = ssm_state = None
+        if cfg.decode:
+            if index is None or valid is None:
+                raise ValueError("mamba2 in decode mode needs the index "
+                                 "and the valid counts")
+            conv_state = self.variable("state", "conv", jnp.zeros,
+                                       (B, taps - 1, wide), cfg.dtype)
+            ssm_state = self.variable("state", "ssm", jnp.zeros,
+                                      (B, H, P, N), jnp.float32)
+        with jax.named_scope("dtg.ssm.conv"):
+            xbc = nn.silu(_conv_with_state(xbc, conv_w, conv_state, index,
+                                           state_rows, valid, bias=conv_b))
+        x, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        x = x.reshape(B, S, H, P)
+        b, c = b.reshape(B, S, G, N), c.reshape(B, S, G, N)
+        with jax.named_scope("dtg.ssm.scan"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            a = -jnp.exp(a_log)
+            if ssm_state is None:
+                carried = jnp.zeros((B, H, P, N), jnp.float32)
+            else:
+                held = _state_rows_of(ssm_state.value, state_rows)
+                fresh = jnp.reshape(index, (-1, 1, 1, 1)) == 0
+                carried = jnp.where(fresh, jnp.zeros_like(held), held)
+                real = jnp.arange(S)[None, :] < valid[:, None]  # (B, S)
+                dt = jnp.where(real[..., None], dt, 0.0)
+            if S == 1:
+                y, after = ssm_step(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
+                                    carried)
+                y = y[:, None]
+            else:
+                y, after = ssm_chunked(x, dt, a, b, c, carried,
+                                       chunk=cfg.ssm_chunk)
+            if ssm_state is not None:
+                # a row with no real position keeps what it held, a fresh
+                # one's zeros included: nothing reads them before a chunk
+                # at position 0 does
+                after = jnp.where(
+                    jnp.reshape(valid, (-1, 1, 1, 1)) > 0, after, held)
+                ssm_state.value = _state_rows_set(ssm_state.value,
+                                                  state_rows, after)
+            y = y + skip[:, None] * x.astype(jnp.float32)
+        with jax.named_scope("dtg.ssm.gate_norm"):
+            y = y.reshape(B, S, inner) * nn.silu(z.astype(jnp.float32))
+            eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+            grouped = y.reshape(B, S, G, inner // G)
+            grouped = grouped * lax.rsqrt(
+                jnp.mean(jnp.square(grouped), -1, keepdims=True) + eps)
+            gain = self.param("norm_g", nn.with_logical_partitioning(
+                nn.initializers.ones_init(), ("mlp",)), (inner,), jnp.float32)
+            y = (grouped.reshape(B, S, inner) * gain).astype(cfg.dtype)
+        return nn.Dense(cfg.d_model, dtype=cfg.dtype, use_bias=False,
+                        kernel_init=_dense_init("mlp", "embed"),
+                        name="out_proj")(y)
+
+
 class RoutedMLP(nn.Module):
     """The routed feed-forward of a patterned model: ``ops/routed_ffn.py``
     over this module's router, selection bias and the banks of the experts
-    the program holds. Sows the router's census (``load``, (E,)) into the
-    ``routed_stats`` collection, a no-op unless the caller makes it
-    mutable (the serve step does)."""
+    the program holds, each of ``ffn_gate``'s form (the plain one has no
+    ``w_gate`` bank). Sows the router's census
+    (``load``, (E,)) into the ``routed_stats`` collection, a no-op unless
+    the caller makes it mutable (the serve step does)."""
 
     cfg: TransformerConfig
 
@@ -1302,7 +1540,7 @@ class RoutedMLP(nn.Module):
 
         cfg = self.cfg
         e, held = cfg.routed_experts, cfg.routed_held
-        d, ff = cfg.d_model, cfg.routed_d_ff
+        d, ff = cfg.d_model, cfg.routed_d_ff_stored or cfg.routed_d_ff
         b, s, _ = x.shape
         router = self.param("router", _dense_init("embed", "expert"),
                             (d, e), jnp.float32)
@@ -1310,13 +1548,16 @@ class RoutedMLP(nn.Module):
                           (e,), jnp.float32)
         up_names, down_names = (("expert", "embed", "mlp"),
                                 ("expert", "mlp", "embed"))
+        gated = cfg.ffn_gate == "silu"
         y, load = routed_ffn(
             x.reshape(b * s, d).astype(cfg.dtype), router, bias,
-            _ExpertBank((held, d, ff), up_names, name="w_gate")(),
+            (_ExpertBank((held, d, ff), up_names, name="w_gate")()
+             if gated else None),
             _ExpertBank((held, d, ff), up_names, name="w_up")(),
             _ExpertBank((held, ff, d), down_names, name="w_down")(),
             top_k=cfg.routed_top_k, first=cfg.routed_first,
-            live=None if live is None else live.reshape(b * s))
+            live=None if live is None else live.reshape(b * s),
+            scale=cfg.routed_scale, norm_eps=cfg.routed_norm_eps)
         self.sow("routed_stats", "load", load)
         return y.reshape(b, s, d)
 
@@ -1330,25 +1571,38 @@ class Block(nn.Module):
     kinds: tuple | None = None
 
     def _patterned(self, x, index, block_tables, state_rows, valid):
+        """``x + mixer(norm(x))`` then ``x + ffn(norm(x))``, each half only
+        if the layer has it (and its norm, ``ln1`` / ``ln2``, with it). A
+        routed layer's shared expert reads the same normalised rows and is
+        a module of the block's, ``shared``, beside ``mlp``."""
         cfg, (mixer, ffn) = self.cfg, self.kinds
-        h = _norm(cfg, "ln1")(x)
-        if mixer == "attention":
-            with jax.named_scope("dtg.attn"):
-                x = x + MultiHeadAttention(cfg, name="attn")(
-                    h, index, block_tables=block_tables)
-        else:
-            with jax.named_scope("dtg.short_conv"):
-                x = x + ShortConv(cfg, name="conv")(
-                    h, index, state_rows=state_rows, valid=valid)
-        h2 = _norm(cfg, "ln2")(x)
+        if mixer is not None:
+            h = _norm(cfg, "ln1")(x)
+            if mixer == "attention":
+                with jax.named_scope("dtg.attn"):
+                    x = x + MultiHeadAttention(cfg, name="attn")(
+                        h, index, block_tables=block_tables)
+            else:
+                scope, module, name = {
+                    "short_conv": ("dtg.short_conv", ShortConv, "conv"),
+                    "mamba2": ("dtg.ssm", Mamba2, "ssm")}[mixer]
+                with jax.named_scope(scope):
+                    x = x + module(cfg, name=name)(
+                        h, index, state_rows=state_rows, valid=valid)
         if ffn == "routed":
+            h2 = _norm(cfg, "ln2")(x)
             live = None
             if valid is not None:
                 live = jnp.arange(x.shape[1])[None, :] < valid[:, None]
             with jax.named_scope("dtg.routed"):
-                x = x + RoutedMLP(cfg, name="mlp")(h2, live=live)
-        else:
-            x = x + MLP(cfg, name="mlp")(h2)
+                y = RoutedMLP(cfg, name="mlp")(h2, live=live)
+            if cfg.shared_d_ff is not None:
+                with jax.named_scope("dtg.shared_expert"):
+                    y = y + MLP(cfg, width=cfg.shared_d_ff,
+                                name="shared")(h2)
+            x = x + y
+        elif ffn == "dense":
+            x = x + MLP(cfg, name="mlp")(_norm(cfg, "ln2")(x))
         return _constrain(x, ("batch", "seq", "embed"))
 
     @nn.compact
@@ -1388,15 +1642,15 @@ class Transformer(nn.Module):
     def _patterned(self, x, index, block_tables, state_rows, valid,
                    return_hidden):
         """The forward of a model given as a pattern of layers, from the
-        embedded tokens on: learned positions only if the model has no
-        rotary ones, each layer its own mixer and feed-forward, the
-        model's normalisation, the same head."""
+        embedded tokens on: the learned table only if that is what the
+        model has (``position_kind``), each layer its own mixer or
+        feed-forward or both, the model's normalisation, the same head."""
         cfg = self.cfg
         if cfg.decode and not cfg.paged:
             raise ValueError(
                 "a patterned model decodes through the paged engine only "
                 "(serve/engine.py): it has no one-shot cache")
-        if cfg.rope_theta is None:
+        if cfg.position_kind == "table":
             positions = jnp.arange(x.shape[1])[None, :]
             if cfg.decode:
                 positions = positions + jnp.reshape(index, (-1, 1))

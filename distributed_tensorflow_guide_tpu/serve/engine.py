@@ -144,8 +144,7 @@ def _serving_shapes(pcfg: TransformerConfig, slots: int):
     model = Transformer(pcfg)
     n_blk = pcfg.max_len // pcfg.paged_block_size
     rows = jnp.zeros((slots,), jnp.int32)
-    extra = ({"state_rows": rows, "valid": rows}
-             if pcfg.layers is not None else {})
+    extra = {"valid": rows} if pcfg.layers is not None else {}
     return jax.eval_shape(
         lambda: model.init(
             jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32), rows,
@@ -155,10 +154,16 @@ def _serving_shapes(pcfg: TransformerConfig, slots: int):
 def slot_state(pcfg: TransformerConfig, slots: int, device=None):
     """The zeroed per-slot state leaves beside the block pool (``{}`` for a
     model with none): row ``i`` of every leaf is slot ``i``'s, which both
-    step programs address (the prefill program is told its slot as it is
-    told its block table). A slot's row needs no clearing between
-    requests: a chunk that starts at position 0 reads zeros."""
+    step programs address (the decode program's batch row ``i`` is slot
+    ``i``; the prefill program is told its slot as it is told its block
+    table). A slot's row needs no clearing between requests: a chunk that
+    starts at position 0 reads zeros."""
     return _zeros(_serving_shapes(pcfg, slots).get("state", {}), device)
+
+
+def _tree_bytes(tree) -> int:
+    """The bytes of a tree's array leaves (0 for None or ``{}``)."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
 
 
 def _zeros(shapes, device):
@@ -232,18 +237,29 @@ def _pool_scatter(pool, idx, rows):
                   for leaf, r in zip(leaves, rows)])
 
 
-def _routed_counters(load: np.ndarray) -> dict:
-    """One launch's routed census, (routed layers, experts), as the two
-    numbers its ``engine.apply`` span carries: ``experts_touched``, the
-    distinct experts a routed layer read (mean over the layers), and
-    ``load_ratio``, the busiest expert's assignments over the mean
-    expert's (mean over the layers; 1 is even). ``{}`` with no routed
+def _routed_counters(load: np.ndarray, first: int = 0,
+                     count: int | None = None) -> dict:
+    """One launch's routed census, (routed layers, experts) over ALL the
+    experts, as the numbers its ``engine.apply`` span carries, for a
+    program that holds the experts ``[first, first + count)`` (all by
+    default): ``assignments``, every live row's choices in every routed
+    layer, and ``held_assignments``, those that fell to experts held here
+    (the others add nothing in this program); ``experts_touched``, the
+    distinct held experts a routed layer read (mean over the layers), and
+    ``load_ratio``, the busiest held expert's assignments over the mean
+    held expert's (mean over the layers; 1 is even). ``{}`` with no routed
     layer or no live row."""
     if not load.size or not load.sum():
         return {}
-    return {"experts_touched": float((load > 0).sum(axis=1).mean()),
-            "load_ratio": float((load.max(axis=1)
-                                 / load.mean(axis=1)).mean())}
+    held = load[:, first:None if count is None else first + count]
+    out = {"assignments": int(load.sum()),
+           "held_assignments": int(held.sum())}
+    if held.sum():
+        busy = held[held.sum(axis=1) > 0]
+        out["experts_touched"] = float((held > 0).sum(axis=1).mean())
+        out["load_ratio"] = float((busy.max(axis=1)
+                                   / busy.mean(axis=1)).mean())
+    return out
 
 
 def _moe_fold(stats):
@@ -320,7 +336,6 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
             logits, mut = model.apply(
                 {"params": params, "cache": pool, "state": state},
                 last_tok[:, None], written, block_tables=tables,
-                state_rows=jnp.arange(written.shape[0]),
                 valid=(written > 0).astype(jnp.int32),
                 mutable=["cache", "state", "routed_stats"])
             pos_keys = jax.vmap(jax.random.fold_in)(keys, written + 1)
@@ -532,8 +547,8 @@ class ServeEngine:
             # state: served so, its first token would already be wrong
             raise ValueError(
                 "this model's sequences carry state beside their keys and "
-                "values (a short_conv mixer's last positions), and the "
-                "prefix cache, the host tier and KV adoption move blocks "
+                f"values (its {' and '.join(self.fns.cfg.state_mixers)} "
+                "mixers'), and the prefix cache, the host tier and KV adoption move blocks "
                 "of keys and values alone: prefix_cache and host_blocks "
                 "must stay off (a preempted or migrated request "
                 "re-prefills, which rebuilds the state)")
@@ -573,6 +588,9 @@ class ServeEngine:
         self.pool = paged_cache_pool(self.fns.cfg, slots, self.device)
         self.state = (slot_state(self.fns.cfg, slots, self.device)
                       if self.fns.patterned else None)
+        # the routed layers' census summed over launches: every live row's
+        # choices, and those that fell to experts this program holds
+        self.routed_assignments = {"assignments": 0, "held_assignments": 0}
         self._trash_row = table_row(
             [], self.fns.n_blk, self.sched.pool.trash_block)
         if self.store is not None:
@@ -842,7 +860,11 @@ class ServeEngine:
                 if self.fns.patterned:
                     self.state, load = moe
                     moe = []
-                    routed = _routed_counters(np.asarray(load))
+                    cfg = self.fns.cfg
+                    routed = _routed_counters(
+                        np.asarray(load), cfg.routed_first, cfg.routed_count)
+                    for k in self.routed_assignments:
+                        self.routed_assignments[k] += routed.get(k, 0)
                 elif moe:  # ([overflowed slots,] expert load, overflow)
                     moe = [np.asarray(x) for x in moe]
                     self._moe_load += moe[-2].astype(np.int64)
@@ -1132,6 +1154,9 @@ class ServeEngine:
             "expired": sd.expired,
             "preemptions": sd.preemptions,
             "live_blocks": sd.pool.live_blocks(),
+            "pool_bytes": _tree_bytes(self.pool),
+            "state_bytes": _tree_bytes(self.state),
+            "routed": dict(self.routed_assignments),
             "prefix_hit_tokens": sd.prefix_hit_tokens,
             "prefill_tokens_saved": sd.prefill_tokens_saved,
             "prefix_evictions": sd.prefix_evictions,

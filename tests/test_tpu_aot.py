@@ -327,6 +327,126 @@ def test_routed_ffn_compiles_to_native_grouped_products(v5e, rows, first,
     assert products <= flops < 1.5 * products  # not `held` times them
 
 
+# ---- a model with state-space mixers, at the Nemotron cell's widths (PR 33) --
+
+
+@pytest.mark.parametrize("chunk", [1, 128])
+def test_paged_kernel_compiles_under_a_group_of_16_at_head_size_128(
+        v5e, as_on_tpu, chunk):
+    """32 query heads of 128 over a pool of 2: a group's 16 query heads on
+    the sublanes of their pool head's step. A decode step of 128 rows takes
+    both pool heads a step; a 128-token chunk takes a divisor of them."""
+    heads, kv_heads, hd, block_size, n_blk = 32, 2, 128, 128, 16
+    b = 128 if chunk == 1 else 1
+    pool = sds((2048, kv_heads, hd, block_size), jnp.bfloat16)
+
+    def fn(q, k, v, tables, lengths):
+        return DA.paged_decode_attention(q, k, v, tables, lengths,
+                                         block_size=block_size)
+
+    hb = DA.paged_heads_per_step(
+        kv_heads, group=heads // kv_heads, chunk=chunk, hd=hd,
+        blk_k=block_size, dtype=jnp.bfloat16, q_dtype=jnp.bfloat16)
+    assert kv_heads % hb == 0 and (hb == kv_heads or chunk > 1)
+    compiled = compile_for(
+        SingleDeviceSharding(v5e[0]), fn,
+        sds((b, chunk, heads, hd), jnp.bfloat16), pool, pool,
+        sds((b, n_blk), jnp.int32), sds((b,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def entry_results(text: str, leaf: tuple[int, ...]) -> list[str]:
+    """The instructions of a compiled program's entry computation whose
+    result has ``leaf``'s shape, ``name opcode`` each (parameters and tuple
+    plumbing left out: they move nothing)."""
+    dims = ",".join(map(str, leaf))
+    entry = text[text.index("ENTRY"):]
+    found = re.findall(
+        r"%(\S+) = \w+\[" + dims + r"\]\{[^}]*\} ([\w-]+)\(", entry)
+    return [f"{name} {op}" for name, op in found
+            if op not in ("parameter", "get-tuple-element")]
+
+
+def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
+                                                                as_on_tpu):
+    """Both step programs of a Mamba-2, attention and routed layer at the
+    Nemotron cell's widths and geometry (128 slots, 2,048 blocks of 128, a
+    128-token chunk), pool and state donated: every donated byte is
+    aliased, a launch's temporaries are a small part of ONE 268 MB state
+    leaf (so no leaf, and no 638 MB bank of experts, is copied), the decode
+    program makes each state leaf once, in its own buffer, and the prefill
+    program writes its slot's row as a slice update."""
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        TransformerConfig,
+    )
+    from distributed_tensorflow_guide_tpu.serve import engine as E
+    from yardstick import harness, weights_nemotron
+
+    held = harness.load_json(
+        harness.HERE / "configs" / "nemotron-3-nano-30b-a3b.json")
+    held["hybrid_override_pattern"] = "M*E"
+    z = weights_nemotron.sizes_of(held)
+    dep = held["deployment"]
+    slots, chunk = dep["slots"], dep["prefill_chunk"]
+    cfg = TransformerConfig(
+        vocab_size=z["vocab"], num_layers=z["L"], num_heads=z["h"],
+        d_model=z["d"], d_ff=z["ff"], max_len=z["positions"], causal=True,
+        dtype=jnp.bfloat16, layers=z["layers"], norm="rmsnorm",
+        norm_eps=z["eps"], ffn_gate="relu2", positions="none",
+        num_kv_heads=z["kv"], override_head_dim=z["hd"],
+        conv_kernel=z["taps"], ssm_heads=z["H"], ssm_head_dim=z["P"],
+        ssm_groups=z["G"], ssm_state=z["N"], ssm_chunk=z["chunk"],
+        routed_experts=z["E"], routed_top_k=z["k"], routed_d_ff=z["eff"],
+        routed_d_ff_stored=z["eff_stored"], routed_first=z["first"],
+        routed_count=z["held"], routed_scale=z["scale"],
+        routed_norm_eps=1e-20, shared_d_ff=z["sff"])
+    assert (cfg.num_heads // cfg.kv_heads, cfg.head_dim) == (16, 128)
+    from distributed_tensorflow_guide_tpu.ops import routed_ffn
+
+    assert routed_ffn.grouped_impl(z["d"], z["eff_stored"]) == "pallas"
+    assert routed_ffn.grouped_impl(2048, 1536) == "native"  # LFM2's banks
+    fns = E.build_step_fns(cfg, slots=slots, num_blocks=dep["num_blocks"],
+                           block_size=dep["block_size"], prefill_chunk=chunk)
+    assert fns.donates_pool and fns.declared_donate_argnums == (1, 2)
+    params = jax.eval_shape(lambda: weights_nemotron.flax_tree(1, z))
+    pool = E.paged_cache_shapes(fns.cfg, slots)
+    state = E._serving_shapes(fns.cfg, slots)["state"]
+    ssm_leaf, conv_leaf = (slots, 64, 64, 128), (slots, 3, 6144)
+    assert {k: (v.shape, v.dtype) for k, v in
+            state["block_0"]["ssm"].items()} == {
+                "ssm": (ssm_leaf, jnp.float32),
+                "conv": (conv_leaf, jnp.bfloat16)}
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, state)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    one = SingleDeviceSharding(v5e[0])
+    decode = compile_for(
+        one, fns.decode, params, pool, state, i32(slots, fns.n_blk),
+        i32(slots), i32(slots), sds((slots, 2), jnp.uint32))
+    prefill = compile_for(
+        one, fns.prefill, params, pool, state, i32(1, fns.n_blk), i32(1),
+        i32(1, chunk), i32(), sds((2,), jnp.uint32), i32())
+    ssm_bytes = 4 * slots * 64 * 64 * 128
+    for compiled in (decode, prefill):
+        text = compiled.as_text()
+        # the paged kernel, two cache writes, and the two grouped products
+        # as the Pallas call with derived tiles (2688 and 1920 are no
+        # multiples of 512: the native call would tile them 128 x 128)
+        assert text.count("tpu_custom_call") >= 5
+        assert "%ragged-dot-none" not in text and "jit(gmm)" in text
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == donated
+        assert mem.temp_size_in_bytes < ssm_bytes // 8
+    # decode: the leaf comes out of one fusion (state in, state and y out)
+    # and nothing else has its shape; prefill: a slice update in place
+    assert entry_results(decode.as_text(), ssm_leaf) == []
+    moved = entry_results(prefill.as_text(), ssm_leaf)
+    assert len(moved) == 1 and "fusion" in moved[0], moved
+    moved = entry_results(prefill.as_text(), conv_leaf)
+    assert moved and all("dynamic-update-slice" in m for m in moved), moved
+    assert not FA.fallback_stats()
+
+
 # ---- whole programs, at chip_smoke.py's sizes -------------------------------
 
 
