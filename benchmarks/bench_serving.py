@@ -595,6 +595,7 @@ def main() -> None:
             over ``progress_rids`` crosses it (the MTTR probe)."""
             pending = sorted(pending, key=lambda r: r[1])
             now, events, shed_rids, caught_up = start_now, [], [], None
+            killed = False
 
             def progress():
                 return sum(len(e.sched.emitted.get(r, []))
@@ -634,11 +635,15 @@ def main() -> None:
                     caught_up = now
                 if kill_at_tokens is not None \
                         and progress() >= kill_at_tokens:
-                    return dict(events=events, now=now, pending=pending,
-                                shed=shed_rids, killed=True,
-                                caught_up=caught_up)
+                    killed = True
+                    break
+            # the engine hands a launch's events out one call later: what
+            # it still holds (a snapshot settled it, or the kill came
+            # first) the client has seen by now
+            events.extend(dataclasses.replace(ev, time=now)
+                          for ev in e.settle())
             return dict(events=events, now=now, pending=pending,
-                        shed=shed_rids, killed=False, caught_up=caught_up)
+                        shed=shed_rids, killed=killed, caught_up=caught_up)
 
         wl = make_workload(rates[top], args.requests, tag=30)
         wl_rids = [r for r, _, _, _ in wl]
@@ -948,8 +953,9 @@ def main() -> None:
                 for i in range(N):
                     rid = act[i]
                     if rid is not None and rid in e.sched.finished:
-                        toks = np.asarray(e.sched.emitted.get(rid, []),
-                                          np.int32)
+                        # completions() settles: a stream is finished in
+                        # the books a call before its last token is here
+                        toks = np.asarray(e.completions()[rid], np.int32)
                         streams[rid] = toks
                         act[i] = None
                         turn[i] += 1

@@ -27,6 +27,20 @@ to a one-shot ``make_generate_fn`` run of that request alone, no matter
 how scheduling interleaved it (the engine-vs-one-shot parity tests pin
 this, greedy and sampled, across the decode levers).
 
+One launch in flight (PR 34): a tick dispatches its launch and only then
+fetches the tokens of the launch BEFORE it, so the device runs one program
+while the host hands out the last one's tokens and plans the next. That
+takes two things. The decode program's ``last_tok`` is a device vector the
+engine keeps (``_pending``: a launch's tokens are merged into it by one
+small jitted ``where``, never fetched for it), and the scheduler's
+bookkeeping is advanced at the dispatch with the token's value left open
+(scheduler.py: nothing but ``emitted`` and ``pending`` depends on the
+value). :meth:`ServeEngine.settle` fetches and applies what is owed; the
+engine calls it before anything that reads a token's value, and at once
+for a model whose bookkeeping depends on what comes back (``MoEMLP``'s
+overflow flags). A launch's events are returned by the ``step()`` call
+after the one that dispatched it.
+
 Serving under fire (PR 11) — the same position-derived keys are what
 make every recovery path *bitwise-safe*:
 
@@ -235,6 +249,31 @@ def _pool_scatter(pool, idx, rows):
     return jax.tree.unflatten(
         treedef, [leaf.at[idx].set(r.astype(leaf.dtype))
                   for leaf, r in zip(leaves, rows)])
+
+
+@jax.jit
+def _merge_tokens(pending, tokens, rows):
+    """The slots' pending tokens after a launch: the rows of ``rows`` take
+    the launch's ``tokens`` (a decode launch's vector, a prompt's one
+    sample, or what the host knows and the device does not), the others
+    keep theirs. Dispatched after the launch, never waited for."""
+    return jnp.where(rows, tokens, pending)
+
+
+@dataclasses.dataclass
+class _Launch:
+    """One dispatched launch until it is settled: what the device will
+    hand back, and what the scheduler was told with the values left open."""
+
+    kind: str
+    arg: object  # the prefill slot, or the decode rows
+    ids: dict  # the spans' tick (and rid)
+    now: float
+    t0: float
+    outs: tuple  # (tokens, [overflowed,] [census ...]) on the device
+    payload: dict | None  # the recorder's launch identity
+    produced: list | None = None  # None: not advanced until it is fetched
+    owed: list = dataclasses.field(default_factory=list)
 
 
 def _routed_counters(load: np.ndarray, first: int = 0,
@@ -573,7 +612,7 @@ class ServeEngine:
                                       h2d=self._cache_h2d,
                                       h2d_many=self._cache_h2d_many)
                       if self.store is not None else None),
-            recorder=self.rec)
+            recorder=self.rec, settle=self._settle)
         if self.fns.lora:
             # the bank is a jit-operand (not a closed-over constant):
             # swapping adapter weights never retraces the two programs
@@ -603,6 +642,20 @@ class ServeEngine:
             trash = self.sched.pool.trash_block
             self._cache_h2d(trash, self._cache_d2h(trash))
         self.steps = {"decode": 0, "prefill": 0, "idle": 0}
+        # one launch in flight: the slots' pending tokens where the decode
+        # program reads them, on the device (row i is slot i's; ``_row_slot``
+        # says whose token a row holds, so that a slot whose token only the
+        # host knows, a swapped-in continuation's, is set from there); the
+        # launch not yet settled; the settled events not yet handed out,
+        # with the kind of the launch they came from
+        self._pending = jax.device_put(np.zeros((slots,), np.int32),
+                                       self.device)
+        self._row_slot: list = [None] * slots
+        self._inflight: _Launch | None = None
+        self._settled: list[Event] = []
+        self._settled_kind: str | None = None
+        self.launches = 0
+        self.overlapped_launches = 0  # dispatched with the last unsettled
         # MoE serving census (observe-only, absorbed by obs/metrics):
         # per-expert token load / overflow counts summed over launches
         # and layers, plus the stall tally of the degrade-to-overflow
@@ -790,17 +843,27 @@ class ServeEngine:
     def step(self, now: float = 0.0) -> tuple[list[Event], str]:
         """One engine tick: apply due chaos faults, sweep lifecycle
         (cancellations / deadlines), admit arrived requests, launch (at
-        most) one program, apply its results. Returns (events, kind)
-        with kind in {"prefill", "decode", "idle"} — the bench times
-        this call to get per-launch service time.
+        most) one program, then settle the launch BEFORE it: fetch its
+        tokens and hand out its events while the device runs this one.
+        Returns (events, kind): the events and the kind, in {"prefill",
+        "decode"}, of the launch that was settled, after them what the
+        sweep ended in this call. A call that launched and had nothing to
+        settle (the first after idle) returns ``([], kind)`` of what it
+        launched; one with nothing to launch settles what is owed and
+        returns its kind; "idle" means nothing was launched and nothing
+        was owed. The bench times this call to get per-launch service
+        time.
 
         The tick is one span, ``engine.tick``, and its phases five more
         under it (obs/tracing.span: on the profiler's clock whenever a
         session runs, in the recorder when it is enabled): ``schedule``
         up to the plan, ``build`` the launch's host operands,
-        ``dispatch`` the jitted call until it returns, ``fetch`` the host
-        blocked until the device hands the tokens back, ``apply`` from
-        the scheduler's bookkeeping to the lifecycle events."""
+        ``dispatch`` the jitted calls until they return (``overlapped``:
+        the last launch was still unsettled), then ``fetch`` the host
+        blocked until the device hands the tokens back and ``apply``
+        from filling them in to the lifecycle events. The last two carry
+        the ``tick`` (and ``rid``) of the launch they settle, not of the
+        call they run in."""
         tick = self._tick
         self._tick += 1
         rec, sd = self.rec, self.sched
@@ -814,7 +877,7 @@ class ServeEngine:
                         self.chaos.obs_now = now
                     self._apply_chaos(tick, now)
                 self._release_pressure(tick)
-                events = [Event(now, *t) for t in sd.sweep(now)]
+                swept = [Event(now, *t) for t in sd.sweep(now)]
                 if self.store is not None:
                     # prefetch ahead of schedule: queued spilled
                     # continuations' h2d copies land NOW, before this
@@ -824,76 +887,188 @@ class ServeEngine:
                     sd.prefetch()
                 sd.admit(now)
                 kind, arg = sd.plan()
+            t0 = time.perf_counter()
+            if kind == "idle":
+                self._settle(now)
+            else:
+                self._dispatch(kind, arg, tick, now)
+            # the launch before this one predates this call's sweep: its
+            # tokens come first, so none follows its request's terminal
+            events, settled = self._settled + swept, self._settled_kind
+            self._settled, self._settled_kind = [], None
+            if settled is not None:
+                kind = settled
             if kind == "idle":
                 self.last_tick_s = 0.0
                 self.steps[kind] += 1
-                if rec.enabled and events:
-                    self._emit_lifecycle(events, now, tick)
-                return events, kind
-            if kind == PREFILL:
-                slot = sd.slots[arg]
-                rows, fn, program = 1, self.fns.prefill, "prefill_chunk_step"
-                ids = {"tick": tick, "rid": slot.rid}
             else:
-                rows, fn, program = len(arg), self.fns.decode, "decode_step"
-                ids = {"tick": tick}
-            launch = None
-            if rec.enabled:
-                # launch identity, taken BEFORE the program runs: apply_*
-                # frees a slot the moment its request completes
-                launch = ({"slot": arg, "rid": slot.rid,
-                           "chunk": slot.chunk_cursor} if kind == PREFILL
-                          else {"slots": list(arg),
-                                "rids": [sd.slots[i].rid for i in arg]})
-            t0 = time.perf_counter()
-            with span(rec, "engine.build", cat="serve", kind=kind,
-                      rows=rows, **ids):
-                args = (self._prefill_operands(arg) if kind == PREFILL
-                        else self._decode_operands(arg))
-            with span(rec, "engine.dispatch", cat="serve", program=program,
-                      **ids):
-                toks, self.pool, *moe = self._launch(
-                    lambda: fn(*args), tag="serve_" + program)
-            routed = {}
-            with span(rec, "engine.fetch", cat="serve", **ids):
-                toks = np.asarray(toks)
-                if self.fns.patterned:
-                    self.state, load = moe
-                    moe = []
-                    cfg = self.fns.cfg
-                    routed = _routed_counters(
-                        np.asarray(load), cfg.routed_first, cfg.routed_count)
-                    for k in self.routed_assignments:
-                        self.routed_assignments[k] += routed.get(k, 0)
-                elif moe:  # ([overflowed slots,] expert load, overflow)
-                    moe = [np.asarray(x) for x in moe]
-                    self._moe_load += moe[-2].astype(np.int64)
-                    self._moe_overflow += moe[-1].astype(np.int64)
-            with span(rec, "engine.apply", cat="serve", **ids, **routed):
-                if kind == PREFILL:
-                    produced = sd.apply_prefill(arg, int(toks))
-                else:
-                    produced = self._apply_decode(
-                        arg, toks, moe[0] if moe else None)
-                events.extend(Event(now, *ev) for ev in produced)
                 self.last_tick_s = time.perf_counter() - t0
-                self.steps[kind] += 1
-                if launch is not None:
-                    launch["tick"] = tick
-                    launch["dur_s"] = self.last_tick_s
-                    rec.emit(f"{kind}.launch", cat="serve", actor="engine",
-                             payload=launch, t=now)
-                for e in events:
-                    if e.first and e.status == "ok":
-                        arrival = sd.meta.get(e.rid, (now, None, None))[0]
-                        ttft = max(0.0, now - arrival)
-                        if np.isfinite(ttft):
-                            self._ttft_ewma = (
-                                ttft if self._ttft_ewma is None
-                                else 0.8 * self._ttft_ewma + 0.2 * ttft)
-                if rec.enabled and events:
-                    self._emit_lifecycle(events, now, tick)
+            if rec.enabled and swept:
+                self._emit_lifecycle(swept, now, tick)
         return events, kind
+
+    def _dispatch(self, kind: str, arg, tick: int, now: float) -> None:
+        """Launch what the plan asked for, tell the scheduler at once
+        (the tokens' values left open), and only then settle the launch
+        before it, which the device finished while this one was built. A
+        ``MoEMLP`` model's bookkeeping depends on what comes back (an
+        overflowed row keeps its token), so its launch is settled at
+        once, and advanced there: the same calls, none in flight."""
+        rec, sd = self.rec, self.sched
+        t0 = time.perf_counter()
+        if kind == PREFILL:
+            slot = sd.slots[arg]
+            rows, fn, program = 1, self.fns.prefill, "prefill_chunk_step"
+            ids = {"tick": tick, "rid": slot.rid}
+            last_chunk = (slot.chunk_cursor + 1
+                          == sd.prefill_done_chunks(arg))
+        else:
+            rows, fn, program = len(arg), self.fns.decode, "decode_step"
+            ids = {"tick": tick}
+        payload = None
+        if rec.enabled:
+            # launch identity, taken BEFORE the scheduler is told: it
+            # frees a slot the moment its request completes
+            payload = ({"slot": arg, "rid": slot.rid,
+                        "chunk": slot.chunk_cursor} if kind == PREFILL
+                       else {"slots": list(arg),
+                             "rids": [sd.slots[i].rid for i in arg]})
+            payload["tick"] = tick
+        with span(rec, "engine.build", cat="serve", kind=kind,
+                  rows=rows, **ids):
+            args = (self._prefill_operands(arg) if kind == PREFILL
+                    else self._decode_operands(arg))
+        overlapped = int(self._inflight is not None)
+        with span(rec, "engine.dispatch", cat="serve", program=program,
+                  overlapped=overlapped, **ids):
+            toks, self.pool, *outs = self._launch(
+                lambda: fn(*args), tag="serve_" + program)
+            if self.fns.patterned:
+                self.state, *outs = outs
+            if kind != PREFILL or last_chunk:
+                # the next decode launch reads these tokens where they
+                # are: a prompt's first token in its slot's row, a decode
+                # launch's in the rows that were ready
+                took = np.zeros((self.num_slots,), bool)
+                took[arg] = True
+                self._pending = _merge_tokens(self._pending, toks, took)
+                if kind == PREFILL:
+                    self._row_slot[arg] = slot
+        self.launches += 1
+        self.overlapped_launches += overlapped
+        self.steps[kind] += 1
+        launch = _Launch(kind, arg, ids, now, t0, (toks, *outs), payload)
+        before, self._inflight = self._inflight, launch
+        if not self.fns.moe:
+            launch.produced = self._advance(launch)
+            launch.owed, sd.owed = sd.owed, []
+        if before is not None:
+            self._fetch_apply(before, now)
+        if self.fns.moe:
+            self._settle(now)
+
+    def _advance(self, launch: _Launch, toks=None,
+                 overflowed=None) -> list[tuple]:
+        """The scheduler's bookkeeping for ``launch``: with ``toks`` None
+        every token's value is left open (``Scheduler.fill`` takes it
+        later). ``overflowed`` (``MoEMLP`` only, and then ``toks`` is
+        known) flags the slots whose token came from a forward that
+        skipped its expert at some layer."""
+        sd = self.sched
+        if launch.kind == PREFILL:
+            return sd.apply_prefill(launch.arg,
+                                    None if toks is None else int(toks))
+        produced, stalled = [], 0
+        for i in launch.arg:
+            if overflowed is not None and overflowed[i]:
+                # degrade-to-overflow: discard the token and leave
+                # pending/written untouched, so the SAME token retries
+                # next tick (cache rewrites are idempotent; dispatch fills
+                # in slot order, so the lowest contending slot always
+                # advances). A hot expert costs goodput, never a dropped
+                # or corrupted token. The device's row took the discarded
+                # sample: the host sets it again.
+                stalled += 1
+                self._row_slot[i] = None
+                continue
+            produced.extend(sd.apply_decode(
+                i, None if toks is None else int(toks[i])))
+        if stalled:
+            self._moe_stall_slot_ticks += stalled
+            self._moe_stall_ticks += 1
+        return produced
+
+    def _settle(self, now: float | None = None) -> None:
+        """Fetch and apply the launch in flight, if there is one; its
+        events wait in ``_settled`` for the next hand-out."""
+        launch, self._inflight = self._inflight, None
+        if launch is not None:
+            self._fetch_apply(launch, now)
+
+    def settle(self) -> list[Event]:
+        """Fetch the tokens the device still owes and fill them in: after
+        it every emitted token has its value and nothing is in flight. A
+        no-op with nothing owed. Returns the events not yet handed out
+        (what the next :meth:`step` would have returned first). The
+        engine settles by itself before anything that reads a token's
+        value: :meth:`completions`, :meth:`health`, a snapshot, a stream's
+        export, a preemption, :meth:`close`."""
+        self._settle()
+        events, self._settled, self._settled_kind = self._settled, [], None
+        return events
+
+    def _fetch_apply(self, launch: _Launch, now: float | None) -> None:
+        """Settle ``launch``: wait for what it hands back, fill the
+        values in (or, where nothing was advanced yet, advance with them)
+        and build its events, timed ``now`` (the call that hands them
+        out; the dispatching call's where there is none)."""
+        rec, sd, ids = self.rec, self.sched, launch.ids
+        now = launch.now if now is None else now
+        toks, *outs = launch.outs
+        routed = {}
+        with span(rec, "engine.fetch", cat="serve", **ids):
+            toks = np.asarray(toks)
+            if self.fns.patterned:
+                cfg = self.fns.cfg
+                routed = _routed_counters(
+                    np.asarray(outs[0]), cfg.routed_first, cfg.routed_count)
+                for k in self.routed_assignments:
+                    self.routed_assignments[k] += routed.get(k, 0)
+            elif outs:  # ([overflowed slots,] expert load, overflow)
+                outs = [np.asarray(x) for x in outs]
+                self._moe_load += outs[-2].astype(np.int64)
+                self._moe_overflow += outs[-1].astype(np.int64)
+        with span(rec, "engine.apply", cat="serve", **ids, **routed):
+            if launch.produced is None:
+                produced = self._advance(
+                    launch, toks, outs[0] if len(outs) == 3 else None)
+            else:
+                values = toks.tolist()
+                values = ([values] * len(launch.owed)
+                          if launch.kind == PREFILL
+                          else [values[i] for i in launch.arg])
+                sd.fill(launch.owed, values)
+                produced = [(rid, value, first, done)
+                            for (rid, _, first, done), value
+                            in zip(launch.produced, values)]
+            events = [Event(now, *ev) for ev in produced]
+            if launch.payload is not None:
+                launch.payload["dur_s"] = time.perf_counter() - launch.t0
+                rec.emit(f"{launch.kind}.launch", cat="serve",
+                         actor="engine", payload=launch.payload,
+                         t=launch.now)
+            for e in events:
+                if e.first:
+                    arrival = sd.meta.get(e.rid, (now, None, None))[0]
+                    ttft = max(0.0, now - arrival)
+                    if np.isfinite(ttft):
+                        self._ttft_ewma = (
+                            ttft if self._ttft_ewma is None
+                            else 0.8 * self._ttft_ewma + 0.2 * ttft)
+            if rec.enabled and events:
+                self._emit_lifecycle(events, now, ids["tick"])
+        self._settled.extend(events)
+        self._settled_kind = launch.kind
 
     def _emit_lifecycle(self, events: list[Event], now: float,
                         tick: int) -> None:
@@ -989,21 +1164,29 @@ class ServeEngine:
 
     def _decode_operands(self, ready: list[int]) -> tuple:
         """One decode step over the ``ready`` slots as the decode
-        program's arguments; every other row reads the trash block."""
+        program's arguments; every other row reads the trash block. The
+        tokens go in where they are, on the device (``_pending``: not
+        donated, so a retried launch reads the same); a row whose slot's
+        token only the host knows is set from there first."""
         S, n_blk = self.num_slots, self.fns.n_blk
         tables = np.tile(self._trash_row, (S, 1))
         written = np.zeros((S,), np.int32)
-        last_tok = np.zeros((S,), np.int32)
         keys = np.zeros((S, 2), np.uint32)
         adapter_ids = np.zeros((S,), np.int32)
+        known, stale = np.zeros((S,), np.int32), np.zeros((S,), bool)
         for i in ready:
             s = self.sched.slots[i]
             tables[i] = table_row(s.blocks, n_blk,
                                   self.sched.pool.trash_block)
             written[i] = s.written
-            last_tok[i] = s.pending
             keys[i] = s.rng
             adapter_ids[i] = s.adapter
+            if self._row_slot[i] is not s:
+                self._row_slot[i] = s
+                known[i], stale[i] = s.pending, True
+        if stale.any():
+            self._pending = _merge_tokens(self._pending, known, stale)
+        last_tok = self._pending
         if self.fns.patterned:
             return (self.params, self.pool, self.state, tables, written,
                     last_tok, keys)
@@ -1011,27 +1194,6 @@ class ServeEngine:
         if self.fns.lora:
             args += (self.adapters, adapter_ids)
         return args
-
-    def _apply_decode(self, ready: list[int], nxt, overflowed) -> list:
-        """Hand the scheduler each ready slot's sampled token.
-        ``overflowed`` (MoE only) flags the slots whose token came from a
-        forward that skipped its expert at some layer."""
-        produced, stalled = [], 0
-        for i in ready:
-            if overflowed is not None and overflowed[i]:
-                # degrade-to-overflow: discard the token and leave
-                # pending/written untouched, so the SAME token retries
-                # next tick (cache rewrites are idempotent; dispatch fills
-                # in slot order, so the lowest contending slot always
-                # advances). A hot expert costs goodput, never a dropped
-                # or corrupted token.
-                stalled += 1
-                continue
-            produced.extend(self.sched.apply_decode(i, int(nxt[i])))
-        if stalled:
-            self._moe_stall_slot_ticks += stalled
-            self._moe_stall_ticks += 1
-        return produced
 
     # ---- chaos application (testing.chaos serve kinds) -------------------
 
@@ -1128,11 +1290,13 @@ class ServeEngine:
             ticks += 1
             if max_ticks is not None and ticks >= max_ticks:
                 break
+        events.extend(self.settle())  # a bounded run leaves nothing owed
         self._release_pressure(float("inf"))
         return events
 
     def completions(self) -> dict[int, list[int]]:
         """rid -> every token emitted so far (complete or not)."""
+        self._settle()
         return {rid: list(toks)
                 for rid, toks in self.sched.emitted.items()}
 
@@ -1143,7 +1307,12 @@ class ServeEngine:
 
     def health(self) -> dict:
         """Engine health counters — what the CLI/examples surface so a
-        degraded engine is observable, not silent."""
+        degraded engine is observable, not silent. ``launches`` counts
+        the programs dispatched and ``overlapped_launches`` those
+        dispatched while the launch before was still unsettled: how often
+        the device had its next program before the host asked for the
+        last one's tokens."""
+        self._settle()  # the routing sums are of settled launches
         sd = self.sched
         return {
             "resident": sum(s is not None for s in sd.slots),
@@ -1178,6 +1347,8 @@ class ServeEngine:
             "last_tick_s": self.last_tick_s,
             "ticks": self._tick,
             "launch_failures": self.launch_failures,
+            "launches": self.launches,
+            "overlapped_launches": self.overlapped_launches,
             **({"moe": {
                 "expert_load": [int(x) for x in self._moe_load],
                 "expert_overflow": [int(x) for x in self._moe_overflow],
@@ -1341,6 +1512,7 @@ class ServeEngine:
         exists."""
         if self._ckpt is None:
             raise ValueError("ServeEngine(snapshot_dir=...) not configured")
+        self._settle()
         got = self._ckpt.restore_latest_valid(None)
         if got is None:
             if self.rec.enabled:
@@ -1373,6 +1545,7 @@ class ServeEngine:
         and drop the prefix cache's block references — device AND host
         tier — plus any banked spill records, so the joint
         ``Scheduler.check_leaks()`` audits clean after shutdown."""
+        self._settle()
         self.sched.release_prefix_cache()
         if self.store is not None:
             self.sched.release_spill_store()

@@ -323,6 +323,9 @@ class FleetScheduler:
         # the first exception a replica's step let escape: the breaker
         # recovers from faults, so whoever must know WHY reads it here
         self.first_fault: Exception | None = None
+        # events a replica's engine still held when it left the rotation
+        # (or was read behind its back): the next step() hands them out
+        self._late: list[Event] = []
         self.migration_dups_dropped = 0
         self.autoscale_added = 0
         self.autoscale_retired = 0
@@ -627,6 +630,25 @@ class FleetScheduler:
             if e.done:
                 ent["done"] = True
 
+    def _settle_replica(self, i: int) -> None:
+        """A replica's engine dispatches a launch one call before it
+        hands out its tokens. Before it leaves the rotation, or its
+        scheduler is read behind its back, it settles: the tokens of the
+        launch in flight are observed here like any others, and their
+        events leave with this tick's."""
+        evs = self.engines[i].settle()
+        self._observe(i, evs)
+        self._late.extend(evs)
+
+    def settle(self) -> list[Event]:
+        """Settle every replica's engine and return the events no step
+        has handed out yet: after a drive loop of one's own, what
+        :meth:`run` does at its end."""
+        for i in range(len(self.engines)):
+            self._settle_replica(i)
+        events, self._late = self._late, []
+        return events
+
     def _stamp_handoff(self, record: dict) -> dict:
         """Give a migration / re-anchor record its adoption identity:
         the fleet generation it left in, and a unique handoff id — the
@@ -766,6 +788,7 @@ class FleetScheduler:
         is reachable (world shed, stall, breaker ejection); a HARD crash
         goes through :meth:`_crash_replica`, which never touches the
         dead engine.  Returns the number of streams re-anchored."""
+        self._settle_replica(idx)
         eng = self.engines[idx]
         sd = eng.sched
         live = sorted((s for s in sd.slots if s is not None),
@@ -827,6 +850,10 @@ class FleetScheduler:
         — and re-anchored queue-front as a continuation.  A FRESH
         engine (memoized geometry, compiles nothing) takes the slot and
         returns through the breaker's half-open probe."""
+        # the last scrape reads what the device had handed back: a stream
+        # that ended in the launch in flight is terminal here AND in the
+        # ledger, or it would be both buried and re-anchored
+        self._settle_replica(idx)
         eng = self.engines[idx]
         self.generation += 1
         self._harvest(eng)
@@ -1175,6 +1202,7 @@ class FleetScheduler:
                                  "reason": "drain"},
                         t=now)
             if not sd.has_resident and not sd.queue:
+                self._settle_replica(idx)
                 self._draining.discard(idx)
                 self._live.discard(idx)
                 self.autoscale_retired += 1
@@ -1550,6 +1578,9 @@ class FleetScheduler:
             busy = busy or kind != "idle"
         if self.disagg:
             busy = bool(self._migrate_prefilled(now)) or busy
+        if self._late:
+            events.extend(self._late)
+            self._late = []
         return events, ("busy" if busy else "idle")
 
     def next_arrival(self) -> float | None:
@@ -1589,6 +1620,7 @@ class FleetScheduler:
             ticks += 1
             if max_ticks is not None and ticks >= max_ticks:
                 break
+        events.extend(self.settle())
         for i in sorted(self._live):
             self.engines[i]._release_pressure(float("inf"))
         return events

@@ -61,6 +61,17 @@ and a swap-in restores the *same bytes* the re-prefill would recompute —
 the hierarchy moves cost, not content.  All device<->host traffic is
 counted (``spill_*`` counters) so the byte model in benchmarks/common.py
 can reconcile it against the PCIe roofline.
+
+Tokens left open (PR 34): the engine advances this bookkeeping the moment
+it has dispatched a launch, before the device has handed the tokens back
+(``apply_prefill`` / ``apply_decode`` with no token). Nothing here but
+``emitted`` and a slot's ``pending`` depends on a token's VALUE — a
+request ends by its budget, a deadline or a cancellation, never by what
+was sampled — so the next plan, the block growth, the slot freed and the
+next admission are exact while the value is owed. The place in
+``emitted`` holds None until :meth:`Scheduler.fill` writes it; whatever
+reads a value (``_preempt``, ``detach_stream``, ``snapshot_state``) calls
+``self.settle()`` first, which the engine points at its own.
 """
 
 from __future__ import annotations
@@ -146,7 +157,7 @@ class Scheduler:
                  tenant_quotas: dict[int, dict] | None = None,
                  drr_quantum: int | None = None,
                  host_store=None, cache_io=None,
-                 recorder=None) -> None:
+                 recorder=None, settle=None) -> None:
         if max_len % prefill_chunk:
             raise ValueError(
                 f"prefill_chunk {prefill_chunk} must divide max_len "
@@ -164,6 +175,13 @@ class Scheduler:
         self.blocks_per_seq = max_len // block_size
         self.queue: list[Request] = []  # FIFO; preemptions go to the front
         self.emitted: dict[int, list[int]] = {}  # rid -> all emitted tokens
+        # tokens an advance left open, (slot, place in emitted[rid]), until
+        # the engine takes them with its launch; ``_open`` counts the
+        # places not yet filled. ``settle`` is called before anything here
+        # reads a token's value (the engine's own settle; a no-op alone).
+        self.owed: list[tuple[_Slot, int]] = []
+        self._open = 0
+        self.settle = settle if settle is not None else lambda: None
         self.first_emit: dict[int, bool] = {}  # rid -> saw first token yet
         self.done: set[int] = set()
         self._seq = 0  # admission counter (preemption picks the youngest)
@@ -951,6 +969,7 @@ class Scheduler:
         return max(live)[1]  # youngest admission goes first
 
     def _preempt(self, i: int) -> None:
+        self.settle()  # the continuation is built from emitted values
         slot = self.slots[i]
         # hierarchy on: demote the written blocks to host instead of
         # destroying them — the continuation below still queues, but
@@ -1015,6 +1034,7 @@ class Scheduler:
         travel here — the engine d2h-copies :meth:`migratable_blocks`
         BEFORE calling this and attaches them to the returned record.
         Raises KeyError for unknown or terminal rids."""
+        self.settle()  # the record carries emitted values
         if rid in self.finished:
             raise KeyError(
                 f"rid {rid} is terminal ({self.finished[rid]}); "
@@ -1184,10 +1204,12 @@ class Scheduler:
         s = self.slots[slot_idx]
         return -(-len(s.prompt) // self.prefill_chunk)
 
-    def apply_prefill(self, slot_idx: int, token: int) -> list[tuple]:
+    def apply_prefill(self, slot_idx: int,
+                      token: int | None = None) -> list[tuple]:
         """One chunk finished for ``slot_idx``; ``token`` is the program's
         sample from the chunk's last valid row (meaningful only on the
-        final chunk). Returns [(rid, token, first, done)] events."""
+        final chunk; None leaves its value open, see :meth:`fill`).
+        Returns [(rid, token, first, done)] events."""
         s = self.slots[slot_idx]
         s.chunk_cursor += 1
         s.written = min(s.chunk_cursor * self.prefill_chunk,
@@ -1197,7 +1219,6 @@ class Scheduler:
         # final chunk: the sample at position P is the first new token
         s.written = len(s.prompt)
         s.phase = DECODE
-        s.pending = int(token)
         if self.prefix is not None:
             # cache the FULL prompt blocks (all their positions hold true
             # prompt KV, written by deterministic chunk-aligned prefill —
@@ -1209,17 +1230,26 @@ class Scheduler:
                     s.prompt[:n_full * self.block_size],
                     s.blocks[:n_full], adapter=int(s.adapter),
                     pool=self.pool)
-        return self._emit(slot_idx, int(token))
+        return self._emit(slot_idx, token)
 
-    def apply_decode(self, slot_idx: int, token: int) -> list[tuple]:
+    def apply_decode(self, slot_idx: int,
+                     token: int | None = None) -> list[tuple]:
         s = self.slots[slot_idx]
         s.written += 1  # the step wrote pending's k/v at `written`
-        s.pending = int(token)
-        return self._emit(slot_idx, int(token))
+        return self._emit(slot_idx, token)
 
-    def _emit(self, slot_idx: int, token: int) -> list[tuple]:
+    def _emit(self, slot_idx: int, token: int | None) -> list[tuple]:
+        """The slot's next token: its pending one, and the next place in
+        ``emitted``. With ``token`` None both hold None and the place is
+        noted in ``owed``; everything else is exact already."""
         s = self.slots[slot_idx]
         rid = s.rid
+        if token is None:
+            self.owed.append((s, len(self.emitted[rid])))
+            self._open += 1
+        else:
+            token = int(token)
+        s.pending = token
         self.emitted[rid].append(token)
         first = not self.first_emit[rid]
         self.first_emit[rid] = True
@@ -1234,6 +1264,17 @@ class Scheduler:
             self.finished[rid] = "done"
             self._tc(s.tenant)["done"] += 1
         return [(rid, token, first, done)]
+
+    def fill(self, owed: list[tuple], tokens: list[int]) -> None:
+        """The values of the tokens one launch's advance left open, in its
+        order. A slot's pending token is its request's newest: where a
+        later advance has left a newer one open, that one fills it."""
+        for (slot, place), token in zip(owed, tokens, strict=True):
+            toks = self.emitted[slot.rid]
+            toks[place] = token
+            if place == len(toks) - 1:
+                slot.pending = token
+        self._open -= len(owed)
 
     # ---- lifecycle: cancellation, deadlines (PR 11) ----------------------
 
@@ -1321,6 +1362,7 @@ class Scheduler:
         deliberately NOT captured — restore re-prefills each
         continuation, and position-derived sampling keys make the re-run
         land on the same stream bitwise."""
+        self.settle()
         requests = []
         live = sorted((s for s in self.slots if s is not None),
                       key=lambda s: s.admitted_seq)
@@ -1452,7 +1494,9 @@ class Scheduler:
 
     @property
     def has_resident(self) -> bool:
-        return any(s is not None for s in self.slots)
+        """A slot is held, or a token is still owed to a request that held
+        one: a drain loop on this steps once more and is handed it."""
+        return self._open > 0 or any(s is not None for s in self.slots)
 
     @property
     def has_queued(self) -> bool:

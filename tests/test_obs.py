@@ -449,23 +449,75 @@ def test_span_lands_in_the_profilers_trace(profiled, name):
 
 
 def test_tick_phases_nest_in_their_tick_and_do_not_overlap(profiled):
+    """One launch in flight: a tick schedules, builds and dispatches its
+    own launch and then fetches and applies the launch BEFORE it, so its
+    last two phases carry that launch's tick. The first tick after idle
+    has nothing to settle, the last nothing to launch."""
     ticks = {r[3]["tick"]: r for r in profiled.spans if r[0] == "engine.tick"}
     assert sorted(ticks) == list(range(profiled.ticks))
-    by_tick: dict = {}
-    for r in profiled.spans:
-        if r[0] in ENGINE_PHASES:
-            by_tick.setdefault(r[3]["tick"], []).append(r)
-    launched = 0
-    for tick, (_, t_start, t_end, stats) in ticks.items():
-        phases = by_tick[tick]
-        names = [r[0] for r in phases]
-        assert names in (ENGINE_PHASES, ENGINE_PHASES[:1]), (tick, names)
-        launched += names == ENGINE_PHASES
-        assert t_start <= phases[0][1] and phases[-1][2] <= t_end
-        for before, after in zip(phases, phases[1:]):
+    phases = [r for r in profiled.spans if r[0] in ENGINE_PHASES]
+    launched, settled, unsettled = [], [], None
+    for tick, (_, t_start, t_end, stats) in sorted(ticks.items()):
+        inside = [r for r in phases if t_start <= r[1] and r[2] <= t_end]
+        names = [r[0] for r in inside]
+        assert names in (ENGINE_PHASES, ENGINE_PHASES[:3],
+                         ENGINE_PHASES[:1] + ENGINE_PHASES[3:],
+                         ENGINE_PHASES[:1]), (tick, names)
+        for before, after in zip(inside, inside[1:]):
             assert before[2] <= after[1], (tick, before[0], after[0])
         assert stats == {"tick": tick}
-    assert launched == profiled.launches
+        for r in inside:
+            own = r[0] in ENGINE_PHASES[:3]
+            assert r[3]["tick"] == (tick if own else unsettled), (tick, r[0])
+        if "engine.fetch" in names:
+            settled.append(unsettled)
+        if "engine.dispatch" in names:
+            (dispatch,) = (r for r in inside if r[0] == "engine.dispatch")
+            # overlapped: the launch before was still unsettled
+            assert dispatch[3]["overlapped"] == int(unsettled is not None)
+            launched.append(tick)
+            unsettled = tick
+        elif "engine.fetch" in names:
+            unsettled = None
+    assert len(phases) == sum(
+        t_start <= r[1] and r[2] <= t_end for r in phases
+        for _, t_start, t_end, _ in ticks.values())  # none outside a tick
+    # every launch is settled once, in order, by the tick after its own
+    assert launched == settled and len(launched) == profiled.launches
+    assert all(b == a + 1 for a, b in zip(launched, launched[1:]))
+
+
+def test_the_recorder_sees_a_launch_settled_under_its_own_tick(params):
+    """The same in the recorder: ``engine.fetch`` / ``engine.apply`` begin
+    with the tick (and rid) of the launch they settle, ``engine.dispatch``
+    says whether it overlapped, and ``health()`` counts both."""
+    rec = obs_events.FlightRecorder(capacity=1 << 14)
+    eng = _engine(CFG, params, recorder=rec)
+    _drive(eng)
+    begun = [e.payload for e in rec.events() if e.kind == "span.begin"]
+    by_name = {n: [p for p in begun if p["name"] == n]
+               for n in ENGINE_PHASES + ["engine.tick"]}
+    dispatched = by_name["engine.dispatch"]
+    health = eng.health()
+    assert health["launches"] == len(dispatched) == (
+        eng.steps["prefill"] + eng.steps["decode"])
+    assert [p["overlapped"] for p in dispatched] == [0] + [1] * (
+        len(dispatched) - 1)
+    assert health["overlapped_launches"] == len(dispatched) - 1
+    for name in ("engine.fetch", "engine.apply"):
+        got = [(p["tick"], p.get("rid")) for p in by_name[name]]
+        assert got == [(p["tick"], p.get("rid")) for p in dispatched], name
+    # a launch's recorder event keeps its own tick too
+    launches = [e.payload["tick"] for e in rec.events()
+                if e.kind in ("prefill.launch", "decode.launch")]
+    assert launches == [p["tick"] for p in dispatched]
+    firsts = {e.payload["rid"]: e.payload["tick"] for e in rec.events()
+              if e.kind == "req.first_token"}
+    last_chunk = {}
+    for p in dispatched:
+        if p["program"] == "prefill_chunk_step":
+            last_chunk[p["rid"]] = p["tick"]
+    assert firsts == last_chunk  # the tick that launched the last chunk
 
 
 def test_span_attrs_read_back_from_the_events_stats(profiled):

@@ -23,6 +23,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -445,6 +446,55 @@ def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
     moved = entry_results(prefill.as_text(), conv_leaf)
     assert moved and all("dynamic-update-slice" in m for m in moved), moved
     assert not FA.fallback_stats()
+
+
+def test_the_token_merge_lowers_and_the_step_pair_takes_what_it_took(
+        v5e, as_on_tpu):
+    """One launch in flight (PR 34): the slots' pending tokens stay on the
+    device, merged by one ``where`` a launch, at the serving cells' 24, 64
+    and 128 slots, from a decode launch's vector and from a prompt's one
+    sample. Neither step program changes for it: the decode program lowers
+    from the engine's own operands, the device vector among them, to the
+    text it lowers to from the host vector it used to be handed."""
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        Transformer,
+        TransformerConfig,
+    )
+    from distributed_tensorflow_guide_tpu.serve import engine as E
+
+    one = SingleDeviceSharding(v5e[0])
+    for slots in (24, 64, 128):
+        for tokens in (sds((slots,), jnp.int32), sds((), jnp.int32)):
+            merged = compile_for(one, E._merge_tokens,
+                                 sds((slots,), jnp.int32), tokens,
+                                 sds((slots,), jnp.bool_))
+            text = merged.as_text()
+            assert "select" in text and "tpu_custom_call" not in text
+            (out,) = jax.tree.leaves(merged.out_info)
+            assert (out.shape, out.dtype) == ((slots,), jnp.int32)
+            # nothing is donated: a retried launch reads the same vector
+            assert merged.memory_analysis().alias_size_in_bytes == 0
+    cfg = TransformerConfig(vocab_size=64, num_layers=2, num_heads=2,
+                            d_model=16, d_ff=32, max_len=256, causal=True,
+                            dtype=jnp.bfloat16)
+    tree = jax.eval_shape(Transformer(cfg).init, jax.random.PRNGKey(0),
+                          jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = E.ServeEngine(cfg, tree, slots=4, num_blocks=9, block_size=128,
+                        prefill_chunk=128)
+    eng.submit(E.Request(rid=0, prompt=np.arange(5, dtype=np.int32),
+                         max_new_tokens=4, rng=np.zeros((2,), np.uint32)))
+    eng.sched.admit(0.0)
+    operands = eng._decode_operands([0])
+    assert isinstance(operands[4], jax.Array)  # where the tokens are
+    as_before = operands[:4] + (np.zeros((4,), np.int32),) + operands[5:]
+
+    def lowered(args):
+        args = jax.tree.map(lambda a: sds(a.shape, a.dtype), args)
+        return eng.fns.decode.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    assert lowered(operands) == lowered(as_before)
+    assert "tpu_custom_call" in lowered(operands)
 
 
 # ---- whole programs, at chip_smoke.py's sizes -------------------------------
