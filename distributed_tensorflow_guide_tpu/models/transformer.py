@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -40,10 +41,17 @@ from distributed_tensorflow_guide_tpu.utils.activation_sharding import (
 
 Dtype = Any
 
-MIXERS = ("attention", "short_conv", "mamba2")
+MIXERS = ("attention", "short_conv", "mamba2", "mamba1", "window_attention",
+          "cross_attention", "gmu")
 FFNS = ("dense", "routed")
-#: the mixers whose sequences carry state beside their keys and values
-STATE_MIXERS = ("short_conv", "mamba2")
+#: the mixers whose sequences carry state beside their keys and values in
+#: the block pool (a window layer's keys and values are such state: they
+#: live in a ring of the slot's own, not in the pool)
+STATE_MIXERS = ("short_conv", "mamba2", "mamba1", "window_attention")
+#: the mixers ``HybridAttention`` runs
+HYBRID_ATTENTION = ("window_attention", "cross_attention")
+#: a window layer's two leaves of the ``state`` collection: its slots' rings
+WINDOW_LEAVES = ("win_key", "win_value")
 POSITIONS = ("table", "rotary", "none")
 
 
@@ -180,8 +188,12 @@ class TransformerConfig:
     moe_experts: int | None = None
     moe_capacity: int | None = None
     # The model as a PATTERN of layers (PR 28). ``layers`` set → layer ``i``
-    # is ``(mixer, ffn)``: a mixer kind (``"attention"`` | ``"short_conv"``
-    # | ``"mamba2"``) over a feed-forward kind (``"dense"`` | ``"routed"``),
+    # is ``(mixer, ffn)``: a mixer kind (one of ``MIXERS``: ``"attention"``
+    # | ``"short_conv"`` | ``"mamba2"`` | ``"mamba1"`` |
+    # ``"window_attention"`` | ``"cross_attention"``, which reads the cache
+    # of the last ``"attention"`` layer before it | ``"gmu"``, which gates
+    # by the scan output of the last ``"mamba1"`` layer before it) over a
+    # feed-forward kind (``"dense"`` | ``"routed"``),
     # either of which may be None: the layer is then the other half alone,
     # ``x + f(norm(x))`` (not both). The sizes below are the model's own;
     # ``num_layers`` must equal its length. None (default) is GPT-2's block
@@ -212,7 +224,7 @@ class TransformerConfig:
     # RMSNorm over the head size on every query and key head, before the
     # rotation
     qk_norm: bool = False
-    # short_conv and mamba2 mixers: taps of the depthwise causal
+    # short_conv, mamba2 and mamba1 mixers: taps of the depthwise causal
     # convolution; a sequence carries ``conv_kernel - 1`` positions of state
     conv_kernel: int = 3
     # mamba2 mixer (ops/ssm_scan.py): ``ssm_heads`` heads of ``ssm_head_dim``
@@ -249,6 +261,33 @@ class TransformerConfig:
     routed_norm_eps: float = 1e-6
     shared_d_ff: int | None = None
     routed_d_ff_stored: int | None = None
+    # mamba1 mixer (ops/ssm_scan.py, the end of the file): ``ssm_inner``
+    # channels each with ``ssm_state`` numbers of state and a decay of its
+    # own for each, the step size a channel through a projection of rank
+    # ``ssm_dt_rank``; ``conv_kernel`` taps. A sequence carries a float32
+    # (ssm_inner, ssm_state) matrix beside the convolution's positions. A
+    # ``gmu`` mixer (no state, no cache) gates by the scan output of the
+    # last mamba1 layer before it, in the same forward pass.
+    ssm_inner: int | None = None
+    ssm_dt_rank: int | None = None
+    # ``window_attention``: a query sees its own position and the ``window
+    # - 1`` before it. Serving keeps a slot's last ``window_ring``
+    # positions of keys and values in blocks of the slot's own (the
+    # ``state`` collection), whatever the sequence's length; the engine
+    # sets it (``serve/engine.py paged_config``): whole blocks and whole
+    # prefill chunks, at least ``window - 1`` positions and a chunk.
+    window: int | None = None
+    window_ring: int | None = None
+    # differential attention (Ye et al., 2024): query heads in pairs ``(2p,
+    # 2p + 1)``, key heads likewise, the pair's value both value heads side
+    # by side; ``(softmax(q1 k1) - lambda softmax(q2 k2)) V``, a learned
+    # lambda a layer, an RMSNorm over the pair's output. The cache holds a
+    # pair as ONE head twice as wide, each key and value once.
+    differential: bool = False
+    # biases on attention's two projections
+    attn_bias: bool = False
+    # the head is the embedding, transposed: no ``lm_head`` leaf
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         self._check_pattern()
@@ -372,7 +411,12 @@ class TransformerConfig:
                      routed_d_ff_stored=self.routed_d_ff_stored is not None,
                      num_kv_heads=self.num_kv_heads is not None,
                      qk_norm=self.qk_norm,
-                     routed_experts=self.routed_experts is not None)
+                     routed_experts=self.routed_experts is not None,
+                     ssm_inner=self.ssm_inner is not None,
+                     window=self.window is not None,
+                     differential=self.differential,
+                     attn_bias=self.attn_bias,
+                     tie_embeddings=self.tie_embeddings)
         if self.layers is None:
             given = sorted(k for k, v in sizes.items() if v)
             if given:
@@ -446,6 +490,37 @@ class TransformerConfig:
         elif self.shared_d_ff is not None:
             raise ValueError("shared_d_ff is a routed layer's shared "
                              "expert: no layer here is routed")
+        mixers = [m for m, _ in self.layers]
+        if "mamba1" in mixers:
+            needed = (self.ssm_inner, self.ssm_state, self.ssm_dt_rank)
+            if any(v is None or v < 1 for v in needed):
+                raise ValueError("a mamba1 mixer needs ssm_inner, ssm_state "
+                                 "and ssm_dt_rank")
+        for kind, source in (("gmu", "mamba1"),
+                             ("cross_attention", "attention")):
+            if kind in mixers and source not in mixers[:mixers.index(kind)]:
+                raise ValueError(
+                    f"a {kind} layer reads the last {source} layer before "
+                    "it: there is none")
+        if ("window_attention" in mixers) != (self.window is not None):
+            raise ValueError("window_attention layers, and they alone, take "
+                             "a window")
+        if self.window is not None and self.window < 1:
+            raise ValueError("window must be >= 1")
+        if self.window_ring is not None:
+            bs = self.paged_block_size
+            if (self.window is None or bs is None or self.window_ring % bs
+                    or self.window_ring < self.window):
+                raise ValueError(
+                    f"window_ring {self.window_ring}: whole blocks of the "
+                    "paged cache, at least the window")
+        if (any(m in HYBRID_ATTENTION for m in mixers)
+                and (self.position_kind == "rotary" or self.qk_norm)):
+            raise ValueError("window and cross attention have no wiring for "
+                             "rotary positions or qk_norm")
+        if self.differential and (self.num_heads % 2 or self.kv_heads % 2):
+            raise ValueError("differential attention pairs heads: "
+                             "num_heads and num_kv_heads must be even")
         # what the patterned path has no wiring for is refused by name
         unwired = dict(lora_rank=self.lora_rank, weight_dtype=self.weight_dtype,
                        moe_experts=self.moe_experts, tp_axis=self.tp_axis,
@@ -745,18 +820,19 @@ def rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 class MultiHeadAttention(nn.Module):
     cfg: TransformerConfig
+    layer: int = 0  # a patterned model's: differential attention's lambda
 
     def _grouped_qkv(self, x, index):
         """The patterned model's projection: ``h`` query heads and
-        ``kv_heads`` key and value heads out of one kernel, each query and
-        key head normalised (``qk_norm``) and then turned by its position
-        (``rope_theta``)."""
+        ``kv_heads`` key and value heads out of one kernel (with a bias
+        where ``attn_bias``), each query and key head normalised
+        (``qk_norm``) and then turned by its position (``rope_theta``)."""
         cfg = self.cfg
         h, kv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         qkv = nn.DenseGeneral(
             (h + 2 * kv, hd), axis=-1, dtype=cfg.dtype,
             kernel_init=_dense_init("embed", "heads", "kv"),
-            use_bias=False, name="qkv")(x)
+            use_bias=cfg.attn_bias, name="qkv")(x)
         q, k, v = qkv[:, :, :h], qkv[:, :, h:h + kv], qkv[:, :, h + kv:]
         if cfg.qk_norm:
             eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
@@ -772,7 +848,12 @@ class MultiHeadAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, index=None, *,
-                 block_tables=None, adapter=None) -> jax.Array:  # (B, S, D)
+                 block_tables=None, adapter=None,
+                 hand_kv: bool = False) -> jax.Array:  # (B, S, D)
+        # ``hand_kv``: the result is ``(out, (keys, values))``, the keys and
+        # values as the pool keeps them ((B, heads, hd, S), or in decode
+        # mode the pool's two leaves after this chunk's write), for the
+        # ``cross_attention`` layers below (``HybridAttention``)
         cfg = self.cfg
         h, hd = cfg.num_heads, cfg.head_dim
         if cfg.tp_axis:  # Megatron f: identity fwd, psum bwd (see tp_axis doc)
@@ -818,16 +899,24 @@ class MultiHeadAttention(nn.Module):
         k = _constrain(k, ("batch", "seq_inner", "heads", "kv"))
         v = _constrain(v, ("batch", "seq_inner", "heads", "kv"))
 
+        if cfg.differential:
+            q, k, v = _in_pairs(q, k, v)
+        handed = None
+        if hand_kv and not cfg.decode:
+            handed = tuple(jnp.transpose(t, (0, 2, 3, 1)) for t in (k, v))
         group = h // k.shape[2]
         if group > 1 and not (cfg.decode and cfg.paged):
             # the training view: every query head beside its own copy of
             # its group's keys and values (the paged path never copies)
             k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if cfg.decode and cfg.paged:
-            out = self._paged_decode_attend(q, k, v, index, block_tables)
+            out, leaves = self._paged_decode_attend(q, k, v, index,
+                                                    block_tables)
+            handed = leaves if hand_kv else None
         elif cfg.decode:
             out = self._decode_attend(q, k, v, index)
-        elif cfg.resolve_attn_impl(x.shape[1]) == "flash":
+        elif (cfg.resolve_attn_impl(x.shape[1]) == "flash"
+              and not cfg.differential):  # whose value is wider than its key
             from distributed_tensorflow_guide_tpu.ops.flash_attention import (
                 flash_attention,
             )
@@ -847,6 +936,8 @@ class MultiHeadAttention(nn.Module):
                 scores.astype(jnp.float32), axis=-1
             ).astype(cfg.dtype)
             out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        if cfg.differential:
+            out = _pairs_difference(self, out, self.layer)
         proj_in = out
         if cfg.weight_dtype:
             out = WeightQuantDense(
@@ -866,7 +957,7 @@ class MultiHeadAttention(nn.Module):
                 axis=(-2, -1),
                 dtype=cfg.dtype,
                 kernel_init=_dense_init("heads", "kv", "embed"),
-                use_bias=False,
+                use_bias=cfg.attn_bias,
                 name="proj",
             )(out)
         if cfg.lora:
@@ -877,7 +968,7 @@ class MultiHeadAttention(nn.Module):
                 out = out + _lora_delta(proj_a, proj_b, flat, adapter)
         if cfg.tp_axis:  # Megatron g: psum fwd (row-parallel proj), id bwd
             out = tp_allreduce(out, cfg.tp_axis)
-        return out
+        return (out, handed) if hand_kv else out
 
     def _decode_attend(self, q, k, v, index):
         """KV-cache incremental attention over a (B, C, H, hd) chunk.
@@ -980,7 +1071,9 @@ class MultiHeadAttention(nn.Module):
         the lane axis, which is how the device keeps such an array
         whatever shape it is declared with (ops/decode_attention.py, the
         paged section's comment), so neither the write nor the kernel's
-        read relays a leaf out.
+        read relays a leaf out. Returns the rows' outputs and the two
+        leaves after the write (a ``cross_attention`` layer below reads
+        them through the same tables).
         """
         cfg = self.cfg
         if index is None or block_tables is None:
@@ -1027,6 +1120,9 @@ class MultiHeadAttention(nn.Module):
                 leaf.value, jnp.transpose(rows, (0, 2, 3, 1)))  # (B,H,hd,C)
 
         lengths = index + C  # (B,) live length after the write
+        # heads in pairs are twice as wide and keep their own width's scale
+        scale = cfg.head_dim ** -0.5 if cfg.differential else None
+        leaves = (ck.value, cv.value)
         if impl == "pallas":
             blk_k = DA.paged_decode_blk_k_for(
                 b=B, h=h, s=cfg.max_len, d=hd, dtype=cache_dtype,
@@ -1036,25 +1132,30 @@ class MultiHeadAttention(nn.Module):
                     q, ck.value, cv.value, block_tables, lengths,
                     key_scale_pool=scale_pools[0],
                     value_scale_pool=scale_pools[1],
-                    block_size=bs, blk_k=blk_k)
+                    block_size=bs, blk_k=blk_k, scale=scale), leaves
             self._note_kernel_missed(
                 "paged_decode_attention", C, blk_k,
                 f"block_size {bs} has no usable KV edge")
         keys, vals, *scales = (
             None if leaf is None else gather_view(leaf, block_tables)
-            for leaf in (ck.value, cv.value, *scale_pools))
+            for leaf in (*leaves, *scale_pools))
         return _dense_cache_read(q, keys, vals, index, "bhdk", cfg.dtype,
-                                 *scales)
+                                 *scales, scale=scale), leaves
 
 
 def _dense_cache_read(q, keys, vals, index, layout: str, dtype,
-                      k_scale=None, v_scale=None):
+                      k_scale=None, v_scale=None, *, scale=None,
+                      window=None):
     """The dense read of a decode cache, the one statement of it: ``q``
     (B, C, h * group, hd) against each sequence's keys and values in
     ``layout`` — ``"bhkd"``, the one-shot cache as it lies, or ``"bhdk"``,
     the views ``gather_view`` makes of the pool — under the mask ``key_pos
     <= index + c``, ``index`` the position of the chunk's first row (one
-    for the batch, or one a row).
+    for the batch, or one a row). ``window``: a query sees the ``window``
+    keys up to and including its own position and none before them.
+    ``scale`` multiplies the scores in place of ``1 / sqrt(hd)``
+    (differential attention's queries are widened with zeros and keep the
+    scale of the width they had).
 
     Scores and softmax are float32. The int8 cache's dequantisation is
     folded into the two contractions and no dequantised copy is made: a
@@ -1067,12 +1168,15 @@ def _dense_cache_read(q, keys, vals, index, layout: str, dtype,
     h = keys.shape[1]
     seq = keys.shape[layout.index("k")]
     qg = q.reshape(B, C, h, hq // h, hd)
-    scores = (jnp.einsum(f"bqhgd,{layout}->bhgqk", qg, keys.astype(dtype))
-              / jnp.sqrt(hd).astype(dtype)).astype(jnp.float32)
+    scores = jnp.einsum(f"bqhgd,{layout}->bhgqk", qg, keys.astype(dtype))
+    scores = (scores / jnp.sqrt(hd).astype(dtype) if scale is None
+              else scores * scale).astype(jnp.float32)
     if k_scale is not None:
         scores = scores * k_scale[:, :, None]
     q_pos = jnp.reshape(index, (-1, 1)) + jnp.arange(C)  # (B or 1, C)
     mask = jnp.arange(seq)[None, None, :] <= q_pos[:, :, None]
+    if window is not None:
+        mask &= jnp.arange(seq)[None, None, :] > q_pos[:, :, None] - window
     scores = jnp.where(mask[:, None, None], scores,
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, -1)
@@ -1081,6 +1185,192 @@ def _dense_cache_read(q, keys, vals, index, layout: str, dtype,
     out = jnp.einsum(f"bhgqk,{layout}->bqhgd", probs.astype(dtype),
                      vals.astype(dtype))
     return out.reshape(B, C, hq, hd)
+
+
+def _in_pairs(q, k=None, v=None):
+    """Differential attention's heads in pairs (``cfg.differential``). A
+    pair of key heads, and the pair's value (both value heads side by
+    side), are kept as ONE head twice as wide, each key and value once:
+    ``k``, ``v`` ``(B, S, kv, hd) -> (B, S, kv / 2, 2 hd)``. A query head is
+    widened with zeros on the other key's side, ``(B, S, h, hd) -> (B, S,
+    h, 2 hd)``: an even head ``[q | 0]`` (it reads the pair's first key
+    head), an odd one ``[0 | q]`` (the second). The zeros add nothing to a
+    score, each head's softmax then weighs the pair's whole value, and the
+    paged kernel's grouped heads compute ``A1 V`` and ``A2 V`` as they
+    compute any head (at the scale of the width a head had)."""
+    zero = jnp.zeros_like(q)
+    odd = (jnp.arange(q.shape[2]) % 2 == 1)[:, None]
+    q = jnp.where(odd, jnp.concatenate([zero, q], -1),
+                  jnp.concatenate([q, zero], -1))
+    if k is None:
+        return q
+    wide = k.shape[:2] + (k.shape[2] // 2, 2 * k.shape[3])
+    return q, k.reshape(wide), v.reshape(wide)
+
+
+def _pairs_difference(module: nn.Module, out, layer: int):
+    """Differential attention's epilogue on ``out`` (B, S, h, 2 hd), the
+    heads' ``A V`` over :func:`_in_pairs`' keys and values: ``(A1 V -
+    lambda A2 V)`` a pair, an RMSNorm over the pair's width (``subln``) and
+    ``1 - lambda_init``, with ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    lambda_init`` and ``lambda_init = 0.8 - 0.6 exp(-0.3 layer)``. The five
+    leaves are ``module``'s own. Returns (B, S, h / 2, 2 hd)."""
+    cfg = module.cfg
+    start = 0.8 - 0.6 * math.exp(-0.3 * layer)
+    lq1, lk1, lq2, lk2 = (module.param(
+        name, nn.initializers.normal(stddev=0.1), (cfg.head_dim,),
+        jnp.float32)
+        for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + start
+    o = out.astype(jnp.float32)
+    o = o[:, :, 0::2] - lam * o[:, :, 1::2]
+    eps = 1e-5 if cfg.norm_eps is None else cfg.norm_eps
+    gain = module.param("subln", nn.initializers.ones_init(),
+                        (o.shape[-1],), jnp.float32)
+    o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                      + eps) * gain * (1.0 - start)
+    return o.astype(cfg.dtype)
+
+
+class HybridAttention(nn.Module):
+    """The two kinds of attention a hybrid decoder adds beside
+    ``MultiHeadAttention``'s ``"attention"`` (no positions of any kind; the
+    same biases and the same heads in pairs where the model has them):
+
+    * ``"cross_attention"``: a query projection only. Keys and values are
+      ``shared``, what the last ``attention`` layer handed on (``hand_kv``:
+      in decode mode the pool's two leaves, read through the same block
+      table and never written);
+    * ``"window_attention"``: a query sees its own position and the
+      ``cfg.window - 1`` before it. In decode mode the keys and values live
+      in a ring of the slot's own, ``cfg.window_ring`` positions in blocks
+      of the pool's shape in the ``state`` collection (``win_key``,
+      ``win_value``: ``(rows * blocks + 1, heads, hd, block_size)``, row
+      ``r``'s blocks ``[r * blocks, (r + 1) * blocks)`` and a last one that
+      takes idle rows' writes), position ``p`` in slot ``p % ring``. A
+      chunk is written first and then read through a block table TURNED so
+      that the ring's oldest block comes first: the view is ``ring``
+      consecutive positions ending in the chunk's block, which the paged
+      kernel (or the gathered dense read) masks by the window as it would
+      any sequence. The ring is whole prefill chunks, and a chunk starts at
+      a multiple of its length (the engine's do), so none straddles the
+      ring's end; it is at least ``window - 1`` positions and a chunk, so a
+      chunk never overwrites a key its first query still sees. Padding
+      positions land where the next positions will, on keys ``ring``
+      behind that no query sees any more; idle rows write the last block."""
+
+    cfg: TransformerConfig
+    kind: str
+    layer: int = 0
+
+    @nn.compact
+    def __call__(self, x, index=None, *, block_tables=None, state_rows=None,
+                 valid=None, shared=None):
+        cfg = self.cfg
+        h, kv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+
+        def heads(n, name):
+            return nn.DenseGeneral(
+                (n, hd), axis=-1, dtype=cfg.dtype, use_bias=cfg.attn_bias,
+                kernel_init=_dense_init("embed", "heads", "kv"), name=name)
+
+        window = None
+        if self.kind == "cross_attention":
+            q = heads(h, "q")(x)
+            keys, vals = shared
+            if cfg.differential:
+                q = _in_pairs(q)
+        else:
+            window = cfg.window
+            qkv = heads(h + 2 * kv, "qkv")(x)
+            q, k, v = qkv[:, :, :h], qkv[:, :, h:h + kv], qkv[:, :, h + kv:]
+            if cfg.differential:
+                q, k, v = _in_pairs(q, k, v)
+            # as the pool keeps them: (B, heads, hd, S)
+            keys, vals = (jnp.transpose(t, (0, 2, 3, 1)) for t in (k, v))
+        scale = hd ** -0.5
+        if not cfg.decode:
+            out = _dense_cache_read(q, keys, vals, 0, "bhdk", cfg.dtype,
+                                    scale=scale, window=window)
+        elif index is None or block_tables is None:
+            raise ValueError("attention in decode mode needs the index "
+                             "vector and the block tables")
+        elif window is not None:
+            out = self._ring_attend(q, (keys, vals), index, state_rows,
+                                    valid, scale)
+        else:
+            out = self._read(q, keys, vals, block_tables,
+                             index + q.shape[1], scale)
+        if cfg.differential:
+            out = _pairs_difference(self, out, self.layer)
+        return nn.DenseGeneral(
+            cfg.d_model, axis=(-2, -1), dtype=cfg.dtype,
+            use_bias=cfg.attn_bias,
+            kernel_init=_dense_init("heads", "kv", "embed"), name="proj")(out)
+
+    def _read(self, q, keys, vals, tables, lengths, scale, window=None):
+        """``q`` (B, C, ...) at positions ``[lengths - C, lengths)`` of the
+        sequences ``tables`` lays out over the pool-shaped leaves ``keys``
+        and ``vals``: the paged kernel where it is the path and fits, else
+        the views gathered and :func:`_dense_cache_read`."""
+        cfg = self.cfg
+        from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+        from distributed_tensorflow_guide_tpu.serve.paged_cache import (
+            gather_view,
+        )
+
+        C = q.shape[1]
+        bs, span = cfg.paged_block_size, tables.shape[1] * cfg.paged_block_size
+        if cfg.resolve_decode_impl() == "pallas":
+            blk_k = DA.paged_decode_blk_k_for(
+                b=q.shape[0], h=keys.shape[1], s=span, d=q.shape[-1],
+                dtype=keys.dtype, block_size=bs)
+            if DA.paged_supported(span, bs, blk_k, C):
+                return DA.paged_decode_attention(
+                    q, keys, vals, tables, lengths, block_size=bs,
+                    blk_k=blk_k, scale=scale, window=window)
+        return _dense_cache_read(
+            q, gather_view(keys, tables), gather_view(vals, tables),
+            lengths - C, "bhdk", cfg.dtype, scale=scale, window=window)
+
+    def _ring_attend(self, q, new, index, state_rows, valid, scale):
+        """A window layer through its slots' rings (the class's docstring
+        has the layout)."""
+        cfg = self.cfg
+        if valid is None or cfg.window_ring is None:
+            raise ValueError("window_attention in decode mode needs the "
+                             "valid counts and cfg.window_ring (the engine "
+                             "sets it)")
+        from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+        from distributed_tensorflow_guide_tpu.serve.paged_cache import (
+            write_chunk,
+        )
+
+        B, C = q.shape[:2]
+        bs, ring = cfg.paged_block_size, cfg.window_ring
+        nb = ring // bs
+        block = (B * nb + 1,) + new[0].shape[1:3] + (bs,)
+        leaves = [self.variable("state", name, jnp.zeros, block, cfg.dtype)
+                  for name in WINDOW_LEAVES]
+        rows = jnp.arange(B) if state_rows is None else state_rows
+        own = rows[:, None] * nb + jnp.arange(nb)  # (B, nb): a row's blocks
+        trash = leaves[0].value.shape[0] - 1
+        into = jnp.where((valid > 0)[:, None], own, trash)
+        for leaf, chunk in zip(leaves, new):
+            leaf.value = write_chunk(
+                leaf.value, chunk, into, index % ring, block_size=bs,
+                kernel=(cfg.resolve_decode_impl() == "pallas"
+                        and DA.paged_write_fits(block[1:], cfg.dtype)))
+        # the view: the ring's blocks oldest first, once it has wrapped
+        last = index + C - 1  # the chunk's last position
+        wrapped = (last >= ring)[:, None]
+        order = jnp.where(
+            wrapped, ((last // bs)[:, None] + 1 + jnp.arange(nb)) % nb,
+            jnp.arange(nb))
+        first = jnp.where(wrapped[:, 0], (last // bs - (nb - 1)) * bs, 0)
+        return self._read(q, leaves[0].value, leaves[1].value,
+                          jnp.take_along_axis(own, order, axis=1),
+                          last + 1 - first, scale, window=cfg.window)
 
 
 class MLP(nn.Module):
@@ -1522,6 +1812,115 @@ class Mamba2(nn.Module):
                         name="out_proj")(y)
 
 
+class Mamba1(nn.Module):
+    """The Mamba-1 state-space mixer (``ops/ssm_scan.py``'s end has the
+    recurrence). With ``D = ssm_inner`` channels, a state of ``N`` a
+    channel and a step projection of rank ``R``: ``[u | z] = W_in x``; ``u
+    = silu(conv(u) + b)``, depthwise and causal over ``conv_kernel`` taps;
+    ``[r | B | C] = W_x u`` (``R | N | N``); ``dt = softplus(W_dt r +
+    b_dt)`` and ``A = -exp(A_log)`` (D, N), both float32; ``y = scan(u, dt,
+    A, B, C) + D u``; ``out = W_out (y * silu(z))``. Returns ``(out, y)``:
+    ``y``, before the gate, is what a later ``gmu`` layer gates by.
+
+    A sequence carries two things, each a row of a leaf of the ``state``
+    collection in decode mode, as ``Mamba2``'s: ``conv`` (rows, K - 1, D)
+    in the activations' dtype and ``ssm`` (rows, D, N) float32. Rows, fresh
+    chunks, padding and idle rows as there (``dt = 0`` leaves the state)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, index=None, *, state_rows=None,
+                 valid=None):
+        from distributed_tensorflow_guide_tpu.ops.ssm_scan import (
+            selective_scan,
+            selective_step,
+        )
+
+        cfg = self.cfg
+        D, N, R, taps = (cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank,
+                         cfg.conv_kernel)
+        B, S, _ = x.shape
+
+        def dense(features, names, name, **kw):
+            return nn.Dense(features, dtype=cfg.dtype,
+                            kernel_init=_dense_init(*names), name=name, **kw)
+
+        u, z = jnp.split(dense(2 * D, ("embed", "mlp"), "in_proj",
+                               use_bias=False)(x), 2, axis=-1)
+        conv_w = self.param("conv_w", _dense_init("mlp", "conv"),
+                            (D, taps), jnp.float32).astype(cfg.dtype)
+        conv_b = self.param("conv_b", nn.initializers.zeros_init(), (D,),
+                            jnp.float32).astype(cfg.dtype)
+        a_log = self.param("A_log", _dense_init("mlp", "state"), (D, N),
+                           jnp.float32)
+        skip = self.param("D", nn.with_logical_partitioning(
+            nn.initializers.ones_init(), ("mlp",)), (D,), jnp.float32)
+        conv_state = ssm_state = None
+        if cfg.decode:
+            if index is None or valid is None:
+                raise ValueError("mamba1 in decode mode needs the index "
+                                 "and the valid counts")
+            conv_state = self.variable("state", "conv", jnp.zeros,
+                                       (B, taps - 1, D), cfg.dtype)
+            ssm_state = self.variable("state", "ssm", jnp.zeros, (B, D, N),
+                                      jnp.float32)
+        with jax.named_scope("dtg.ssm.conv"):
+            u = nn.silu(_conv_with_state(u, conv_w, conv_state, index,
+                                         state_rows, valid, bias=conv_b))
+        r, b, c = jnp.split(dense(R + 2 * N, ("mlp", "state"), "x_proj",
+                                  use_bias=False)(u), [R, R + N], axis=-1)
+        dt = nn.Dense(D, dtype=jnp.float32,
+                      kernel_init=_dense_init("state", "mlp"),
+                      name="dt_proj")(r)
+        with jax.named_scope("dtg.ssm.scan"):
+            dt = jax.nn.softplus(dt)
+            a = -jnp.exp(a_log)
+            if ssm_state is None:
+                carried = jnp.zeros((B, D, N), jnp.float32)
+            else:
+                held = _state_rows_of(ssm_state.value, state_rows)
+                fresh = jnp.reshape(index, (-1, 1, 1)) == 0
+                carried = jnp.where(fresh, jnp.zeros_like(held), held)
+                real = jnp.arange(S)[None, :] < valid[:, None]  # (B, S)
+                dt = jnp.where(real[..., None], dt, 0.0)
+            if S == 1:
+                y, after = selective_step(u[:, 0], dt[:, 0], a, b[:, 0],
+                                          c[:, 0], carried)
+                y = y[:, None]
+            else:
+                y, after = selective_scan(u, dt, a, b, c, carried)
+            if ssm_state is not None:
+                after = jnp.where(jnp.reshape(valid, (-1, 1, 1)) > 0, after,
+                                  held)
+                ssm_state.value = _state_rows_set(ssm_state.value,
+                                                  state_rows, after)
+            y = y + skip * u.astype(jnp.float32)
+        gated = (y * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+        return dense(cfg.d_model, ("mlp", "embed"), "out_proj",
+                     use_bias=False)(gated), y
+
+
+class GatedMemory(nn.Module):
+    """The gated memory unit: ``out = W_2 (silu(W_1 x) * m)``, ``m`` (B, S,
+    ssm_inner) the scan output of the last ``mamba1`` layer before this one
+    at the same positions of the same forward pass: an activation of the
+    launch, never kept. No state, no cache, no biases."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, memory: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        gate = nn.Dense(cfg.ssm_inner, dtype=cfg.dtype, use_bias=False,
+                        kernel_init=_dense_init("embed", "mlp"),
+                        name="in_proj")(x)
+        y = (nn.silu(gate.astype(jnp.float32)) * memory).astype(cfg.dtype)
+        return nn.Dense(cfg.d_model, dtype=cfg.dtype, use_bias=False,
+                        kernel_init=_dense_init("mlp", "embed"),
+                        name="out_proj")(y)
+
+
 class RoutedMLP(nn.Module):
     """The routed feed-forward of a patterned model: ``ops/routed_ffn.py``
     over this module's router, selection bias and the banks of the experts
@@ -1569,19 +1968,68 @@ class Block(nn.Module):
 
     cfg: TransformerConfig
     kinds: tuple | None = None
+    layer: int = 0  # a patterned model's: the layer's index
 
-    def _patterned(self, x, index, block_tables, state_rows, valid):
+    def _hybrid_mixer(self, h, index, block_tables, state_rows, valid,
+                      carried):
+        """The mixers that read or hand on what ``carried`` holds: ``kv``,
+        the keys and values of the last full-attention layer (in decode
+        mode the pool's two leaves), which the ``cross_attention`` layers
+        below it read (the producer and its readers under one outer scope,
+        ``dtg.shared_kv``), and ``memory``, the last ``mamba1`` layer's scan
+        output, which a ``gmu`` layer gates by; and ``window_attention``,
+        which does neither. Returns the mixer's output and ``carried``
+        after it."""
+        cfg, mixer = self.cfg, self.kinds[0]
+        if mixer == "gmu":
+            with jax.named_scope("dtg.gmu"):
+                return GatedMemory(cfg, name="gmu")(
+                    h, carried["memory"]), carried
+        if mixer == "mamba1":
+            with jax.named_scope("dtg.ssm"):
+                out, y = Mamba1(cfg, name="ssm")(
+                    h, index, state_rows=state_rows, valid=valid)
+            return out, {**carried, "memory": y}
+        if mixer == "window_attention":
+            with jax.named_scope("dtg.window_attn"):
+                return HybridAttention(
+                    cfg, kind=mixer, layer=self.layer, name="attn")(
+                        h, index, block_tables=block_tables,
+                        state_rows=state_rows, valid=valid), carried
+        with jax.named_scope("dtg.shared_kv"):
+            if mixer == "cross_attention":
+                with jax.named_scope("dtg.cross_attn"):
+                    return HybridAttention(
+                        cfg, kind=mixer, layer=self.layer, name="attn")(
+                            h, index, block_tables=block_tables,
+                            shared=carried["kv"]), carried
+            with jax.named_scope("dtg.attn"):
+                out, kv = MultiHeadAttention(
+                    cfg, layer=self.layer, name="attn")(
+                        h, index, block_tables=block_tables, hand_kv=True)
+        return out, {**carried, "kv": kv}
+
+    def _patterned(self, x, index, block_tables, state_rows, valid,
+                   carried):
         """``x + mixer(norm(x))`` then ``x + ffn(norm(x))``, each half only
         if the layer has it (and its norm, ``ln1`` / ``ln2``, with it). A
         routed layer's shared expert reads the same normalised rows and is
-        a module of the block's, ``shared``, beside ``mlp``."""
+        a module of the block's, ``shared``, beside ``mlp``. Returns ``x``
+        and what the layer hands to later ones (``_hybrid_mixer``)."""
         cfg, (mixer, ffn) = self.cfg, self.kinds
         if mixer is not None:
             h = _norm(cfg, "ln1")(x)
-            if mixer == "attention":
+            mixers = [m for m, _ in cfg.layers]
+            if mixer in ("mamba1", "gmu") + HYBRID_ATTENTION or (
+                    mixer == "attention" and "cross_attention" in mixers):
+                out, carried = self._hybrid_mixer(
+                    h, index, block_tables, state_rows, valid, carried)
+                x = x + out
+            elif mixer == "attention":
                 with jax.named_scope("dtg.attn"):
-                    x = x + MultiHeadAttention(cfg, name="attn")(
-                        h, index, block_tables=block_tables)
+                    x = x + MultiHeadAttention(
+                        cfg, layer=self.layer, name="attn")(
+                            h, index, block_tables=block_tables)
             else:
                 scope, module, name = {
                     "short_conv": ("dtg.short_conv", ShortConv, "conv"),
@@ -1603,16 +2051,22 @@ class Block(nn.Module):
             x = x + y
         elif ffn == "dense":
             x = x + MLP(cfg, name="mlp")(_norm(cfg, "ln2")(x))
-        return _constrain(x, ("batch", "seq", "embed"))
+        return _constrain(x, ("batch", "seq", "embed")), carried
 
     @nn.compact
     def __call__(self, x: jax.Array, index=None, *,
                  block_tables=None, adapter=None,
-                 moe_mask=None, state_rows=None, valid=None) -> jax.Array:
+                 moe_mask=None, state_rows=None, valid=None,
+                 carried=None):
+        # ``carried`` (a patterned model's, from ``Transformer``): what the
+        # layers before hand to this one; given, the result is ``(x,
+        # carried)`` after this layer, else ``x`` alone
         cfg = self.cfg
         if self.kinds is not None:
-            return self._patterned(x, index, block_tables, state_rows,
-                                   valid)
+            x, after = self._patterned(
+                x, index, block_tables, state_rows, valid,
+                {} if carried is None else carried)
+            return x if carried is None else (x, after)
         # Attention-only selective remat (core/precision.py): checkpoint the
         # attention sub-layer here so EVERY consumer — the flat Transformer,
         # all four pipeline schedules — gets the same HBM/FLOP trade without
@@ -1640,11 +2094,12 @@ class Transformer(nn.Module):
     cfg: TransformerConfig
 
     def _patterned(self, x, index, block_tables, state_rows, valid,
-                   return_hidden):
+                   return_hidden, embed):
         """The forward of a model given as a pattern of layers, from the
         embedded tokens on: the learned table only if that is what the
         model has (``position_kind``), each layer its own mixer or
-        feed-forward or both, the model's normalisation, the same head."""
+        feed-forward or both, the model's normalisation, the same head (or
+        ``embed``'s table, transposed, where the head is tied to it)."""
         cfg = self.cfg
         if cfg.decode and not cfg.paged:
             raise ValueError(
@@ -1661,13 +2116,21 @@ class Transformer(nn.Module):
         block = Block
         if cfg.resolved_remat_mode == "block":
             block = nn.remat(Block, prevent_cse=False)
+        carried = {}  # what a layer hands to later ones: Block._hybrid_mixer
         for i, kinds in enumerate(cfg.layers):
-            x = block(cfg, kinds=tuple(kinds), name=f"block_{i}")(
+            x, carried = block(cfg, kinds=tuple(kinds), layer=i,
+                               name=f"block_{i}")(
                 x, index, block_tables=block_tables,
-                state_rows=state_rows, valid=valid)
+                state_rows=state_rows, valid=valid, carried=carried)
         x = _norm(cfg, "ln_f")(x)
         if return_hidden:
             return x
+        if cfg.tie_embeddings:
+            # float32 logits, as the head below gives, out of the table as
+            # it is stored: no float32 copy of it is made
+            return jnp.einsum("bsd,vd->bsv", x,
+                              embed.embedding.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
         return nn.Dense(cfg.vocab_size, dtype=jnp.float32, use_bias=False,
                         kernel_init=_dense_init("embed", "vocab"),
                         name="lm_head")(x)
@@ -1688,16 +2151,17 @@ class Transformer(nn.Module):
         cfg = self.cfg
         if cfg.decode and index is None:
             raise ValueError("cfg.decode=True requires the position index")
-        x = nn.Embed(
+        embed = nn.Embed(
             cfg.vocab_size,
             cfg.d_model,
             dtype=cfg.dtype,
             embedding_init=_dense_init("vocab", "embed"),
             name="tok_emb",
-        )(tokens)
+        )
+        x = embed(tokens)
         if cfg.layers is not None:
             return self._patterned(x, index, block_tables, state_rows,
-                                   valid, return_hidden)
+                                   valid, return_hidden, embed)
         positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.decode:
             # the serve engine passes a PER-REQUEST (B,) index vector

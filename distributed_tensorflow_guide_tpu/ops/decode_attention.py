@@ -507,7 +507,7 @@ def paged_heads_per_step(kv_heads: int, *, group: int, chunk: int, hd: int,
 
 def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
                          scale: float, blk_k: int, chunk: int, rows: int,
-                         quantized: bool):
+                         quantized: bool, window: int | None = None):
     """One grid step: ``hb`` pool heads of one slot against one key tile.
 
     ``q_ref``/``o_ref`` (1, hb, R, hd): a pool head's query rows on the
@@ -517,7 +517,8 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
     (1, hb, hd, blk_k) of the pool as stored, the int8 cache's scale rows
     (1, hb, 1, blk_k); scratch ``m``, ``l`` (hb, R, LANE), ``acc``
     (hb, R, hd), float32. The online softmax of the one-head kernel over a
-    leading head axis."""
+    leading head axis. ``window``: a query sees the ``window`` keys up to
+    and including its own position and none before them (None: all)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -534,7 +535,12 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
 
     length = len_ref[b]  # per-request live length (continuous batching)
 
-    @pl.when(j * blk_k < length)
+    live = j * blk_k < length
+    if window is not None:
+        # a tile wholly before the chunk's first query's window adds nothing
+        live &= (j + 1) * blk_k > length - chunk - window + 1
+
+    @pl.when(live)
     def _():
         q = q_ref[0]  # (hb, R, hd)
         kT = k_ref[0]  # (hb, hd, blk_k): slots on lanes
@@ -559,7 +565,10 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
         q_pos = (length - chunk) + r
         k_pos = j * blk_k + jax.lax.broadcasted_iota(
             jnp.int32, (rp, blk_k), 1)
-        s = jnp.where((k_pos <= q_pos)[None], s, NEG_INF)
+        seen = k_pos <= q_pos
+        if window is not None:
+            seen &= k_pos > q_pos - window
+        s = jnp.where(seen[None], s, NEG_INF)
         m_prev = m_scr[:, :, :1]
         l_prev = l_scr[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -585,7 +594,9 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
 
 def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
                            *, key_scale_pool=None, value_scale_pool=None,
-                           block_size: int, blk_k: int | None = None):
+                           block_size: int, blk_k: int | None = None,
+                           scale: float | None = None,
+                           window: int | None = None):
     """Length-aware cache attention reading a paged pool through tables.
 
     ``q``: (B, C, H, hd) public layout. ``key_pool``/``value_pool``:
@@ -639,7 +650,8 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
         qk = jnp.pad(qk, ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
     lengths = jnp.maximum(jnp.asarray(lengths, jnp.int32), 1)
     tables = jnp.asarray(block_tables, jnp.int32)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     n_kv = S // blk_k
     sub = block_size // blk_k  # kernel tiles per physical block
     hb = paged_heads_per_step(kv_heads, group=group, chunk=C, hd=hd,
@@ -678,7 +690,7 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
     )
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                blk_k=blk_k, chunk=C, rows=rows,
-                               quantized=quantized)
+                               quantized=quantized, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -1,6 +1,7 @@
-"""The forward of a selective state-space layer (Mamba-2's recurrence), in
-the two forms serving needs: a run of positions from a carried state, and
-one position for every row.
+"""The forward of a selective state-space layer, in the two forms serving
+needs: a run of positions from a carried state, and one position for every
+row. Mamba-2's recurrence first (one decay a head), Mamba-1's at the end of
+the file (a decay a channel and state).
 
 A head ``h`` carries a matrix ``S`` of ``P x N`` (head size by state size).
 With ``dt_t >= 0`` the step size of position ``t``, ``A < 0`` the head's
@@ -115,3 +116,49 @@ def ssm_step(x, dt, a, b, c, state):
              + drive * _heads(b, heads).astype(F32)[:, :, None, :])
     y = jnp.sum(state * _heads(c, heads).astype(F32)[:, :, None, :], axis=-1)
     return y, state
+
+
+# --------------------------------------------------------------------------
+# Mamba-1: a decay a channel and state
+# --------------------------------------------------------------------------
+#
+# A channel ``c`` of ``D`` carries a vector ``h[c, :]`` of ``N`` numbers, and
+# the decay is the channel's and the state's own, ``A`` (D, N) (Mamba-2's is
+# one number a head, which is what lets :func:`_one_chunk` turn a chunk into
+# matrix products; here there is no such factoring and the recurrence runs a
+# position at a time):
+#
+#     h_t[c, :] = exp(dt_t[c] A[c, :]) h_{t-1}[c, :] + dt_t[c] u_t[c] B_t
+#     y_t[c]    = h_t[c, :] . C_t
+#
+# ``B_t`` and ``C_t`` (N,) are shared by every channel. ``D u_t``, the gate
+# and the output projection are the caller's; ``dt_t == 0`` leaves the state
+# as it was, as above.
+
+
+def selective_step(u, dt, a, b, c, state):
+    """One position of every row: ``u`` (B, D), ``dt`` (B, D) float32 (0
+    for a row that is idle), ``a`` (D, N) float32, ``b`` and ``c`` (B, N),
+    ``state`` (B, D, N) float32. Returns ``(y (B, D) float32, the state
+    after)``, elementwise over the state: one pass over its bytes."""
+    dt = dt.astype(F32)
+    decay = jnp.exp(dt[..., None] * a.astype(F32))
+    drive = (dt * u.astype(F32))[..., None] * b.astype(F32)[:, None, :]
+    state = decay * state.astype(F32) + drive
+    return jnp.sum(state * c.astype(F32)[:, None, :], axis=-1), state
+
+
+def selective_scan(u, dt, a, b, c, state):
+    """``S`` positions of ``B`` sequences from ``state`` (B, D, N) float32
+    (zeros for a sequence's first): ``u`` (B, S, D), ``dt`` (B, S, D)
+    float32 (0 at positions that are padding), ``a`` (D, N), ``b`` and
+    ``c`` (B, S, N). Returns ``(y (B, S, D) float32, the state after the
+    last position)``: :func:`selective_step` over the positions in turn, a
+    ``lax.scan`` whose carry is the state."""
+    def step(state, at):
+        y, state = selective_step(*at[:2], a, *at[2:], state)
+        return state, y
+
+    by_position = tuple(jnp.swapaxes(v, 0, 1) for v in (u, dt, b, c))
+    state, ys = lax.scan(step, state.astype(F32), by_position)
+    return jnp.swapaxes(ys, 0, 1), state

@@ -69,6 +69,7 @@ import dataclasses
 import glob
 import io as _io
 import json
+import math
 import os
 import time
 import zlib
@@ -86,6 +87,7 @@ from distributed_tensorflow_guide_tpu.models.generation import (
     sample_rows,
 )
 from distributed_tensorflow_guide_tpu.models.transformer import (
+    WINDOW_LEAVES,
     Transformer,
     TransformerConfig,
 )
@@ -135,11 +137,32 @@ class Event:
 
 
 def paged_config(cfg: TransformerConfig, *, num_blocks: int,
-                 block_size: int) -> TransformerConfig:
-    """The serving view of a training config, paged flavour."""
+                 block_size: int,
+                 prefill_chunk: int | None = None) -> TransformerConfig:
+    """The serving view of a training config, paged flavour. A model with
+    window layers also gets the size of a slot's ring
+    (:func:`window_ring`), which needs the prefill chunk's length."""
+    ring = None
+    if cfg.window is not None:
+        if prefill_chunk is None:
+            raise ValueError("a model with window layers is paged for a "
+                             "prefill chunk's length: give prefill_chunk")
+        ring = window_ring(cfg.window, block_size, prefill_chunk)
     return dataclasses.replace(decode_config(cfg),
                                paged_num_blocks=num_blocks,
-                               paged_block_size=block_size)
+                               paged_block_size=block_size,
+                               window_ring=ring)
+
+
+def window_ring(window: int, block_size: int, prefill_chunk: int) -> int:
+    """The positions of keys and values a slot keeps for a window layer,
+    whatever the sequence's length: the ``window - 1`` before a prefill
+    chunk's first query and the chunk itself (so that a chunk, written
+    before it is read, overwrites no key its own first queries still see),
+    rounded up to whole blocks and whole chunks (a chunk starts at a
+    multiple of its length and never straddles the ring's end)."""
+    unit = math.lcm(block_size, prefill_chunk)
+    return -(-(window - 1 + prefill_chunk) // unit) * unit
 
 
 def paged_cache_shapes(pcfg: TransformerConfig, slots: int):
@@ -178,6 +201,19 @@ def slot_state(pcfg: TransformerConfig, slots: int, device=None):
 def _tree_bytes(tree) -> int:
     """The bytes of a tree's array leaves (0 for None or ``{}``)."""
     return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
+
+def _state_bytes(state) -> dict:
+    """``window_bytes``, the window layers' rings (the ``state``
+    collection's ``WINDOW_LEAVES``), and ``state_bytes``, its other leaves,
+    the recurrent mixers': the two kinds of storage beside the block pool,
+    neither of which grows with a sequence."""
+    window = total = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state or {}):
+        total += int(leaf.nbytes)
+        if getattr(path[-1], "key", None) in WINDOW_LEAVES:
+            window += int(leaf.nbytes)
+    return {"state_bytes": total - window, "window_bytes": window}
 
 
 def _zeros(shapes, device):
@@ -272,6 +308,7 @@ class _Launch:
     t0: float
     outs: tuple  # (tokens, [overflowed,] [census ...]) on the device
     payload: dict | None  # the recorder's launch identity
+    keys: dict  # a decode launch's keys, for its ``engine.apply`` span
     produced: list | None = None  # None: not advanced until it is fetched
     owed: list = dataclasses.field(default_factory=list)
 
@@ -352,11 +389,15 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
     spinning an engine up with a geometry already served compiles
     nothing at all."""
     donate = jax.default_backend() != "cpu"
-    memo_key = (cfg, num_blocks, block_size, temperature, top_k, donate)
+    pcfg = paged_config(cfg, num_blocks=num_blocks, block_size=block_size,
+                        prefill_chunk=prefill_chunk)
+    # a window model's ring is whole prefill chunks: the one way the
+    # chunk's length reaches a trace (``pcfg.window_ring``, None otherwise)
+    memo_key = (cfg, num_blocks, block_size, temperature, top_k, donate,
+                pcfg.window_ring)
     hit = _STEP_FNS.get(memo_key)
     if hit is not None:
         return hit
-    pcfg = paged_config(cfg, num_blocks=num_blocks, block_size=block_size)
     model = Transformer(pcfg)
     n_blk = pcfg.max_len // block_size
     lora = pcfg.lora_rank is not None
@@ -586,10 +627,11 @@ class ServeEngine:
             # state: served so, its first token would already be wrong
             raise ValueError(
                 "this model's sequences carry state beside their keys and "
-                f"values (its {' and '.join(self.fns.cfg.state_mixers)} "
-                "mixers'), and the prefix cache, the host tier and KV adoption move blocks "
-                "of keys and values alone: prefix_cache and host_blocks "
-                "must stay off (a preempted or migrated request "
+                "values in the pool (its "
+                f"{' and '.join(self.fns.cfg.state_mixers)} "
+                "mixers'), and the prefix cache, the host tier and KV "
+                "adoption move blocks of the pool alone: prefix_cache and "
+                "host_blocks must stay off (a preempted or migrated request "
                 "re-prefills, which rebuilds the state)")
         self.persist_cache = bool(persist_cache)
         self.store = (BlockStore(capacity=host_blocks) if host_blocks
@@ -916,6 +958,7 @@ class ServeEngine:
         once, and advanced there: the same calls, none in flight."""
         rec, sd = self.rec, self.sched
         t0 = time.perf_counter()
+        keys = {}
         if kind == PREFILL:
             slot = sd.slots[arg]
             rows, fn, program = 1, self.fns.prefill, "prefill_chunk_step"
@@ -925,6 +968,7 @@ class ServeEngine:
         else:
             rows, fn, program = len(arg), self.fns.decode, "decode_step"
             ids = {"tick": tick}
+            keys = self._decode_keys(arg)
         payload = None
         if rec.enabled:
             # launch identity, taken BEFORE the scheduler is told: it
@@ -957,7 +1001,8 @@ class ServeEngine:
         self.launches += 1
         self.overlapped_launches += overlapped
         self.steps[kind] += 1
-        launch = _Launch(kind, arg, ids, now, t0, (toks, *outs), payload)
+        launch = _Launch(kind, arg, ids, now, t0, (toks, *outs), payload,
+                         keys)
         before, self._inflight = self._inflight, launch
         if not self.fns.moe:
             launch.produced = self._advance(launch)
@@ -966,6 +1011,20 @@ class ServeEngine:
             self._fetch_apply(before, now)
         if self.fns.moe:
             self._settle(now)
+
+    def _decode_keys(self, ready: list[int]) -> dict:
+        """What a decode launch over the ``ready`` slots reads of keys and
+        values, for a model with window layers (``{}`` for any other):
+        ``live_keys``, the rows' live lengths after the launch's write
+        summed (what a full-attention layer, and each layer that reads its
+        cache, attends), and ``window_keys``, each length capped at the
+        window (what a window layer attends)."""
+        window = self.fns.cfg.window
+        if window is None:
+            return {}
+        live = [self.sched.slots[i].written + 1 for i in ready]
+        return {"live_keys": sum(live),
+                "window_keys": sum(min(n, window) for n in live)}
 
     def _advance(self, launch: _Launch, toks=None,
                  overflowed=None) -> list[tuple]:
@@ -1038,7 +1097,8 @@ class ServeEngine:
                 outs = [np.asarray(x) for x in outs]
                 self._moe_load += outs[-2].astype(np.int64)
                 self._moe_overflow += outs[-1].astype(np.int64)
-        with span(rec, "engine.apply", cat="serve", **ids, **routed):
+        with span(rec, "engine.apply", cat="serve", **ids, **routed,
+                  **launch.keys):
             if launch.produced is None:
                 produced = self._advance(
                     launch, toks, outs[0] if len(outs) == 3 else None)
@@ -1324,7 +1384,7 @@ class ServeEngine:
             "preemptions": sd.preemptions,
             "live_blocks": sd.pool.live_blocks(),
             "pool_bytes": _tree_bytes(self.pool),
-            "state_bytes": _tree_bytes(self.state),
+            **_state_bytes(self.state),
             "routed": dict(self.routed_assignments),
             "prefix_hit_tokens": sd.prefix_hit_tokens,
             "prefill_tokens_saved": sd.prefill_tokens_saved,

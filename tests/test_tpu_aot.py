@@ -576,3 +576,83 @@ def test_serve_step_pair_compiles(v5e, as_on_tpu):
     assert_mosaic(decode)
     assert_mosaic(prefill)  # a 128-token chunk still takes the kernel
     assert not FA.fallback_stats()
+
+
+def test_hybrid_decoder_step_pair_compiles_at_the_published_widths(
+        v5e, as_on_tpu):
+    """Both step programs of one layer of each kind of the
+    Phi-4-mini-flash cell (Mamba-1, window attention, Mamba-1 handing its
+    memory on, full attention, a gated memory unit, cross-attention) at the
+    published widths and the cell's geometry (64 slots, 4,096 blocks of
+    128, rings of 640 positions, a 128-token chunk), pool and state
+    donated: every donated byte is aliased; the paged kernel runs once in
+    each attention layer (heads in pairs: 10 pool heads of 128, four query
+    heads each) and the paged write twice where a layer has keys of its
+    own, so the cross layer writes nothing; and a launch's temporaries are
+    a small part of ONE 105 MB ring leaf, so no leaf, nor the 1 GB
+    embedding under the tied head, is copied."""
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        TransformerConfig,
+    )
+    from distributed_tensorflow_guide_tpu.serve import engine as E
+    from yardstick import harness, weights_phi4flash
+
+    held = harness.load_json(
+        harness.HERE / "configs" / "phi-4-mini-flash-reasoning.json")
+    held["num_hidden_layers"] = 6
+    held["assumed"]["layout"].update(memory_layer=2, full_layer=3)
+    z = weights_phi4flash.sizes_of(held)
+    assert [m for m, _ in z["layers"]] == [
+        "mamba1", "window_attention", "mamba1", "attention", "gmu",
+        "cross_attention"]
+    dep = held["deployment"]
+    slots, chunk = dep["slots"], dep["prefill_chunk"]
+    cfg = TransformerConfig(
+        vocab_size=z["vocab"], num_layers=z["L"], num_heads=z["h"],
+        d_model=z["d"], d_ff=z["ff"], max_len=z["positions"], causal=True,
+        dtype=jnp.bfloat16, layers=z["layers"], norm="layernorm",
+        norm_eps=z["eps"], ffn_gate="silu", positions="none",
+        num_kv_heads=z["kv"], conv_kernel=z["taps"], ssm_inner=z["inner"],
+        ssm_state=z["N"], ssm_dt_rank=z["R"], window=z["window"],
+        differential=True, attn_bias=True, tie_embeddings=True)
+    fns = E.build_step_fns(cfg, slots=slots, num_blocks=dep["num_blocks"],
+                           block_size=dep["block_size"], prefill_chunk=chunk)
+    assert fns.cfg.window_ring == 640
+    params = jax.eval_shape(lambda: weights_phi4flash.flax_tree(1, z))
+    pool = E.paged_cache_shapes(fns.cfg, slots)
+    state = E._serving_shapes(fns.cfg, slots)["state"]
+    pair_heads = (z["kv"] // 2, 2 * z["hd"], dep["block_size"])
+    assert {k: v.shape for k, v in pool["block_3"]["attn"].items()} == {
+        "cached_key": (dep["num_blocks"],) + pair_heads,
+        "cached_value": (dep["num_blocks"],) + pair_heads}
+    ring_leaf = (slots * 5 + 1,) + pair_heads
+    assert {k: (v.shape, v.dtype) for k, v in
+            state["block_1"]["attn"].items()} == {
+                "win_key": (ring_leaf, jnp.bfloat16),
+                "win_value": (ring_leaf, jnp.bfloat16)}
+    assert {k: (v.shape, v.dtype) for k, v in
+            state["block_0"]["ssm"].items()} == {
+                "ssm": ((slots, 5120, 16), jnp.float32),
+                "conv": ((slots, 3, 5120), jnp.bfloat16)}
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, state)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    one = SingleDeviceSharding(v5e[0])
+    decode = compile_for(
+        one, fns.decode, params, pool, state, i32(slots, fns.n_blk),
+        i32(slots), i32(slots), sds((slots, 2), jnp.uint32))
+    prefill = compile_for(
+        one, fns.prefill, params, pool, state, i32(1, fns.n_blk), i32(1),
+        i32(1, chunk), i32(), sds((2,), jnp.uint32), i32())
+    ring_bytes = 2 * int(np.prod(ring_leaf))
+    for compiled in (decode, prefill):
+        text = compiled.as_text()
+        # window: two writes and a read; full: two writes and a read;
+        # cross: a read
+        assert text.count("tpu_custom_call") == 7
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == donated
+        assert mem.temp_size_in_bytes < max(ring_bytes,
+                                            2 * chunk * z["vocab"] * 4)
+    assert decode.memory_analysis().temp_size_in_bytes < ring_bytes // 4
+    assert not FA.fallback_stats()
