@@ -1617,24 +1617,28 @@ class MoEMLP(nn.Module):
 def _state_rows_of(leaf, rows):
     """Rows ``rows`` of a per-slot state leaf: None is every row in order
     (the decode program: batch row ``b`` is slot ``b``), which reads the
-    leaf as it stands; one row (a prefill chunk's slot) is a slice."""
+    leaf as it stands; the rows of a prefill launch (one a chunk, few and
+    their number static) are a slice each."""
     if rows is None:
         return leaf
-    if rows.shape[0] == 1:
-        return lax.dynamic_slice_in_dim(leaf, rows[0], 1, axis=0)
-    return leaf[rows]
+    return jnp.concatenate(
+        [lax.dynamic_slice_in_dim(leaf, rows[r], 1, axis=0)
+         for r in range(rows.shape[0])])
 
 
 def _state_rows_set(leaf, rows, new):
     """``leaf`` with ``rows`` replaced by ``new``, so written that a
-    donated leaf is updated where it lies: all rows elementwise, one row as
-    a slice update (a scatter of one row may copy the leaf first)."""
+    donated leaf is updated where it lies: all rows elementwise, a prefill
+    launch's rows as one slice update each (a scatter may copy the leaf
+    first, and a leaf is hundreds of megabytes). The rows must differ: the
+    updates run in order, so a row given twice keeps the last."""
     new = new.astype(leaf.dtype)
     if rows is None:
         return new
-    if rows.shape[0] == 1:
-        return lax.dynamic_update_slice_in_dim(leaf, new, rows[0], axis=0)
-    return leaf.at[rows].set(new)
+    for r in range(rows.shape[0]):
+        leaf = lax.dynamic_update_slice_in_dim(leaf, new[r:r + 1], rows[r],
+                                               axis=0)
+    return leaf
 
 
 def _conv_with_state(z, w, state, index, state_rows, valid, bias=None):
@@ -2087,6 +2091,14 @@ class Block(nn.Module):
         return _constrain(x, ("batch", "seq", "embed"))
 
 
+def _positions_kept(x, last):
+    """``x`` (B, S, D) at position ``last[b]`` of each row, (B, 1, D);
+    ``x`` itself where ``last`` is None."""
+    if last is None:
+        return x
+    return jnp.take_along_axis(x, jnp.reshape(last, (-1, 1, 1)), axis=1)
+
+
 class Transformer(nn.Module):
     """Token-in, logits-out. ``cfg.num_classes`` set → [CLS]-pooled
     classification logits (BERT/GLUE); otherwise per-token LM logits."""
@@ -2094,7 +2106,7 @@ class Transformer(nn.Module):
     cfg: TransformerConfig
 
     def _patterned(self, x, index, block_tables, state_rows, valid,
-                   return_hidden, embed):
+                   return_hidden, embed, last=None):
         """The forward of a model given as a pattern of layers, from the
         embedded tokens on: the learned table only if that is what the
         model has (``position_kind``), each layer its own mixer or
@@ -2122,7 +2134,7 @@ class Transformer(nn.Module):
                                name=f"block_{i}")(
                 x, index, block_tables=block_tables,
                 state_rows=state_rows, valid=valid, carried=carried)
-        x = _norm(cfg, "ln_f")(x)
+        x = _norm(cfg, "ln_f")(_positions_kept(x, last))
         if return_hidden:
             return x
         if cfg.tie_embeddings:
@@ -2138,7 +2150,7 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, tokens: jax.Array, index=None, *,
                  block_tables=None, adapter=None, moe_mask=None,
-                 state_rows=None, valid=None,
+                 state_rows=None, valid=None, last=None,
                  return_hidden: bool = False) -> jax.Array:
         # tokens (B, S) int32; ``index`` only in cfg.decode mode: the
         # absolute position of tokens[:, 0] (prefill passes 0, the decode
@@ -2147,7 +2159,11 @@ class Transformer(nn.Module):
         # applying the LM head — the entry point of the fused
         # cross-entropy loss path (ops/fused_ce.py), which must never see
         # full-vocab logits. Param layout is unchanged (init runs the
-        # default call, so lm_head still materializes).
+        # default call, so lm_head still materializes). ``last`` (B,) keeps
+        # one position a row for the final norm and the head, (B, 1, V)
+        # logits: a prefill launch samples one position a row, and the
+        # head over a whole chunk is 100 MB of float32 a row at a 200k
+        # vocabulary.
         cfg = self.cfg
         if cfg.decode and index is None:
             raise ValueError("cfg.decode=True requires the position index")
@@ -2161,7 +2177,7 @@ class Transformer(nn.Module):
         x = embed(tokens)
         if cfg.layers is not None:
             return self._patterned(x, index, block_tables, state_rows,
-                                   valid, return_hidden, embed)
+                                   valid, return_hidden, embed, last)
         positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.decode:
             # the serve engine passes a PER-REQUEST (B,) index vector
@@ -2188,7 +2204,8 @@ class Transformer(nn.Module):
             x = block(cfg, name=f"block_{i}")(
                 x, index, block_tables=block_tables, adapter=adapter,
                 moe_mask=moe_mask)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(
+            _positions_kept(x, last))
         if return_hidden:
             return x
 

@@ -145,22 +145,21 @@ def to_chrome_trace(events: Iterable) -> dict:
             out.append({"ph": "B" if kind == "span.begin" else "E",
                         "name": str(payload.get("name", kind)),
                         "pid": pid, "tid": tid, "ts": ts})
-        elif kind == "prefill.launch":
-            tid = ids.tid(pid, f"slot{payload.get('slot', 0)}")
-            out.append({"ph": "X", "name": f"prefill rid{payload.get('rid')}",
-                        "pid": pid, "tid": tid, "ts": ts,
-                        "dur": max(payload.get("dur_s", 0.0), 0.0) * 1e6,
-                        "args": {k: v for k, v in payload.items()
-                                 if k not in ("slot",)}})
-        elif kind == "decode.launch":
+        elif kind in ("prefill.launch", "decode.launch"):
+            # a launch carries several slots' rows: one box a slot's lane
             dur = max(payload.get("dur_s", 0.0), 0.0) * 1e6
             slots = payload.get("slots", [])
             rids = payload.get("rids", [])
-            for slot, rid in zip(slots, rids):
+            chunks = payload.get("chunks", [None] * len(slots))
+            for slot, rid, chunk in zip(slots, rids, chunks):
                 tid = ids.tid(pid, f"slot{slot}")
-                out.append({"ph": "X", "name": f"decode rid{rid}",
+                args = {"tick": payload.get("tick")}
+                if chunk is not None:
+                    args["chunk"] = chunk
+                out.append({"ph": "X",
+                            "name": f"{kind.partition('.')[0]} rid{rid}",
                             "pid": pid, "tid": tid, "ts": ts, "dur": dur,
-                            "args": {"tick": payload.get("tick")}})
+                            "args": args})
         elif kind == "req.admit":
             wait = payload.get("queue_wait_s")
             tid = ids.tid(pid, "queue")
@@ -203,8 +202,9 @@ def ttft_breakdown(events: Iterable) -> dict[int, dict[str, float]]:
             w = payload.get("queue_wait_s")
             if w is not None and math.isfinite(w):
                 queue_wait.setdefault(rid, w)
-        elif kind == "prefill.launch" and rid is not None:
-            prefill[rid] = prefill.get(rid, 0.0) + payload.get("dur_s", 0.0)
+        elif kind == "prefill.launch":
+            for r in payload.get("rids", []):
+                prefill[r] = prefill.get(r, 0.0) + payload.get("dur_s", 0.0)
         elif kind == "decode.launch":
             for r in payload.get("rids", []):
                 if r not in first_token:

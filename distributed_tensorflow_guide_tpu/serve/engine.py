@@ -1,7 +1,7 @@
 """Continuous-batching serving engine over the paged KV pool.
 
-Exactly TWO compiled programs serve every request mix, and neither ever
-retraces as the population changes:
+Exactly TWO programs serve every request mix, the prefill one compiled at
+three widths, and neither ever retraces as the population changes:
 
 * ``serve_decode_step`` — all ``slots`` rows advance one token. Each
   slot feeds its pending token at its own write position (the ``(B,)``
@@ -10,13 +10,17 @@ retraces as the population changes:
   mid-prefill slots ride along with all-trash tables: their writes land
   in the trash block, the causal mask zeroes whatever they read, and the
   host discards their samples.
-* ``serve_prefill_chunk_step`` — ONE request advances by one
-  ``prefill_chunk``-token chunk (B=1, static chunk width; the chunk is
-  just a C>1 decode through the same ``_paged_decode_attend`` path).
-  Long prompts stream through in chunks interleaved with decode steps,
-  so admission never stalls resident streams for a whole prefill. The
-  final chunk's sample at the prompt's last valid row IS the request's
-  first generated token.
+* ``serve_prefill_chunk_step`` — every prompt that waits, up to four,
+  advances by one ``prefill_chunk``-token chunk, a row each (static
+  chunk width; the chunk is just a C>1 decode through the same
+  ``_paged_decode_attend`` path). The rows are padded to the next of
+  ``PREFILL_WIDTHS``, and every width is compiled before the first
+  prefill launch returns. Long prompts stream through in chunks
+  interleaved with decode steps, one prefill launch between two decode
+  launches, so admission never stalls resident streams for a whole
+  prefill. The final chunk's sample at the prompt's last valid position
+  IS the request's first generated token, whatever else the launch
+  carried.
 
 Both programs are pool -> pool: the cache pool is donated and returned,
 so XLA aliases it in place (the state->state analogue of the one-shot
@@ -78,11 +82,9 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from distributed_tensorflow_guide_tpu.core.dist import retry_with_backoff
 from distributed_tensorflow_guide_tpu.models.generation import (
-    _sample,
     decode_config,
     sample_rows,
 )
@@ -93,6 +95,7 @@ from distributed_tensorflow_guide_tpu.models.transformer import (
 )
 from distributed_tensorflow_guide_tpu.obs import events as obs_events
 from distributed_tensorflow_guide_tpu.obs.tracing import span
+from distributed_tensorflow_guide_tpu.serve import program_cache
 from distributed_tensorflow_guide_tpu.serve.paged_cache import (
     BlockStore,
     table_row,
@@ -118,6 +121,21 @@ __all__ = ["Event", "Request", "ServeEngine", "EngineOverloaded",
 # rids are non-negative) and release after this many engine ticks
 _CHAOS_RID = -7
 _PRESSURE_HOLD_TICKS = 4
+
+# The rows a prefill launch may have. A launch carries the next chunk of
+# every waiting prompt, up to the widest, padded to the next width: a chunk
+# of 128 rows gives each weight byte 128 multiply-adds where the v5e's ridge
+# is 240, so a second prompt's chunk rides on bytes already read, and a
+# padded row costs what a real one does, so the ladder is short.
+PREFILL_WIDTHS = (1, 2, 4)
+# On the CPU a launch carries one prompt. Nothing there is bound by the
+# weights' bytes, and XLA's CPU products round by the extent of their row
+# axis (a chunk's numbers differ in the last bits with who shared its
+# launch: 1e-6 in float32, a routing choice now and then in bfloat16), while
+# what the CPU runs is tests that hold a request's tokens to references
+# computed for the request alone. A test of the wide programs sets this to
+# ``PREFILL_WIDTHS``.
+CPU_PREFILL_WIDTHS = (1,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,11 +307,19 @@ def _pool_scatter(pool, idx, rows):
 
 @jax.jit
 def _merge_tokens(pending, tokens, rows):
-    """The slots' pending tokens after a launch: the rows of ``rows`` take
-    the launch's ``tokens`` (a decode launch's vector, a prompt's one
-    sample, or what the host knows and the device does not), the others
-    keep theirs. Dispatched after the launch, never waited for."""
+    """The slots' pending tokens after a decode launch: the rows of
+    ``rows`` take the launch's ``tokens`` (or what the host knows and the
+    device does not), the others keep theirs. Dispatched after the launch,
+    never waited for."""
     return jnp.where(rows, tokens, pending)
+
+
+@jax.jit
+def _place_tokens(pending, tokens, slots):
+    """The slots' pending tokens after a prefill launch: row ``r``'s sample
+    is slot ``slots[r]``'s first token, and a row whose prompt has chunks
+    left, or a padding row, says a slot past the last and is dropped."""
+    return pending.at[slots].set(tokens, mode="drop")
 
 
 @dataclasses.dataclass
@@ -302,8 +328,8 @@ class _Launch:
     hand back, and what the scheduler was told with the values left open."""
 
     kind: str
-    arg: object  # the prefill slot, or the decode rows
-    ids: dict  # the spans' tick (and rid)
+    arg: list  # the slots of a prefill launch's rows, or the decode rows
+    ids: dict  # the spans' tick (and a prefill launch's rids)
     now: float
     t0: float
     outs: tuple  # (tokens, [overflowed,] [census ...]) on the device
@@ -311,6 +337,8 @@ class _Launch:
     keys: dict  # a decode launch's keys, for its ``engine.apply`` span
     produced: list | None = None  # None: not advanced until it is fetched
     owed: list = dataclasses.field(default_factory=list)
+    # where in the launch's tokens each produced event's value lies
+    took: list = dataclasses.field(default_factory=list)
 
 
 def _routed_counters(load: np.ndarray, first: int = 0,
@@ -403,6 +431,20 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
     lora = pcfg.lora_rank is not None
     moe = pcfg.moe
 
+    def chunk_samples(logits, start, valid, keys):
+        """A prefill launch's tokens, (R,), from the (R, 1, V) logits at
+        each row's last valid position (``last_valid``): row ``r``'s sample
+        under its own key for the absolute position ``start[r] +
+        valid[r]``, on a prompt's final chunk exactly the one-shot prefill
+        sample at position P, whatever else the launch carried. A padding
+        row (``valid`` 0) samples its first position; the host discards
+        it."""
+        pos_keys = jax.vmap(jax.random.fold_in)(keys, start + valid)
+        return sample_rows(logits[:, 0], pos_keys, temperature, top_k)
+
+    def last_valid(valid):
+        return jnp.maximum(valid - 1, 0)
+
     patterned = pcfg.layers is not None
     if patterned:
         # still exactly two jitted programs. A patterned model's pair
@@ -424,19 +466,14 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
                     _routed_fold(mut.get("routed_stats", {})))
 
         def prefill_chunk_step(params, pool, state, tables, start, chunk,
-                               valid, key, slot):
+                               valid, keys, slots):
             logits, mut = model.apply(
                 {"params": params, "cache": pool, "state": state},
-                chunk, start, block_tables=tables,
-                state_rows=jnp.reshape(slot, (1,)),
-                valid=jnp.reshape(valid, (1,)),
+                chunk, start, block_tables=tables, state_rows=slots,
+                valid=valid, last=last_valid(valid),
                 mutable=["cache", "state", "routed_stats"])
-            last = lax.dynamic_index_in_dim(logits[0], valid - 1, axis=0,
-                                            keepdims=False)
-            tok = _sample(last[None],
-                          jax.random.fold_in(key, start[0] + valid),
-                          temperature, top_k)[0]
-            return (tok, mut.get("cache", pool), mut.get("state", state),
+            return (chunk_samples(logits, start, valid, keys),
+                    mut.get("cache", pool), mut.get("state", state),
                     _routed_fold(mut.get("routed_stats", {})))
     elif lora:
         # still exactly two jitted programs: the LoRA engine's pair takes
@@ -454,17 +491,12 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
             return nxt, mut["cache"]
 
         def prefill_chunk_step(params, pool, tables, start, chunk, valid,
-                               key, adapters, adapter_ids):
+                               keys, adapters, adapter_ids):
             logits, mut = model.apply(
                 {"params": params, "cache": pool, "adapters": adapters},
-                chunk, start, block_tables=tables,
-                adapter=adapter_ids, mutable=["cache"])
-            last = lax.dynamic_index_in_dim(logits[0], valid - 1, axis=0,
-                                            keepdims=False)
-            tok = _sample(last[None],
-                          jax.random.fold_in(key, start[0] + valid),
-                          temperature, top_k)[0]
-            return tok, mut["cache"]
+                chunk, start, block_tables=tables, adapter=adapter_ids,
+                last=last_valid(valid), mutable=["cache"])
+            return chunk_samples(logits, start, valid, keys), mut["cache"]
     elif moe:
         # still exactly two jitted programs: the MoE pair runs the router
         # dispatch INSIDE the step (mutable=["moe_stats"] so the sown
@@ -483,23 +515,20 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
             return nxt, mut["cache"], of_tok > 0, load, overflow
 
         def prefill_chunk_step(params, pool, tables, start, chunk, valid,
-                               key):
-            # the dispatch buffer widens to the chunk length (MoEMLP:
-            # multi-token calls are dropless by construction), so a
-            # prefill chunk can never overflow — only pad rows past
-            # ``valid`` are masked out of the census
-            mask = (jnp.arange(chunk.shape[1]) < valid)[None, :]
+                               keys):
+            # the dispatch buffer widens to the launch's rows x the chunk
+            # length (MoEMLP: multi-token calls are dropless by
+            # construction), so a prefill chunk can never overflow — only
+            # pad rows past ``valid`` (a padding row: all of them) are
+            # masked out of the census
+            mask = jnp.arange(chunk.shape[1])[None, :] < valid[:, None]
             logits, mut = model.apply(
                 {"params": params, "cache": pool},
-                chunk, start, block_tables=tables,
-                moe_mask=mask, mutable=["cache", "moe_stats"])
+                chunk, start, block_tables=tables, moe_mask=mask,
+                last=last_valid(valid), mutable=["cache", "moe_stats"])
             load, overflow, _ = _moe_fold(mut["moe_stats"])
-            last = lax.dynamic_index_in_dim(logits[0], valid - 1, axis=0,
-                                            keepdims=False)
-            tok = _sample(last[None],
-                          jax.random.fold_in(key, start[0] + valid),
-                          temperature, top_k)[0]
-            return tok, mut["cache"], load, overflow
+            return (chunk_samples(logits, start, valid, keys), mut["cache"],
+                    load, overflow)
     else:
         def decode_step(params, pool, tables, written, last_tok, keys):
             """(S,) tokens in, (S,) tokens out; pool threaded
@@ -513,24 +542,19 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
             return nxt, mut["cache"]
 
         def prefill_chunk_step(params, pool, tables, start, chunk, valid,
-                               key):
-            """One (1, prefill_chunk) slice of one prompt. ``valid`` is
-            how many rows of the chunk are real prompt (the rest are pads
-            whose writes land inside the admitted blocks and are either
-            overwritten by decode before anything attends them, or masked
-            forever); the returned sample comes from row ``valid - 1``
-            with the key for absolute position ``start + valid`` — on the
-            final chunk that is exactly the one-shot prefill sample at
-            position P."""
+                               keys):
+            """One (R, prefill_chunk) launch: the next chunk of R prompts,
+            a row each. ``valid[r]`` is how many positions of row ``r`` are
+            real prompt (the rest are pads whose writes land inside the
+            admitted blocks and are either overwritten by decode before
+            anything attends them, or masked forever; a padding ROW has
+            none, and an all-trash table); the samples are
+            ``chunk_samples``'."""
             logits, mut = model.apply(
                 {"params": params, "cache": pool},
-                chunk, start, block_tables=tables, mutable=["cache"])
-            last = lax.dynamic_index_in_dim(logits[0], valid - 1, axis=0,
-                                            keepdims=False)
-            tok = _sample(last[None],
-                          jax.random.fold_in(key, start[0] + valid),
-                          temperature, top_k)[0]
-            return tok, mut["cache"]
+                chunk, start, block_tables=tables,
+                last=last_valid(valid), mutable=["cache"])
+            return chunk_samples(logits, start, valid, keys), mut["cache"]
 
     # donation intent is (1,) — the pool — for both programs; the CPU
     # backend doesn't implement input-output aliasing, same gate as
@@ -544,7 +568,8 @@ def build_step_fns(cfg: TransformerConfig, *, slots: int, num_blocks: int,
         decode=decode_jit, prefill=prefill_jit, model=model, cfg=pcfg,
         n_blk=n_blk, declared_donate_argnums=donated, donates_pool=donate,
         temperature=temperature, top_k=top_k, lora=lora, moe=moe,
-        patterned=patterned)
+        patterned=patterned, static=memo_key, programs={},
+        compiled_widths=set())
     _STEP_FNS[memo_key] = fns
     return fns
 
@@ -684,6 +709,13 @@ class ServeEngine:
             trash = self.sched.pool.trash_block
             self._cache_h2d(trash, self._cache_d2h(trash))
         self.steps = {"decode": 0, "prefill": 0, "idle": 0}
+        # the prefill program's widths this engine launches (a padding
+        # row's state row is a slot's, so none is wider than the slots)
+        # and the chunks its prefill launches carried
+        ladder = (CPU_PREFILL_WIDTHS if jax.default_backend() == "cpu"
+                  else PREFILL_WIDTHS)
+        self._widths = tuple(w for w in ladder if w <= slots)
+        self.prefill_chunks = 0
         # one launch in flight: the slots' pending tokens where the decode
         # program reads them, on the device (row i is slot i's; ``_row_slot``
         # says whose token a row holds, so that a slot whose token only the
@@ -904,7 +936,7 @@ class ServeEngine:
         the last launch was still unsettled), then ``fetch`` the host
         blocked until the device hands the tokens back and ``apply``
         from filling them in to the lifecycle events. The last two carry
-        the ``tick`` (and ``rid``) of the launch they settle, not of the
+        the ``tick`` (and ``rids``) of the launch they settle, not of the
         call they run in."""
         tick = self._tick
         self._tick += 1
@@ -953,54 +985,69 @@ class ServeEngine:
         """Launch what the plan asked for, tell the scheduler at once
         (the tokens' values left open), and only then settle the launch
         before it, which the device finished while this one was built. A
-        ``MoEMLP`` model's bookkeeping depends on what comes back (an
-        overflowed row keeps its token), so its launch is settled at
-        once, and advanced there: the same calls, none in flight."""
+        prefill launch takes the next chunk of as many of the plan's
+        waiting prompts as the widest program has rows, oldest first, in
+        the narrowest program that holds them. A ``MoEMLP`` model's
+        bookkeeping depends on what comes back (an overflowed row keeps
+        its token), so its launch is settled at once, and advanced there:
+        the same calls, none in flight."""
         rec, sd = self.rec, self.sched
         t0 = time.perf_counter()
-        keys = {}
+        keys, counts = {}, {}
         if kind == PREFILL:
-            slot = sd.slots[arg]
-            rows, fn, program = 1, self.fns.prefill, "prefill_chunk_step"
-            ids = {"tick": tick, "rid": slot.rid}
-            last_chunk = (slot.chunk_cursor + 1
-                          == sd.prefill_done_chunks(arg))
+            self._compile_prefill_widths()
+            arg = arg[:self._widths[-1]]
+            width = next(w for w in self._widths if w >= len(arg))
+            program = "prefill_chunk_step"
+            held = [sd.slots[i] for i in arg]
+            # (a space between them: the profiler's stats end at a comma)
+            ids = {"tick": tick, "rids": " ".join(str(s.rid) for s in held)}
+            counts = {"chunks": len(arg), "width": width}
+            # a row whose prompt ends in this chunk: its sample is the
+            # request's first token, where the next decode launch reads it
+            place = np.full((width,), self.num_slots, np.int32)
+            for r, (i, s) in enumerate(zip(arg, held)):
+                if s.chunk_cursor + 1 == sd.prefill_done_chunks(i):
+                    place[r] = i
         else:
-            rows, fn, program = len(arg), self.fns.decode, "decode_step"
+            width, program = None, "decode_step"
             ids = {"tick": tick}
             keys = self._decode_keys(arg)
         payload = None
         if rec.enabled:
             # launch identity, taken BEFORE the scheduler is told: it
             # frees a slot the moment its request completes
-            payload = ({"slot": arg, "rid": slot.rid,
-                        "chunk": slot.chunk_cursor} if kind == PREFILL
-                       else {"slots": list(arg),
-                             "rids": [sd.slots[i].rid for i in arg]})
-            payload["tick"] = tick
+            payload = {"slots": list(arg),
+                       "rids": [sd.slots[i].rid for i in arg], "tick": tick}
+            if kind == PREFILL:
+                payload.update(chunks=[s.chunk_cursor for s in held],
+                               width=width)
         with span(rec, "engine.build", cat="serve", kind=kind,
-                  rows=rows, **ids):
-            args = (self._prefill_operands(arg) if kind == PREFILL
+                  rows=len(arg), **ids):
+            args = (self._prefill_operands(arg, width) if kind == PREFILL
                     else self._decode_operands(arg))
+            fn = self._program(kind, args, width)
         overlapped = int(self._inflight is not None)
         with span(rec, "engine.dispatch", cat="serve", program=program,
-                  overlapped=overlapped, **ids):
+                  overlapped=overlapped, **counts, **ids):
             toks, self.pool, *outs = self._launch(
                 lambda: fn(*args), tag="serve_" + program)
             if self.fns.patterned:
                 self.state, *outs = outs
-            if kind != PREFILL or last_chunk:
-                # the next decode launch reads these tokens where they
-                # are: a prompt's first token in its slot's row, a decode
-                # launch's in the rows that were ready
+            # the next decode launch reads these tokens where they are
+            if kind == PREFILL:
+                self._pending = _place_tokens(self._pending, toks, place)
+                for r, i in enumerate(arg):
+                    if place[r] == i:
+                        self._row_slot[i] = held[r]
+            else:
                 took = np.zeros((self.num_slots,), bool)
                 took[arg] = True
                 self._pending = _merge_tokens(self._pending, toks, took)
-                if kind == PREFILL:
-                    self._row_slot[arg] = slot
         self.launches += 1
         self.overlapped_launches += overlapped
         self.steps[kind] += 1
+        self.prefill_chunks += counts.get("chunks", 0)
         launch = _Launch(kind, arg, ids, now, t0, (toks, *outs), payload,
                          keys)
         before, self._inflight = self._inflight, launch
@@ -1011,6 +1058,46 @@ class ServeEngine:
             self._fetch_apply(before, now)
         if self.fns.moe:
             self._settle(now)
+
+    def _compile_prefill_widths(self) -> None:
+        """Before the engine's first prefill launch, every width of the
+        prefill program once (and the small program that places its
+        tokens), all rows padding: a width met for the first time later
+        would be compiled inside somebody's tick, tens of seconds in which
+        no stream gets a token. Engines that share the traced programs and
+        the shapes that reach them (``build_step_fns``' memo; the slots,
+        the chunk's length, the device) share the compiled ones."""
+        shapes = (self.num_slots, self.sched.prefill_chunk, self.device,
+                  self._widths)
+        if shapes in self.fns.compiled_widths:
+            return
+        nowhere = np.full((self._widths[-1],), self.num_slots, np.int32)
+        for width in self._widths:
+            args = self._prefill_operands([], width)
+            toks, self.pool, *outs = self._program(PREFILL, args, width)(*args)
+            if self.fns.patterned:
+                self.state, *outs = outs
+            self._pending = _place_tokens(self._pending, toks,
+                                          nowhere[:width])
+        self.fns.compiled_widths.add(shapes)
+
+    def _program(self, kind: str, args: tuple, width: int | None):
+        """The step program of ``kind`` (the prefill one: of ``width``
+        rows) as a launch calls it with ``args``: the executable kept for
+        it where a compile cache is configured (``program_cache``: a
+        later process then loads it and traces nothing), else the jitted
+        program itself. Engines that share the traced programs and their
+        shapes share the executables."""
+        jitted = self.fns.prefill if kind == PREFILL else self.fns.decode
+        if program_cache.directory() is None:
+            return jitted
+        shapes = (kind, width, self.num_slots, self.sched.prefill_chunk,
+                  self.device)
+        if shapes not in self.fns.programs:
+            self.fns.programs[shapes] = program_cache.load_or_compile(
+                jitted, args, program=kind, static=self.fns.static,
+                device=self.device)
+        return self.fns.programs[shapes]
 
     def _decode_keys(self, ready: list[int]) -> dict:
         """What a decode launch over the ``ready`` slots reads of keys and
@@ -1028,18 +1115,22 @@ class ServeEngine:
 
     def _advance(self, launch: _Launch, toks=None,
                  overflowed=None) -> list[tuple]:
-        """The scheduler's bookkeeping for ``launch``: with ``toks`` None
-        every token's value is left open (``Scheduler.fill`` takes it
-        later). ``overflowed`` (``MoEMLP`` only, and then ``toks`` is
-        known) flags the slots whose token came from a forward that
-        skipped its expert at some layer."""
+        """The scheduler's bookkeeping for ``launch``, a slot at a time in
+        the launch's order: with ``toks`` None every token's value is left
+        open (``Scheduler.fill`` takes it later; ``launch.took`` says where
+        in the launch's tokens it will lie). ``overflowed`` (``MoEMLP``
+        only, and then ``toks`` is known) flags the slots whose token came
+        from a forward that skipped its expert at some layer."""
         sd = self.sched
-        if launch.kind == PREFILL:
-            return sd.apply_prefill(launch.arg,
-                                    None if toks is None else int(toks))
         produced, stalled = [], 0
-        for i in launch.arg:
-            if overflowed is not None and overflowed[i]:
+        for r, i in enumerate(launch.arg):
+            # a prefill launch's tokens lie a row each, a decode launch's
+            # a slot each
+            at = r if launch.kind == PREFILL else i
+            tok = None if toks is None else int(toks[at])
+            if launch.kind == PREFILL:
+                events = sd.apply_prefill(i, tok)
+            elif overflowed is not None and overflowed[i]:
                 # degrade-to-overflow: discard the token and leave
                 # pending/written untouched, so the SAME token retries
                 # next tick (cache rewrites are idempotent; dispatch fills
@@ -1050,8 +1141,10 @@ class ServeEngine:
                 stalled += 1
                 self._row_slot[i] = None
                 continue
-            produced.extend(sd.apply_decode(
-                i, None if toks is None else int(toks[i])))
+            else:
+                events = sd.apply_decode(i, tok)
+            produced.extend(events)
+            launch.took.extend([at] * len(events))
         if stalled:
             self._moe_stall_slot_ticks += stalled
             self._moe_stall_ticks += 1
@@ -1104,9 +1197,7 @@ class ServeEngine:
                     launch, toks, outs[0] if len(outs) == 3 else None)
             else:
                 values = toks.tolist()
-                values = ([values] * len(launch.owed)
-                          if launch.kind == PREFILL
-                          else [values[i] for i in launch.arg])
+                values = [values[at] for at in launch.took]
                 sd.fill(launch.owed, values)
                 produced = [(rid, value, first, done)
                             for (rid, _, first, done), value
@@ -1198,28 +1289,40 @@ class ServeEngine:
                       else (RuntimeError, OSError)),
             what=tag)
 
-    def _prefill_operands(self, i: int) -> tuple:
-        """The next chunk of slot ``i``'s prompt as the prefill program's
-        arguments."""
-        s = self.sched.slots[i]
-        CH = self.sched.prefill_chunk
-        start = s.chunk_cursor * CH
-        valid = min(CH, len(s.prompt) - start)
-        chunk = np.zeros((1, CH), np.int32)
-        chunk[0, :valid] = s.prompt[start:start + valid]
-        tables = table_row(s.blocks, self.fns.n_blk,
-                           self.sched.pool.trash_block)[None]
+    def _prefill_operands(self, rows: list[int], width: int) -> tuple:
+        """The next chunk of each of the slots ``rows``' prompts as the
+        arguments of the prefill program of ``width`` rows. The rows past
+        them are padding: no valid position, an all-trash table, and (a
+        model with state leaves) the state row of a slot that no real row
+        of the launch holds, which the program hands back as it was; the
+        rows' updates run in order, so a row given twice would not be."""
+        sd = self.sched
+        CH = sd.prefill_chunk
+        tables = np.tile(self._trash_row, (width, 1))
+        start, valid = (np.zeros((width,), np.int32) for _ in range(2))
+        chunk = np.zeros((width, CH), np.int32)
+        keys = np.zeros((width, 2), np.uint32)
+        adapter_ids = np.zeros((width,), np.int32)
+        for r, i in enumerate(rows):
+            s = sd.slots[i]
+            start[r] = s.chunk_cursor * CH
+            valid[r] = min(CH, len(s.prompt) - start[r])
+            chunk[r, :valid[r]] = s.prompt[start[r]:start[r] + valid[r]]
+            tables[r] = table_row(s.blocks, self.fns.n_blk,
+                                  sd.pool.trash_block)
+            keys[r] = s.rng
+            adapter_ids[r] = s.adapter
         # host operands go in as numpy: the launch moves them straight to
         # the device the committed params and pool are on
         if self.fns.patterned:
-            return (self.params, self.pool, self.state, tables,
-                    np.full((1,), start, np.int32), chunk, np.int32(valid),
-                    np.asarray(s.rng, np.uint32), np.int32(i))
-        args = (self.params, self.pool, tables,
-                np.full((1,), start, np.int32), chunk,
-                np.int32(valid), np.asarray(s.rng, np.uint32))
+            spare = [i for i in range(self.num_slots) if i not in rows]
+            state_rows = np.asarray(
+                list(rows) + spare[:width - len(rows)], np.int32)
+            return (self.params, self.pool, self.state, tables, start,
+                    chunk, valid, keys, state_rows)
+        args = (self.params, self.pool, tables, start, chunk, valid, keys)
         if self.fns.lora:
-            args += (self.adapters, np.full((1,), s.adapter, np.int32))
+            args += (self.adapters, adapter_ids)
         return args
 
     def _decode_operands(self, ready: list[int]) -> tuple:
@@ -1245,7 +1348,10 @@ class ServeEngine:
                 self._row_slot[i] = s
                 known[i], stale[i] = s.pending, True
         if stale.any():
-            self._pending = _merge_tokens(self._pending, known, stale)
+            # (on the device first: the merge then takes what it takes
+            # after a decode launch, and compiles nothing new mid-serve)
+            self._pending = _merge_tokens(
+                self._pending, jax.device_put(known, self.device), stale)
         last_tok = self._pending
         if self.fns.patterned:
             return (self.params, self.pool, self.state, tables, written,
@@ -1409,6 +1515,8 @@ class ServeEngine:
             "launch_failures": self.launch_failures,
             "launches": self.launches,
             "overlapped_launches": self.overlapped_launches,
+            "prefill_launches": self.steps[PREFILL],
+            "prefill_chunks": self.prefill_chunks,
             **({"moe": {
                 "expert_load": [int(x) for x in self._moe_load],
                 "expert_overflow": [int(x) for x in self._moe_overflow],
@@ -1435,7 +1543,8 @@ class ServeEngine:
             raise ValueError("ServeEngine(snapshot_dir=...) not configured")
         state = {"sched": self.sched.snapshot_state(),
                  "tick": self._tick,
-                 "steps": dict(self.steps)}
+                 "steps": dict(self.steps),
+                 "prefill_chunks": self.prefill_chunks}
         blob = np.frombuffer(json.dumps(state).encode("utf-8"),
                              dtype=np.uint8).copy()
         label = max(self._tick, self._last_snap + 1)
@@ -1592,6 +1701,7 @@ class ServeEngine:
         self._tick = int(state["tick"])
         for k, v in state["steps"].items():
             self.steps[k] = int(v)
+        self.prefill_chunks = int(state.get("prefill_chunks", 0))
         self._last_snap = label
         if self.rec.enabled:
             self.rec.emit(
@@ -1694,8 +1804,8 @@ def lint_contracts():
                     jax.ShapeDtypeStruct((1, fns.n_blk), i32),
                     jax.ShapeDtypeStruct((1,), i32),
                     jax.ShapeDtypeStruct((1, CH), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((2,), "uint32"))
+                    jax.ShapeDtypeStruct((1,), i32),
+                    jax.ShapeDtypeStruct((1, 2), "uint32"))
             return fns.prefill, args
 
         return _b
@@ -1832,7 +1942,8 @@ def lint_contracts():
             name="serve_prefill_chunk_step",
             build=_build("prefill"),
             cost=CostSpec(max_peak_live_bytes=98304),
-            notes="B=1 chunked prefill through the same attention path",
+            notes="chunked prefill through the same attention path, at "
+                  "the narrowest of its widths (one row)",
             **common),
         ProgramContract(
             name="serve_decode_step_lora",
@@ -1863,9 +1974,9 @@ def lint_contracts():
             name="serve_prefill_chunk_step_moe",
             build=_build("prefill_moe"),
             cost=CostSpec(max_peak_live_bytes=131072),
-            notes="B=1 MoE chunked prefill: the dispatch buffer widens "
-                  "to the chunk length (dropless by construction — a "
-                  "prefill token can never overflow), pad rows masked "
-                  "out of the census",
+            notes="MoE chunked prefill, one row: the dispatch buffer "
+                  "widens to the rows x the chunk length (dropless by "
+                  "construction — a prefill token can never overflow), "
+                  "pad rows masked out of the census",
             **common),
     ]

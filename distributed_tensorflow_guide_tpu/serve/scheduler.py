@@ -898,33 +898,36 @@ class Scheduler:
     # ---- tick planning ---------------------------------------------------
 
     def plan(self) -> tuple[str, object]:
-        """What the engine should launch this tick: ``("prefill", slot)``
-        one chunk for the oldest mid-prefill slot, ``("decode", [slots])``
-        one decode step over the active population, or ``("idle", None)``.
-        When both phases have work they ALTERNATE (chunked prefill
-        interleaved with decode — a long prompt no longer stalls every
-        resident stream for its whole prefill)."""
-        prefills = [i for i, s in enumerate(self.slots)
-                    if s is not None and s.phase == PREFILL]
+        """What the engine should launch this tick: ``("prefill",
+        [slots])`` the next chunk of the slots mid-prefill, oldest
+        admission first (the engine takes as many as its launch has rows),
+        ``("decode", [slots])`` one decode step over the active
+        population, or ``("idle", None)``. When both phases have work they
+        ALTERNATE (chunked prefill interleaved with decode — a long prompt
+        no longer stalls every resident stream for its whole prefill), one
+        prefill launch between two decode launches however many prompts
+        wait."""
+        prefills = self._prefilling()
         decodes = [i for i, s in enumerate(self.slots)
                    if s is not None and s.phase == DECODE]
         if prefills and (self._prefer_prefill or not decodes):
             self._prefer_prefill = False
-            best = min(prefills,
-                       key=lambda i: self.slots[i].admitted_seq)
-            return (PREFILL, best)
+            return (PREFILL, prefills)
         if decodes:
             self._prefer_prefill = bool(prefills)
             ready = self._grow_for_decode(decodes)
             if ready:
                 return (DECODE, ready)
-            prefills = [i for i, s in enumerate(self.slots)
-                        if s is not None and s.phase == PREFILL]
+            prefills = self._prefilling()  # growth may have preempted one
             if prefills:
-                best = min(prefills,
-                           key=lambda i: self.slots[i].admitted_seq)
-                return (PREFILL, best)
+                return (PREFILL, prefills)
         return ("idle", None)
+
+    def _prefilling(self) -> list[int]:
+        """The slots mid-prefill, oldest admission first."""
+        return sorted((i for i, s in enumerate(self.slots)
+                       if s is not None and s.phase == PREFILL),
+                      key=lambda i: self.slots[i].admitted_seq)
 
     def _grow_for_decode(self, decodes: list[int]) -> list[int]:
         """Every decoding slot must own the block its next write lands in;

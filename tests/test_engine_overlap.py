@@ -114,8 +114,9 @@ def assert_streams_well_formed(calls, eng):
     for events, kind in calls:
         ok = [e for e in events if e.status == "ok"]
         assert all(isinstance(e.token, int) and e.token >= 0 for e in ok)
-        if kind == "prefill":
-            assert len(ok) <= 1
+        if kind == "prefill":  # at most a token a row
+            assert len(ok) <= eng._widths[-1]
+            assert len({e.rid for e in ok}) == len(ok)
         if kind == "decode":
             assert not any(e.first for e in ok)
         if kind == "idle":

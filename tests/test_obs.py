@@ -232,8 +232,8 @@ def test_chrome_exporter_schema():
     rec.emit("span.end", cat="train",
              payload={"name": "s", "track": "loop"}, t=2.0)
     rec.emit("prefill.launch", cat="serve",
-             payload={"slot": 0, "rid": 1, "chunk": 8, "dur_s": 0.5},
-             t=3.0)
+             payload={"slots": [0, 2], "rids": [1, 4], "chunks": [8, 0],
+                      "width": 2, "tick": 0, "dur_s": 0.5}, t=3.0)
     rec.emit("decode.launch", cat="serve",
              payload={"slots": [0, 1], "rids": [1, 2], "tick": 1,
                       "dur_s": 0.25}, t=4.0)
@@ -259,7 +259,9 @@ def test_chrome_exporter_schema():
     # decode.launch fans out to one X per (slot, rid)
     xs = [e for e in real if e["ph"] == "X"]
     assert {e["name"] for e in xs} >= {"decode rid1", "decode rid2",
-                                       "prefill rid1"}
+                                       "prefill rid1", "prefill rid4"}
+    assert [e["args"]["chunk"] for e in xs
+            if e["name"].startswith("prefill")] == [8, 0]
     # the queue-wait bar is backdated by exactly the admit's wait
     bar = next(e for e in xs if e["name"] == "rid3 queued")
     assert bar["ts"] == pytest.approx(5.0e6 - 0.5e6)
@@ -489,8 +491,9 @@ def test_tick_phases_nest_in_their_tick_and_do_not_overlap(profiled):
 
 def test_the_recorder_sees_a_launch_settled_under_its_own_tick(params):
     """The same in the recorder: ``engine.fetch`` / ``engine.apply`` begin
-    with the tick (and rid) of the launch they settle, ``engine.dispatch``
-    says whether it overlapped, and ``health()`` counts both."""
+    with the tick (and rids) of the launch they settle, ``engine.dispatch``
+    says whether it overlapped (and a prefill launch its chunks and its
+    width), and ``health()`` counts both."""
     rec = obs_events.FlightRecorder(capacity=1 << 14)
     eng = _engine(CFG, params, recorder=rec)
     _drive(eng)
@@ -505,8 +508,8 @@ def test_the_recorder_sees_a_launch_settled_under_its_own_tick(params):
         len(dispatched) - 1)
     assert health["overlapped_launches"] == len(dispatched) - 1
     for name in ("engine.fetch", "engine.apply"):
-        got = [(p["tick"], p.get("rid")) for p in by_name[name]]
-        assert got == [(p["tick"], p.get("rid")) for p in dispatched], name
+        got = [(p["tick"], p.get("rids")) for p in by_name[name]]
+        assert got == [(p["tick"], p.get("rids")) for p in dispatched], name
     # a launch's recorder event keeps its own tick too
     launches = [e.payload["tick"] for e in rec.events()
                 if e.kind in ("prefill.launch", "decode.launch")]
@@ -514,10 +517,14 @@ def test_the_recorder_sees_a_launch_settled_under_its_own_tick(params):
     firsts = {e.payload["rid"]: e.payload["tick"] for e in rec.events()
               if e.kind == "req.first_token"}
     last_chunk = {}
-    for p in dispatched:
-        if p["program"] == "prefill_chunk_step":
-            last_chunk[p["rid"]] = p["tick"]
+    prefills = [p for p in dispatched if p["program"] == "prefill_chunk_step"]
+    for p in prefills:
+        rids = [int(r) for r in str(p["rids"]).split()]
+        assert p["chunks"] == len(rids) <= p["width"] <= 2  # two slots
+        last_chunk.update(dict.fromkeys(rids, p["tick"]))
     assert firsts == last_chunk  # the tick that launched the last chunk
+    assert (health["prefill_launches"], health["prefill_chunks"]) == (
+        len(prefills), sum(p["chunks"] for p in prefills))
 
 
 def test_span_attrs_read_back_from_the_events_stats(profiled):
@@ -525,10 +532,15 @@ def test_span_attrs_read_back_from_the_events_stats(profiled):
     prefills = [b for b in builds if b["kind"] == "prefill"]
     decodes = [b for b in builds if b["kind"] == "decode"]
     assert len(prefills) + len(decodes) == len(builds)
-    assert {b["rid"] for b in prefills} == {0, 1, 2}
-    assert all(b["rows"] == 1 for b in prefills)
+    # the profiler reads a lone rid back as a number, several as "0 1"
+    rids = [str(b["rids"]).split() for b in prefills]
+    assert {int(r) for launch in rids for r in launch} == {0, 1, 2}
+    assert [b["rows"] for b in prefills] == [len(launch) for launch in rids]
     assert decodes and all(
-        1 <= b["rows"] <= 2 and "rid" not in b for b in decodes)
+        1 <= b["rows"] <= 2 and "rids" not in b for b in decodes)
+    widths = [(r[3]["chunks"], r[3]["width"]) for r in profiled.spans
+              if r[0] == "engine.dispatch" and "chunks" in r[3]]
+    assert len(widths) == len(prefills) and set(widths) <= {(1, 1), (2, 2)}
     programs = {r[3]["program"] for r in profiled.spans
                 if r[0] == "engine.dispatch"}
     assert programs == {"prefill_chunk_step", "decode_step"}

@@ -79,6 +79,15 @@ def assert_mosaic(compiled) -> None:
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def prefill_operands(rows: int, n_blk: int, chunk: int) -> tuple:
+    """The host operands of a prefill launch of ``rows`` rows, abstract:
+    tables, start, chunk, valid, keys (a patterned model's program takes
+    its slots after them)."""
+    return (sds((rows, n_blk), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows, chunk), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows, 2), jnp.uint32))
+
+
 def test_compiler_is_in_the_loop(v5e):
     """The control: a kernel whose one block is 32 MiB of VMEM is refused,
     so a kernel that compiles below was really judged."""
@@ -206,24 +215,34 @@ def test_paged_decode_kernel_compiles_under_grouped_heads(v5e, as_on_tpu,
         sds((b, n_blk), jnp.int32), sds((b,), jnp.int32)))
 
 
+#: a decode launch (every slot a row of one token) and the prefill launches
+#: of the engine's widths (a row a prompt's 128-token chunk)
+LAUNCHES = pytest.mark.parametrize(
+    "chunk,prompts", [(1, None), (128, 1), (128, 2), (128, 4)],
+    ids=["decode", "prefill1", "prefill2", "prefill4"])
+
+
 @pytest.mark.parametrize("cache", ["bf16", "int8"])
-@pytest.mark.parametrize("chunk", [1, 128])
+@LAUNCHES
 @pytest.mark.parametrize("cell", ["gpt2-xl", "lfm2-24b-a2b"])
 def test_paged_kernel_grid_at_the_serving_cells_shapes(v5e, as_on_tpu, cell,
-                                                       chunk, cache):
+                                                       chunk, prompts, cache):
     """Both serving cells' attention layer, a decode launch and a prefill
-    chunk: ONE Pallas call (the benchmark counts launches as kernel events
-    over layers), whose grid is a step a slot and key tile at decode,
-    every pool head in it (GPT-2 XL 24 x 8 = 192 steps, LFM2 64 x 16 =
-    1,024), and a derived divisor of the heads a step at a 128-token
-    chunk; and the v5e's compiler takes it."""
+    launch of each width: ONE Pallas call (the benchmark counts launches
+    as kernel events over layers), whose grid is a step a slot and key
+    tile at decode, every pool head in it (GPT-2 XL 24 x 8 = 192 steps,
+    LFM2 64 x 16 = 1,024), and a derived divisor of the heads a step at a
+    128-token chunk, a row of the grid a prompt; and the v5e's compiler
+    takes it."""
     import math
 
     from distributed_tensorflow_guide_tpu.analysis import walker
 
     heads, kv_heads, rows, n_blk = {
         "gpt2-xl": (25, 25, 24, 8), "lfm2-24b-a2b": (32, 8, 64, 16)}[cell]
-    b, block_size = rows if chunk == 1 else 1, 128
+    b, block_size = prompts or rows, 128
+    assert DA.paged_supported(n_blk * block_size, block_size, block_size,
+                              chunk)
     dtype = jnp.int8 if cache == "int8" else jnp.bfloat16
     pool = sds((b * n_blk + 1, kv_heads, HD, block_size), dtype)
     scale = sds((b * n_blk + 1, kv_heads, 1, block_size), jnp.float32)
@@ -264,19 +283,20 @@ def leaf_sized_results(text: str, leaf: tuple[int, ...]) -> list[str]:
             if op not in ("parameter", "get-tuple-element")]
 
 
-@pytest.mark.parametrize("chunk", [1, 128])
+@LAUNCHES
 @pytest.mark.parametrize("cell", ["gpt2-xl", "lfm2-24b-a2b"])
-def test_paged_layer_moves_no_leaf(v5e, as_on_tpu, cell, chunk):
+def test_paged_layer_moves_no_leaf(v5e, as_on_tpu, cell, chunk, prompts):
     """One attention layer's cache write and paged kernel at the serving
-    cells' sizes, leaves donated: nothing of a leaf's size happens but the
-    write itself, in the leaf's own buffer (ROADMAP S8: a pool declared
-    (N, h, bs, hd) was copied whole three to four times a leaf here)."""
+    cells' sizes, leaves donated, in a decode launch and in a prefill
+    launch of each width: nothing of a leaf's size happens but the write
+    itself, in the leaf's own buffer (ROADMAP S8: a pool declared (N, h,
+    bs, hd) was copied whole three to four times a leaf here)."""
     from distributed_tensorflow_guide_tpu.serve.paged_cache import write_chunk
 
     blocks, heads, kv_heads, rows, n_blk = {
         "gpt2-xl": (129, 25, 25, 24, 8),
         "lfm2-24b-a2b": (1024, 32, 8, 64, 16)}[cell]
-    b, block_size = rows if chunk == 1 else 1, 128
+    b, block_size = prompts or rows, 128
     leaf = (blocks, kv_heads, HD, block_size)
 
     def layer(kp, vp, q, k, v, tables, index):
@@ -303,12 +323,14 @@ def test_paged_layer_moves_no_leaf(v5e, as_on_tpu, cell, chunk):
 
 
 @pytest.mark.parametrize("rows,first,held", [(64, 0, 64), (128, 0, 64),
-                                             (128, 8, 8)])
+                                             (128, 8, 8), (256, 0, 64),
+                                             (512, 0, 64)])
 def test_routed_ffn_compiles_to_native_grouped_products(v5e, rows, first,
                                                         held):
-    """A decode launch's and a prefill chunk's rows over all 64 experts,
-    and a chip's share of 8: three grouped products, each one call of the
-    compiler's own whose operations follow the rows, not the experts."""
+    """A decode launch's rows and those of a prefill launch of one, two and
+    four chunks over all 64 experts, and a chip's share of 8: three grouped
+    products, each one call of the compiler's own whose operations follow
+    the rows, not the experts."""
     from distributed_tensorflow_guide_tpu.ops.routed_ffn import routed_ffn
 
     d, ff, experts, top_k = 2048, 1536, 64, 4
@@ -370,13 +392,14 @@ def entry_results(text: str, leaf: tuple[int, ...]) -> list[str]:
 
 def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
                                                                 as_on_tpu):
-    """Both step programs of a Mamba-2, attention and routed layer at the
+    """The step programs of a Mamba-2, attention and routed layer at the
     Nemotron cell's widths and geometry (128 slots, 2,048 blocks of 128, a
-    128-token chunk), pool and state donated: every donated byte is
-    aliased, a launch's temporaries are a small part of ONE 268 MB state
-    leaf (so no leaf, and no 638 MB bank of experts, is copied), the decode
-    program makes each state leaf once, in its own buffer, and the prefill
-    program writes its slot's row as a slice update."""
+    128-token chunk; the prefill program at each of the engine's widths),
+    pool and state donated: every donated byte is aliased, a launch's
+    temporaries are a small part of ONE 268 MB state leaf (so no leaf, and
+    no 638 MB bank of experts, is copied), the decode program makes each
+    state leaf once, in its own buffer, and a prefill program writes its
+    slots' rows as slice updates, one a row."""
     from distributed_tensorflow_guide_tpu.models.transformer import (
         TransformerConfig,
     )
@@ -424,11 +447,12 @@ def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
     decode = compile_for(
         one, fns.decode, params, pool, state, i32(slots, fns.n_blk),
         i32(slots), i32(slots), sds((slots, 2), jnp.uint32))
-    prefill = compile_for(
-        one, fns.prefill, params, pool, state, i32(1, fns.n_blk), i32(1),
-        i32(1, chunk), i32(), sds((2,), jnp.uint32), i32())
+    prefills = [compile_for(one, fns.prefill, params, pool, state,
+                            *prefill_operands(rows, fns.n_blk, chunk),
+                            i32(rows))
+                for rows in E.PREFILL_WIDTHS]
     ssm_bytes = 4 * slots * 64 * 64 * 128
-    for compiled in (decode, prefill):
+    for compiled in (decode, *prefills):
         text = compiled.as_text()
         # the paged kernel, two cache writes, and the two grouped products
         # as the Pallas call with derived tiles (2688 and 1920 are no
@@ -441,21 +465,26 @@ def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
     # decode: the leaf comes out of one fusion (state in, state and y out)
     # and nothing else has its shape; prefill: a slice update in place
     assert entry_results(decode.as_text(), ssm_leaf) == []
-    moved = entry_results(prefill.as_text(), ssm_leaf)
-    assert len(moved) == 1 and "fusion" in moved[0], moved
-    moved = entry_results(prefill.as_text(), conv_leaf)
-    assert moved and all("dynamic-update-slice" in m for m in moved), moved
+    for rows, prefill in zip(E.PREFILL_WIDTHS, prefills):
+        moved = entry_results(prefill.as_text(), ssm_leaf)
+        assert len(moved) == rows and all("fusion" in m for m in moved), moved
+        moved = entry_results(prefill.as_text(), conv_leaf)
+        assert moved and all("dynamic-update-slice" in m
+                             for m in moved), moved
+        assert "copy" not in " ".join(
+            moved + entry_results(prefill.as_text(), ssm_leaf))
     assert not FA.fallback_stats()
 
 
 def test_the_token_merge_lowers_and_the_step_pair_takes_what_it_took(
         v5e, as_on_tpu):
     """One launch in flight (PR 34): the slots' pending tokens stay on the
-    device, merged by one ``where`` a launch, at the serving cells' 24, 64
-    and 128 slots, from a decode launch's vector and from a prompt's one
-    sample. Neither step program changes for it: the decode program lowers
-    from the engine's own operands, the device vector among them, to the
-    text it lowers to from the host vector it used to be handed."""
+    device, at the serving cells' 24, 64 and 128 slots: a decode launch's
+    vector merged by one ``where``, a prefill launch's samples (one, two
+    or four) put into their slots' rows. Neither step program changes for
+    it: the decode program lowers from the engine's own operands, the
+    device vector among them, to the text it lowers to from the host
+    vector it used to be handed."""
     from distributed_tensorflow_guide_tpu.models.transformer import (
         Transformer,
         TransformerConfig,
@@ -464,12 +493,16 @@ def test_the_token_merge_lowers_and_the_step_pair_takes_what_it_took(
 
     one = SingleDeviceSharding(v5e[0])
     for slots in (24, 64, 128):
-        for tokens in (sds((slots,), jnp.int32), sds((), jnp.int32)):
-            merged = compile_for(one, E._merge_tokens,
-                                 sds((slots,), jnp.int32), tokens,
-                                 sds((slots,), jnp.bool_))
-            text = merged.as_text()
-            assert "select" in text and "tpu_custom_call" not in text
+        pending = sds((slots,), jnp.int32)
+        programs = [compile_for(one, E._merge_tokens, pending, pending,
+                                sds((slots,), jnp.bool_))]
+        assert "select" in programs[0].as_text()
+        programs += [compile_for(one, E._place_tokens, pending,
+                                 sds((rows,), jnp.int32),
+                                 sds((rows,), jnp.int32))
+                     for rows in E.PREFILL_WIDTHS]
+        for merged in programs:
+            assert "tpu_custom_call" not in merged.as_text()
             (out,) = jax.tree.leaves(merged.out_info)
             assert (out.shape, out.dtype) == ((slots,), jnp.int32)
             # nothing is donated: a retried launch reads the same vector
@@ -481,6 +514,7 @@ def test_the_token_merge_lowers_and_the_step_pair_takes_what_it_took(
                           jnp.zeros((1, 8), jnp.int32))["params"]
     eng = E.ServeEngine(cfg, tree, slots=4, num_blocks=9, block_size=128,
                         prefill_chunk=128)
+    assert eng._widths == E.PREFILL_WIDTHS  # as on the chip: all of them
     eng.submit(E.Request(rid=0, prompt=np.arange(5, dtype=np.int32),
                          max_new_tokens=4, rng=np.zeros((2,), np.uint32)))
     eng.sched.admit(0.0)
@@ -495,6 +529,84 @@ def test_the_token_merge_lowers_and_the_step_pair_takes_what_it_took(
 
     assert lowered(operands) == lowered(as_before)
     assert "tpu_custom_call" in lowered(operands)
+
+
+@pytest.mark.parametrize("cell", ["gpt2-xl", "lfm2-24b-a2b"])
+def test_prefill_widths_compile_at_the_serving_cells_widths(v5e, as_on_tpu,
+                                                            cell):
+    """The prefill program of one, two and four rows at GPT-2 XL's and
+    LFM2's published widths and their cells' geometry, a layer of each
+    kind (the other two serving configurations: the two tests above and
+    below), pool and state donated: every donated byte is aliased, the
+    paged kernel and the cache writes are there, a launch's temporaries
+    stay a small part of one pool leaf (or grow by no more from one row
+    to four), and LFM2's convolution state is written a slice a row."""
+    from distributed_tensorflow_guide_tpu.models.transformer import (
+        Transformer,
+        TransformerConfig,
+    )
+    from distributed_tensorflow_guide_tpu.serve import engine as E
+    from yardstick import harness, weights, weights_lfm2
+
+    held = harness.load_json(harness.HERE / "configs" / f"{cell}.json")
+    dep = held["deployment"]
+    if cell == "gpt2-xl":
+        held["n_layer"] = 2
+        z = weights.sizes_of(held)
+        cfg = TransformerConfig(
+            vocab_size=z["vocab"], num_layers=z["L"], num_heads=z["h"],
+            d_model=z["d"], d_ff=z["ff"], max_len=z["positions"],
+            causal=True, dtype=jnp.dtype(dep["compute_dtype"]))
+        params = jax.eval_shape(
+            Transformer(cfg).init, jax.random.PRNGKey(0),
+            jnp.zeros((1, 8), jnp.int32))["params"]
+    else:
+        held.update(layer_types=["conv", "full_attention"],
+                    num_dense_layers=1)
+        z = weights_lfm2.sizes_of(held)
+        cfg = TransformerConfig(
+            vocab_size=z["vocab"], num_layers=z["L"], num_heads=z["h"],
+            d_model=z["d"], d_ff=z["ff"], max_len=z["positions"],
+            causal=True, dtype=jnp.dtype(dep["compute_dtype"]),
+            layers=z["layers"], norm="rmsnorm", norm_eps=z["eps"],
+            ffn_gate="silu", rope_theta=z["theta"], num_kv_heads=z["kv"],
+            qk_norm=True, conv_kernel=z["taps"], routed_experts=z["E"],
+            routed_top_k=z["k"], routed_d_ff=z["eff"])
+        assert [m for m, _ in z["layers"]] == ["short_conv", "attention"]
+        params = jax.eval_shape(lambda: weights_lfm2.flax_tree(1, z))
+    slots, chunk = dep["slots"], dep["prefill_chunk"]
+    fns = E.build_step_fns(cfg, slots=slots, num_blocks=dep["num_blocks"],
+                           block_size=dep["block_size"], prefill_chunk=chunk)
+    assert fns.donates_pool
+    pool = E.paged_cache_shapes(fns.cfg, slots)
+    state = (E._serving_shapes(fns.cfg, slots)["state"],
+             ) if fns.patterned else ()
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, state)))
+    pool_leaf = max(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    one = SingleDeviceSharding(v5e[0])
+    temps = []
+    for rows in E.PREFILL_WIDTHS:
+        compiled = compile_for(
+            one, fns.prefill, params, pool, *state,
+            *prefill_operands(rows, fns.n_blk, chunk),
+            *((sds((rows,), jnp.int32),) if fns.patterned else ()))
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 3  # two writes, the kernel
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == donated
+        temps.append(mem.temp_size_in_bytes)
+        for leaf in jax.tree.leaves(state):
+            moved = entry_results(text, leaf.shape)
+            assert len(moved) == rows and all(
+                "dynamic-update-slice" in m or m.endswith(" fusion")
+                for m in moved), moved
+    # GPT-2 XL's float32 tree is converted at each use (ROADMAP S9: 331 MB
+    # for these two layers and the head); what the rows add is small
+    assert temps[-1] - temps[0] < pool_leaf // 8, temps
+    if fns.patterned:
+        assert max(temps) < pool_leaf // 8, temps
+    assert not FA.fallback_stats()
 
 
 # ---- whole programs, at chip_smoke.py's sizes -------------------------------
@@ -570,9 +682,8 @@ def test_serve_step_pair_compiles(v5e, as_on_tpu):
     decode = compile_for(
         one, fns.decode, params, pool, i32(slots, fns.n_blk), i32(slots),
         i32(slots), sds((slots, 2), jnp.uint32))
-    prefill = compile_for(
-        one, fns.prefill, params, pool, i32(1, fns.n_blk), i32(1),
-        i32(1, chunk), i32(), sds((2,), jnp.uint32))
+    prefill = compile_for(one, fns.prefill, params, pool,
+                          *prefill_operands(1, fns.n_blk, chunk))
     assert_mosaic(decode)
     assert_mosaic(prefill)  # a 128-token chunk still takes the kernel
     assert not FA.fallback_stats()
@@ -641,18 +752,18 @@ def test_hybrid_decoder_step_pair_compiles_at_the_published_widths(
     decode = compile_for(
         one, fns.decode, params, pool, state, i32(slots, fns.n_blk),
         i32(slots), i32(slots), sds((slots, 2), jnp.uint32))
-    prefill = compile_for(
-        one, fns.prefill, params, pool, state, i32(1, fns.n_blk), i32(1),
-        i32(1, chunk), i32(), sds((2,), jnp.uint32), i32())
+    prefills = [compile_for(one, fns.prefill, params, pool, state,
+                            *prefill_operands(rows, fns.n_blk, chunk),
+                            i32(rows))
+                for rows in E.PREFILL_WIDTHS]
     ring_bytes = 2 * int(np.prod(ring_leaf))
-    for compiled in (decode, prefill):
+    for compiled in (decode, *prefills):
         text = compiled.as_text()
         # window: two writes and a read; full: two writes and a read;
         # cross: a read
         assert text.count("tpu_custom_call") == 7
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes == donated
-        assert mem.temp_size_in_bytes < max(ring_bytes,
-                                            2 * chunk * z["vocab"] * 4)
+        assert mem.temp_size_in_bytes < ring_bytes
     assert decode.memory_analysis().temp_size_in_bytes < ring_bytes // 4
     assert not FA.fallback_stats()
