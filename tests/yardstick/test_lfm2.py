@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from yardstick import compare, control_lfm2, counts_lfm2, harness
-from yardstick import program_spans, scoped_ops, weights_lfm2
+from yardstick import scoped_ops, weights_lfm2
 from yardstick import run as command
 from yardstick.readers import routed_roofline, scope_share, span_stat
 
@@ -262,7 +262,7 @@ def made_up_rows():
         ["%c", 61_000.0, 8_000.0, path + "dtg.routed/mlp/dtg.routed.experts/w"],
         ["%d", 70_000.0, 2_000.0, "jit(decode_step)/Transformer/lm_head/dot"],
         # the compiler's own grouped product: its path is its name
-        ["%ragged-dot-none.7 = bf16[8]", 72_000.0, 4_000.0,
+        ["ragged-dot-none.7 pallas:7->bf16", 72_000.0, 4_000.0,
          "ragged-dot-none"],
     ]}
     spans = []
@@ -287,29 +287,30 @@ def made_up_rows():
 @pytest.fixture()
 def made_up(monkeypatch):
     scoped, spans = made_up_rows()
-    monkeypatch.setattr(scoped_ops, "load", lambda cell: scoped)
-    monkeypatch.setattr(program_spans, "load", lambda cell: spans)
-    return made_up_facts()
+    monkeypatch.setattr(scoped_ops, "load", lambda trace: scoped)
+    facts = made_up_facts()
+    facts["trace"]["program"] = spans
+    return facts
 
 
 def test_scope_share_is_the_scopes_part_of_the_programs_device_time(made_up):
-    share = scope_share.read(made_up, cell="c", program="decode_step",
+    share = scope_share.read(made_up, program="decode_step",
                              scope="dtg.routed")
     assert share == pytest.approx(100.0 * 12_000 / 24_000)
-    share = scope_share.read(made_up, cell="c", program="decode_step",
+    share = scope_share.read(made_up, program="decode_step",
                              scope="dtg.routed", also_named=["ragged-dot"])
     assert share == pytest.approx(100.0 * 16_000 / 24_000)
-    assert scope_share.read(made_up, cell="c", program="decode_step",
+    assert scope_share.read(made_up, program="decode_step",
                             scope="dtg.short_conv") is None  # nothing there
-    assert scope_share.read(made_up, cell="c", program="no_such_program",
+    assert scope_share.read(made_up, program="no_such_program",
                             scope="dtg.routed") is None
 
 
 def test_span_stat_means_a_spans_number_over_one_programs_ticks(made_up):
-    args = dict(cell="c", span="engine.apply", stat="experts_touched")
+    args = dict(span="engine.apply", stat="experts_touched")
     assert span_stat.read(made_up, program="decode_step", **args) == 2.5
     assert span_stat.read(made_up, **args) == 3.0
-    assert span_stat.read(made_up, cell="c", span="engine.apply",
+    assert span_stat.read(made_up, span="engine.apply",
                           stat="no_such_stat") is None
 
 
@@ -321,14 +322,14 @@ def test_routed_roofline_is_least_time_over_the_scopes_time(made_up):
                                                  experts_touched=touched)
         least += max(flops / peaks["bf16_flops_per_s"],
                      nbytes / peaks["hbm_bytes_per_s"])  # one routed layer
-    got = routed_roofline.read(made_up, cell="c")
+    got = routed_roofline.read(made_up)
     assert got == pytest.approx(100.0 * least / 12e-6)
-    got = routed_roofline.read(made_up, cell="c", also_named=["ragged-dot"])
+    got = routed_roofline.read(made_up, also_named=["ragged-dot"])
     assert got == pytest.approx(100.0 * least / 16e-6)
     # a program whose spans carry no census leaves the metric out
-    for row in program_spans.load("c"):
+    for row in made_up["trace"]["program"]:
         row[3].pop("experts_touched", None)
-    assert routed_roofline.read(made_up, cell="c") is None
+    assert routed_roofline.read(made_up) is None
 
 
 def test_the_new_kernel_file_counts_the_pools_heads():

@@ -4,12 +4,15 @@ name with a space in it."""
 
 from __future__ import annotations
 
+import inspect
 import json
 import re
+import shutil
 
 import pytest
 
 from yardstick import harness, weights
+from yardstick import reduce as reduction
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -202,3 +205,64 @@ def test_full_check_fits_the_drivers_day(manifest):
 
 def test_manifest_is_plain_json():
     json.loads(harness.MANIFEST.read_text())
+
+
+def _cells() -> list[str]:
+    return [w["name"] for w in harness.load_json(harness.MANIFEST)[
+        "workloads"]]
+
+
+@pytest.mark.parametrize("cell", _cells())
+def test_a_metric_appended_for_any_cell_breaks_no_test(cell, tmp_path,
+                                                       monkeypatch):
+    """The rule a later PR that adds a counter or a span's metric relies
+    on: an entry appended after the last one in ``per_layer`` for any
+    accepted cell, with its file, passes every test here that reads
+    ``BENCHMARK.json`` (PR 34's and PR 36's metrics were refused or taken
+    out for a test that pinned a cell's list). On a copy of the manifest
+    and of ``layer_metrics`` in ``tmp_path``; the rest of the yardstick is
+    linked."""
+    from tests.yardstick import test_nemotron, test_phi4flash
+
+    (tmp_path / "tests").mkdir()
+    (tmp_path / "tests" / "yardstick").symlink_to(harness.ROOT / "tests"
+                                                  / "yardstick")
+    here = tmp_path / "yardstick"
+    here.mkdir()
+    for entry in harness.HERE.iterdir():
+        if entry.name not in ("layer_metrics", ".traces", "__pycache__"):
+            (here / entry.name).symlink_to(entry)
+    shutil.copytree(harness.HERE / "layer_metrics", here / "layer_metrics")
+    manifest = harness.load_json(harness.MANIFEST)
+    moves = next(m["name"] for m in manifest["end_to_end"]
+                 if m["name"] != "setup_s"
+                 and cell in m.get("workloads", [cell]))
+    # named as the cell's metrics are: ``<quantity>.<the cell's suffix>``
+    (suffix,) = {m["name"].rpartition(".")[2] for m in manifest["per_layer"]
+                 if m.get("workloads") == [cell]}
+    name = f"appended_peak_gb.{suffix}"
+    manifest["per_layer"].append({
+        "name": name, "unit": "GB", "better": "lower",
+        "source": "device_trace", "layer": "device", "moves": moves,
+        "workloads": [cell]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest, indent=2))
+    (here / "layer_metrics" / f"{name}.json").write_text(json.dumps(
+        {"name": name, "reader": "hbm_peak_gb", "args": {}}))
+    monkeypatch.setattr(harness, "ROOT", tmp_path)
+    monkeypatch.setattr(harness, "HERE", here)
+    monkeypatch.setattr(harness, "MANIFEST", tmp_path / "BENCHMARK.json")
+    copy = harness.load_json(harness.MANIFEST)
+    assert copy["per_layer"][-1]["name"] == name
+    checks = [fn for key, fn in sorted(globals().items())
+              if key.startswith("test_") and callable(fn)
+              and set(inspect.signature(fn).parameters) <= {"manifest"}]
+    assert len(checks) >= 8
+    for check in checks:
+        check(*([copy] if inspect.signature(check).parameters else []))
+    for module in (test_nemotron, test_phi4flash):  # a cell's own list
+        (check,) = [fn for key, fn in vars(module).items()
+                    if key.startswith("test_every_metric_of_the_cell")]
+        check()
+    assert name in {m["name"] for m in harness.load_cell(
+        cell, copy).per_layer}
+    assert reduction.read_metric(name, {"memory_peak_bytes": 2e9}) == 2.0

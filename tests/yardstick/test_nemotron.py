@@ -16,7 +16,7 @@ import pytest
 
 from tests.yardstick.test_lfm2 import made_up_facts
 from yardstick import compare, control, control_nemotron, counts_nemotron
-from yardstick import harness, program_spans, scoped_ops, weights_nemotron
+from yardstick import harness, scoped_ops, weights_nemotron
 from yardstick import run as command
 from yardstick.readers import scope_roofline
 
@@ -302,7 +302,7 @@ def test_scope_roofline_is_least_time_over_the_scopes_time(monkeypatch):
         ["%p", 41_000.0, 9_000.0, path + "dtg.ssm/ssm/z"],  # prefill
         ["%c", 61_000.0, 8_000.0, path + "dtg.routed/mlp/w"],
         ["%e", 70_000.0, 1_000.0, path + "dtg.shared_expert/shared/dot"],
-        ["%ragged-dot-none.7 = bf16[8]", 72_000.0, 4_000.0,
+        ["ragged-dot-none.7 pallas:7->bf16", 72_000.0, 4_000.0,
          "ragged-dot-none"],
         ["%t", 77_000.0, 2_000.0, path + "dtg.ssm/ssm/dtg.ssm.conv/v"],
     ]}
@@ -322,9 +322,9 @@ def test_scope_roofline_is_least_time_over_the_scopes_time(monkeypatch):
              {"tick": tick, "program": program}, 1],
             ["engine.apply", start + 24_000, 500.0, {"tick": tick, **stats},
              1]]
-    monkeypatch.setattr(scoped_ops, "load", lambda cell: scoped)
-    monkeypatch.setattr(program_spans, "load", lambda cell: spans)
+    monkeypatch.setattr(scoped_ops, "load", lambda trace: scoped)
     facts = made_up_facts()
+    facts["trace"]["program"] = spans
     z = facts["sizes"] = dict(
         d=8, h=2, kv=1, hd=4, ff=16, eff=4, sff=6, E=4, held=2, k=2, H=2,
         P=2, G=1, N=4, inner=4, wide=12, taps=4, vocab=32,
@@ -339,7 +339,7 @@ def test_scope_roofline_is_least_time_over_the_scopes_time(monkeypatch):
                                   nbytes / peaks["hbm_bytes_per_s"])
         return total
 
-    routed = dict(cell="c", scope="dtg.routed", counts="counts_nemotron",
+    routed = dict(scope="dtg.routed", counts="counts_nemotron",
                   work="routed_layer",
                   stats=["experts_touched", "held_assignments"])
     want = least(counts_nemotron.routed_layer, [
@@ -350,7 +350,7 @@ def test_scope_roofline_is_least_time_over_the_scopes_time(monkeypatch):
     assert scope_roofline.read(
         facts, also_named=["ragged-dot"], **routed) == pytest.approx(
             100.0 * want / 16e-6)
-    ssm = dict(cell="c", scope="dtg.ssm", counts="counts_nemotron",
+    ssm = dict(scope="dtg.ssm", counts="counts_nemotron",
                work="ssm_layer")
     want = least(counts_nemotron.ssm_layer, [dict(rows=5), dict(rows=4)])
     assert scope_roofline.read(facts, **ssm) == pytest.approx(
@@ -365,24 +365,28 @@ def test_scope_roofline_is_least_time_over_the_scopes_time(monkeypatch):
 
 
 def test_every_metric_of_the_cell_has_its_file_and_names_the_cell():
+    """The cell's metrics as this test knows them are there, wherever they
+    stand in ``per_layer``; one a later PR appends passes too, so no count
+    and no set is pinned (``test_manifest.py`` states the rule)."""
     manifest = harness.load_json(harness.MANIFEST)
     mine = [m for m in manifest["per_layer"] if m["name"].endswith(
         ".nemotron")]
-    assert {m["name"].rpartition(".")[0] for m in mine} == {
+    assert {m["name"].rpartition(".")[0] for m in mine} >= {
         "decode_step_ms", "mfu", "device_idle", "hbm_peak_gb",
-        "host_ms_per_tick", "ssm_share", "routed_share",
-        "shared_expert_share", "routed_roofline", "ssm_roofline",
-        "paged_decode_roofline", "experts_touched", "expert_load_ratio"}
+        "ssm_share", "routed_share", "shared_expert_share",
+        "routed_roofline", "ssm_roofline", "paged_decode_roofline",
+        "experts_touched", "expert_load_ratio", "overlapped_launches",
+        "chunks_per_prefill", "prefill_ms_per_chunk"}
     for m in mine:
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
         spec = harness.load_json(
             harness.HERE / "layer_metrics" / f"{m['name']}.json")
         assert spec["name"] == m["name"]
-        assert spec["args"].get("cell", CELL) == CELL
-    cell = _load_cell(CELL)
+        assert "cell" not in spec["args"]  # its cell is its ``workloads``
+    cell = _load_cell(CELL, manifest)
     assert {m["name"] for m in cell.end_to_end} == {"setup_s",
                                                     "serve_tokens_per_s"}
-    assert len(cell.per_layer) == 13
+    assert {m["name"] for m in mine} <= {m["name"] for m in cell.per_layer}
     # the grouped-heads kernel file reads this configuration's sizes as is
     from yardstick.kernels import paged_decode_gqa as kernel
 

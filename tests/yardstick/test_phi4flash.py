@@ -382,29 +382,30 @@ def test_the_windows_work_counts_valid_tokens_and_the_three_stores(tiny):
 
 
 def test_every_metric_of_the_cell_has_its_file_and_names_the_cell_alone():
+    """The cell's metrics as this test knows them are there, wherever they
+    stand in ``per_layer``; one a later PR appends passes too, so no count
+    and no set is pinned (``test_manifest.py`` states the rule)."""
     manifest = harness.load_json(harness.MANIFEST)
     mine = [m for m in manifest["per_layer"] if m["name"].endswith(
         ".phi4flash")]
-    assert {m["name"].rpartition(".")[0] for m in mine} == {
+    assert {m["name"].rpartition(".")[0] for m in mine} >= {
         "decode_step_ms", "mfu", "hbm_peak_gb",
         "overlapped_launches", "ssm_share", "window_attn_share",
         "full_attn_share", "cross_attn_share", "gmu_share", "ssm_roofline",
         "window_attn_roofline", "shared_kv_roofline",
-        # the host's side of a tick, by the program's own spans alone: a
-        # traced window of this cell outgrows the profiler's device events
-        # (PERF.md section 7), so no metric here subtracts device time,
-        # and ``device_idle`` is not read
+        # the host's side of a tick, by the program's own spans alone: the
+        # profiler keeps this cell's device events for the first ~22 s of
+        # the window and none after (PERF.md section 7), so no metric here
+        # subtracts device time, and ``device_idle`` is not read
         "schedule_ms_per_tick", "build_ms_per_tick", "apply_ms_per_tick",
-        "tick_max_ms"}
-    # at the end of the list, after every metric that was there
-    assert manifest["per_layer"][-16:] == mine
+        "tick_max_ms", "chunks_per_prefill", "prefill_ms_per_chunk"}
     scopes = {}
     for m in mine:
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
         spec = harness.load_json(
             harness.HERE / "layer_metrics" / f"{m['name']}.json")
         assert spec["name"] == m["name"]
-        assert spec["args"].get("cell", CELL) == CELL
+        assert "cell" not in spec["args"]  # its cell is its ``workloads``
         assert spec["reader"] != "device_idle"
         assert not spec["args"].get("less_device")
         if spec["reader"] in ("scope_share", "scope_roofline"):
@@ -426,10 +427,10 @@ def test_every_metric_of_the_cell_has_its_file_and_names_the_cell_alone():
     for m in manifest["per_layer"]:
         if not m["name"].endswith(".phi4flash"):
             assert CELL not in m.get("workloads", [CELL]), m["name"]
-    cell = _load_cell(CELL)
+    cell = _load_cell(CELL, manifest)
     assert {m["name"] for m in cell.end_to_end} == {"setup_s",
                                                     "serve_tokens_per_s"}
-    assert len(cell.per_layer) == 16
+    assert {m["name"] for m in mine} <= {m["name"] for m in cell.per_layer}
 
 
 def test_the_program_names_the_scopes_the_metrics_read():

@@ -11,10 +11,9 @@ from yardstick import harness
 from yardstick import reduce as reduction
 from yardstick.readers import (
     device_idle,
-    host_ms_per_tick,
     kernel_roofline,
     program_ms,
-    span_ms,
+    program_ms_per_stat,
     token_gap_ms,
 )
 from yardstick.readers import mfu as mfu_reader
@@ -111,17 +110,117 @@ def test_gap_attribution_and_breakdown():
     assert len(top) <= 10
 
 
-def test_host_time_per_tick_and_span_means():
+def program_row(name, start_ms, end_ms, line=1, **attrs):
+    return [name, start_ms * MS, (end_ms - start_ms) * MS, attrs, line]
+
+
+def test_a_gap_goes_to_the_innermost_span_that_covers_it():
+    """The synthetic window with the program's own spans in it: the gap
+    at 20-22 ms lies under ``engine.fetch`` inside ``ys.engine_step``, and
+    goes to the fetch; the gap at 40-50 under no program span keeps its
+    ``ys`` name; a leaf on another thread covers 0-10 only in part and
+    still takes it; a tick's span is no leaf and names nothing."""
     trace = synthetic()
-    # ticks of 33 and 45 ms that held 30 and 40 ms of programs
-    assert host_ms_per_tick.read({"trace": trace}) == pytest.approx(4.0)
-    spans = [("input_wait", 1.0, 1.002), ("input_wait", 2.0, 2.004),
-             ("input_wait", 0.1, 0.9)]  # the last lies before the window
-    facts = {"spans": spans, "t_open": 0.95, "t_close": 3.0, "steps": 3}
-    assert span_ms.read(facts, span="input_wait", per="steps") == (
-        pytest.approx(2.0))
-    assert span_ms.read(facts, span="input_wait") == pytest.approx(3.0)
-    assert span_ms.read(facts, span="absent") is None
+    trace["program"] = [
+        program_row("loop.data_wait", 2, 4, line=2),
+        program_row("engine.tick", 9, 41, tick=1),
+        program_row("engine.dispatch", 9, 10, tick=1),
+        program_row("engine.fetch", 19, 39, tick=1),
+        program_row("engine.tick", 91, 95, tick=2),  # under fence: no leaf
+    ]
+    gaps = dict(reduction.idle_gaps_by_span(trace))
+    assert gaps["loop.data_wait"] == pytest.approx(0.010)
+    assert gaps["engine.fetch"] == pytest.approx(0.002)
+    assert gaps["read_loss"] == pytest.approx(0.010)
+    assert gaps["engine.tick"] == pytest.approx(0.010)  # the leaf of line 1
+    assert "engine_step" not in gaps and "input_wait" not in gaps
+    assert sum(gaps.values()) == pytest.approx(0.032)
+
+
+def test_a_trace_without_the_programs_spans_names_the_benchmarks():
+    """Without ``dtg.`` rows (a program before PR 26, or the recorded
+    trace) attribution is what it was: the benchmark's spans alone."""
+    trace = synthetic()
+    trace["program"] = []
+    assert reduction.idle_gaps_by_span(trace) == (
+        reduction.idle_gaps_by_span(synthetic()))
+    assert reduction.events_held(trace) is None
+
+
+def serve_trace(dropped_ops=False, dropped_run=False):
+    """Four launches in a window of 100 ms: decode 10-30, prefill of 3
+    chunks 32-44, decode 46-66, prefill of 2 chunks 70-80; each launch's
+    dispatch span starts 2 ms before its run. ``dropped_ops`` keeps half
+    of the last decode's operations; ``dropped_run`` loses the first
+    prefill's run."""
+    runs = [("decode_step", 10, 30), ("prefill_chunk_step", 32, 44),
+            ("decode_step", 46, 66), ("prefill_chunk_step", 70, 80)]
+    programs = [[f"jit_{p}", a * MS, (b - a) * MS] for p, a, b in runs]
+    ops = [[f"fusion.{i}", a * MS, (b - a) * MS]
+           for i, (_, a, b) in enumerate(runs)]
+    if dropped_ops:
+        ops[2][2] /= 2
+    if dropped_run:
+        del programs[1]
+    spans = []
+    for tick, ((program, a, _), chunks) in enumerate(zip(runs,
+                                                         (0, 3, 0, 2))):
+        attrs = {"program": program, "overlapped": 1}
+        if chunks:
+            attrs.update(chunks=chunks, width=4)
+        spans.append(program_row("engine.dispatch", a - 2, a - 1.5,
+                                 tick=tick, **attrs))
+    return {"devices": {"0": {"programs": programs, "ops": ops}},
+            "host": [["window_open", 0.0, 0.0],
+                     ["window_close", 100 * MS, 0.0]],
+            "program": spans}
+
+
+def test_the_share_of_the_launches_the_trace_holds():
+    """What ``events_held`` prints for a run: the launches by the program's
+    dispatch spans against the runs and operations the device's lines
+    hold. A profiler that stops keeping device events partway through a
+    window (Phi-4-mini-flash's, PERF.md section 7) shows as runs missing
+    from a time on, with their operations."""
+    held = reduction.events_held(serve_trace())
+    assert held == {"launches": 4, "programs_held": 1.0,
+                    "ops_held": pytest.approx(1.0),
+                    "held_s": pytest.approx(0.080)}
+    held = reduction.events_held(serve_trace(dropped_ops=True))
+    assert held["ops_held"] == pytest.approx(52 / 62)
+    cut = serve_trace()
+    for key in ("programs", "ops"):  # nothing after 45 ms
+        cut["devices"]["0"][key] = cut["devices"]["0"][key][:2]
+    held = reduction.events_held(cut)
+    assert held["programs_held"] == 0.5 and held["ops_held"] == 1.0
+    assert held["held_s"] == pytest.approx(0.044)
+    # the idle share reads what the trace holds, as it did
+    assert reduction.busy_and_window_s(cut)[0] == pytest.approx(0.032)
+
+
+def test_prefill_device_time_per_chunk_pairs_runs_with_their_launches():
+    facts = {"trace": serve_trace()}
+    # 12 + 10 ms of prefill runs for 3 + 2 chunks
+    got = program_ms_per_stat.read(facts, program="prefill_chunk_step",
+                                   stat="chunks")
+    assert got == pytest.approx(22 / 5)
+    # a dropped run: its launch pairs with nothing and counts nothing
+    facts = {"trace": serve_trace(dropped_run=True)}
+    assert program_ms_per_stat.read(
+        facts, program="prefill_chunk_step", stat="chunks") == (
+            pytest.approx(10 / 2))
+    # a dropped dispatch: its run would pair with the launch before,
+    # which has its own run, so it is left out
+    trace = serve_trace()
+    del trace["program"][3]
+    assert program_ms_per_stat.read(
+        {"trace": trace}, program="prefill_chunk_step",
+        stat="chunks") == pytest.approx(12 / 3)
+    assert program_ms_per_stat.read(
+        facts, program="decode_step", stat="chunks") is None
+    facts["trace"]["program"] = []  # a program before the spans
+    assert program_ms_per_stat.read(
+        facts, program="prefill_chunk_step", stat="chunks") is None
 
 
 def test_roofline_share_and_mfu_from_the_synthetic_trace(capsys):
@@ -201,3 +300,9 @@ def test_recorded_trace_reduces(recorded):
     gaps = reduction.idle_gaps_by_span(trace)
     assert sum(s for _, s in gaps) == pytest.approx(window_s - busy_s,
                                                     rel=1e-6)
+    # recorded before the program's rows were kept: read as none
+    assert "program" not in trace
+    assert reduction.leaf_spans(trace) == []
+    assert reduction.events_held(trace) is None
+    assert reduction.read_metric("data_wait_ms.train",
+                                 {"trace": trace, "steps": 2}) is None

@@ -2,39 +2,28 @@
 that lies under a leaf span of the program's own (``dtg.*``: a span with no
 span inside it): idle time the program itself can put a name to. What is
 left lies between spans, or outside every span, in the caller's code.
-Standard error gets the idle seconds by leaf span."""
+Standard error gets the idle seconds by leaf span. The parts under a span
+are the ones ``reduce.idle_gaps_by_span`` hands the breakdown."""
 
 from __future__ import annotations
 
-import bisect
-
-from yardstick import harness, program_spans
+from yardstick import harness
 from yardstick import reduce as reduction
 
 
-def read(facts: dict, *, cell: str):
-    rows = program_spans.load(cell)
-    if not rows:
-        return None
+def read(facts: dict):
     trace = facts["trace"]
-    lo, hi = reduction.window_ns(trace)
-    device = sorted(trace["devices"])[0]
-    idle = reduction.gaps(
-        reduction.busy_intervals(trace, device, lo, hi), lo, hi)
+    if not trace.get("program"):
+        return None
+    idle = reduction.idle_gaps(trace)
     if not idle:
         return None
-    # the gaps are sorted and disjoint, so those a span overlaps are a run
-    starts, ends = [a for a, _ in idle], [b for _, b in idle]
     by_name: dict[str, float] = {}
     covered = []
-    for name, start, dur, _, _ in program_spans.leaves(rows):
-        under = reduction.clip(
-            idle[bisect.bisect_right(ends, start):
-                 bisect.bisect_left(starts, start + dur)],
-            start, start + dur)
-        if under:
-            by_name[name] = by_name.get(name, 0.0) + reduction.total(under)
-            covered.extend(under)
+    for _, name, a, b in reduction.under_spans(idle,
+                                               reduction.leaf_spans(trace)):
+        by_name[name] = by_name.get(name, 0.0) + (b - a)
+        covered.append((a, b))
     explained = reduction.total(reduction.union(covered))
     harness.say(idle_s=reduction.total(idle) / 1e9,
                 idle_s_by_leaf_span={

@@ -12,10 +12,10 @@ from __future__ import annotations
 from yardstick import program_spans
 
 
-def read(facts: dict, *, cell: str, span: str, stat: str = "mean",
+def read(facts: dict, *, span: str, stat: str = "mean",
          per: str | None = None, beside: str | None = None,
          less_device: bool = False):
-    rows = program_spans.in_window(facts, cell)
+    rows = program_spans.in_window(facts)
     picked = [r for r in rows if r[0] == span]
     if beside:
         ids = {program_spans.ident(r) for r in rows if r[0] == beside}

@@ -22,12 +22,12 @@ BUILD, DISPATCH, APPLY, TICK = ("engine.build", "engine.dispatch",
                                 "engine.apply", "engine.tick")
 
 
-def read(facts: dict, *, cell: str, program: str = "decode_step",
+def read(facts: dict, *, program: str = "decode_step",
          scope: str = "dtg.routed", also_named: tuple = ()):
     z = facts["sizes"]
     routed_layers = sum(ffn == "routed" for _, ffn in z["layers"])
     by_tick: dict = {}
-    for row in program_spans.load(cell):
+    for row in program_spans.rows(facts):
         if row[0] in (BUILD, DISPATCH, APPLY, TICK):
             by_tick.setdefault(program_spans.ident(row), {})[row[0]] = row
     lo, hi = reduction.window_ns(facts["trace"])
@@ -50,7 +50,7 @@ def read(facts: dict, *, cell: str, program: str = "decode_step",
             experts_touched=float(touched))
         least += routed_layers * counts.least_seconds(
             flops, nbytes, facts["peaks"])
-    rows = scoped_ops.rows_within(facts, cell, sorted(runs))
+    rows = scoped_ops.rows_within(facts, sorted(runs))
     spent = sum(r[2] for r in rows
                 if scoped_ops.under(r, scope, also_named))
     return 100.0 * least / (spent / 1e9) if spent else None

@@ -29,12 +29,12 @@ BUILD, DISPATCH, APPLY, TICK = ("engine.build", "engine.dispatch",
                                 "engine.apply", "engine.tick")
 
 
-def read(facts: dict, *, cell: str, scope: str, counts: str, work: str,
+def read(facts: dict, *, scope: str, counts: str, work: str,
          stats: tuple = (), program: str = "decode_step",
          also_named: tuple = ()):
     count = getattr(importlib.import_module(f"yardstick.{counts}"), work)
     by_tick: dict = {}
-    for row in program_spans.load(cell):
+    for row in program_spans.rows(facts):
         if row[0] in (BUILD, DISPATCH, APPLY, TICK):
             by_tick.setdefault(program_spans.ident(row), {})[row[0]] = row
     lo, hi = reduction.window_ns(facts["trace"])
@@ -57,7 +57,7 @@ def read(facts: dict, *, cell: str, scope: str, counts: str, work: str,
             **{s: float(carried[s]) for s in stats})
         least += layers * roofline.least_seconds(flops, nbytes,
                                                  facts["peaks"])
-    rows = scoped_ops.rows_within(facts, cell, sorted(runs))
+    rows = scoped_ops.rows_within(facts, sorted(runs))
     spent = sum(r[2] for r in rows
                 if scoped_ops.under(r, scope, also_named))
     return 100.0 * least / (spent / 1e9) if spent else None

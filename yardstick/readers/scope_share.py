@@ -15,10 +15,10 @@ from __future__ import annotations
 from yardstick import scoped_ops
 
 
-def read(facts: dict, *, cell: str, program: str, scope: str,
+def read(facts: dict, *, program: str, scope: str,
          also_named: tuple = ()):
     rows = scoped_ops.rows_within(
-        facts, cell, scoped_ops.program_runs(facts, program))
+        facts, scoped_ops.program_runs(facts, program))
     under = sum(r[2] for r in rows
                 if scoped_ops.under(r, scope, also_named))
     if not rows or not under:

@@ -15,9 +15,9 @@ def ticks_of(rows: list[list], program: str) -> set:
             if r[0] == "engine.dispatch" and r[3].get("program") == program}
 
 
-def read(facts: dict, *, cell: str, span: str, stat: str,
+def read(facts: dict, *, span: str, stat: str,
          program: str | None = None):
-    rows = program_spans.in_window(facts, cell)
+    rows = program_spans.in_window(facts)
     keep = ticks_of(rows, program) if program else None
     values = [float(r[3][stat]) for r in rows
               if r[0] == span and stat in r[3]

@@ -104,7 +104,7 @@ def main(argv=None, *, devices=None) -> int:
         from yardstick import reduce as reduction
 
         metrics, extra, breakdown = reduction.per_layer_metrics(
-            cell, trace_dir, spans, window, ran["facts"], devices, peak)
+            cell, trace_dir, ran["facts"], devices, peak)
         device.update(extra)
     else:
         metrics = {"setup_s": window.setup_s, **ran["end_to_end"]}

@@ -6,17 +6,19 @@ every operation, a fusion's too, as its ``op_name``. The profiler writes
 that into the trace as the ``tf_op`` stat of the operation's *event
 metadata*, which ``jax.profiler.ProfileData`` does not hand out (an event's
 own stats are its offset and duration; looked at by hand on a v5e trace, PR
-28). So this file reads the ``.xplane.pb`` a third time, as bytes: a
-protocol-buffer walk of just the fields it needs (``xplane.proto``: XSpace
-1 planes; XPlane 2 name, 4 event_metadata, 5 stat_metadata; a map entry 1
-key, 2 value; XEventMetadata 2 name, 5 stats; XStat 1 metadata_id, 5
-str_value, 7 ref_value; XStatMetadata 2 name), skipping the lines, which
-are nearly all of the file.
+28). So this file walks the ``.xplane.pb``'s bytes for them once, beside
+the reduction's one ``ProfileData`` parse: a protocol-buffer walk of just
+the fields it needs (``xplane.proto``: XSpace 1 planes; XPlane 2 name, 4
+event_metadata, 5 stat_metadata; a map entry 1 key, 2 value;
+XEventMetadata 2 name, 5 stats; XStat 1 metadata_id, 5 str_value, 7
+ref_value; XStatMetadata 2 name), skipping the lines, which are nearly all
+of the file.
 
-A row is ``[hlo_text, start_ns, dur_ns, scope_path]`` on the clock of
-``facts["trace"]`` (the same file, the same ``ProfileData`` arithmetic). A
-program without such scopes gives rows whose paths lack them, and a share
-read from them is 0: the metric's file decides whether that is left out.
+A row is ``[label, start_ns, dur_ns, scope_path]``: an operation event of
+``facts["trace"]`` (``reduce.op_label``'s name, the same clock) with the
+path its HLO text carries. A program without such scopes gives rows whose
+paths lack them, and a share read from them is 0: the metric's file
+decides whether that is left out.
 """
 
 from __future__ import annotations
@@ -24,7 +26,6 @@ from __future__ import annotations
 import functools
 from pathlib import Path
 
-from yardstick import harness
 from yardstick import reduce as reduction
 
 
@@ -106,27 +107,21 @@ def scope_paths(path: Path, stat: str = "tf_op") -> dict[str, str]:
     return out
 
 
-@functools.cache
-def load(cell: str) -> dict[str, list[list]]:
-    """Device -> the scoped rows of the traced run of ``cell`` that this
-    process made, in order of start."""
-    import jax
-
-    path = reduction.find_xplane(harness.HERE / ".traces" / cell)
-    scopes = scope_paths(path)
-    out: dict[str, list[list]] = {}
-    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
-        m = reduction.DEVICE_PLANE.match(plane.name)
-        if not m:
-            continue
-        rows = out.setdefault(m.group(1), [])
-        for line in plane.lines:
-            if line.name == reduction.OPS_LINE:
-                rows.extend([e.name, float(e.start_ns),
-                             float(e.duration_ns), scopes.get(e.name, "")]
-                            for e in line.events)
-        rows.sort(key=lambda r: r[1])
-    return out
+def load(trace: dict) -> dict[str, list[list]]:
+    """Device -> the scoped rows of ``trace`` (``reduce.load_xplane``'s),
+    in order of start: its operations, each with the scope path its text
+    has in the event metadata of the trace's file, read on the first call
+    and kept in ``trace``. A trace without the texts (the recorded one)
+    has none."""
+    if "scoped" not in trace:
+        path = trace.get("xplane")
+        scopes = scope_paths(path) if path else {}
+        trace["scoped"] = {
+            d: [[label, s, dur, scopes.get(text, "")]
+                for (label, s, dur), text in zip(dev["ops"],
+                                                 dev.get("op_text", ()))]
+            for d, dev in trace["devices"].items()}
+    return trace["scoped"]
 
 
 def program_runs(facts: dict, program: str) -> list[tuple[float, float]]:
@@ -138,22 +133,24 @@ def program_runs(facts: dict, program: str) -> list[tuple[float, float]]:
         trace, device, program) if lo <= s < hi]
 
 
-def rows_within(facts: dict, cell: str, runs) -> list[list]:
+def rows_within(facts: dict, runs) -> list[list]:
     """The first device's scoped rows that start inside ``runs`` (sorted,
     disjoint)."""
     device = sorted(facts["trace"]["devices"])[0]
-    out, i = [], 0
-    for row in load(cell).get(device, []):
-        while i < len(runs) and runs[i][1] <= row[1]:
-            i += 1
-        if i < len(runs) and runs[i][0] <= row[1]:
-            out.append(row)
-    return out
+    return reduction.starting_within(load(facts["trace"]).get(device, []),
+                                     runs)
 
 
 def under(row: list, scope: str, also_named=()) -> bool:
     """Whether a scoped row lies under ``scope``: by its path, or by its
     instruction's name where the compiler's own calls carry no path."""
-    text, _, _, path = row
-    return scope in path.split("/") or (
-        bool(also_named) and text.lstrip("%").startswith(tuple(also_named)))
+    label, _, _, path = row
+    return scope in _parts(path) or (
+        bool(also_named) and label.startswith(tuple(also_named)))
+
+
+@functools.cache
+def _parts(path: str) -> frozenset:
+    """A path's scopes; a trace has a few thousand paths for millions of
+    rows."""
+    return frozenset(path.split("/"))
