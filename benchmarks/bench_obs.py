@@ -9,11 +9,14 @@ Three phases, all CPU-honest:
    hot-path cost), plus the dump cost of the retained tail.
 2. **Disabled overhead** — the observe-only contract quantified: with
    recording OFF and no profiler session, a span site
-   (``obs/tracing.span``, always on) costs one profiler annotation that
-   records nothing and one ``rec.enabled`` check on each side. That
-   exact pattern is timed directly, a tiny jitted proxy train step is
-   timed for scale, and the derived ``disabled_overhead_frac`` =
-   spans-per-step x span-ns / step-ns must come in under 1% — the
+   (``obs/tracing.span``, always on) costs one check that no session runs
+   and one ``rec.enabled`` check on each side; the stall watch
+   (``obs/tracing.watch_stalls``) adds a young collection's early return,
+   a full collection's pair of callbacks and two counters on the tick's
+   span. Each pattern is timed directly, a tiny jitted proxy train step is
+   timed for scale (each the least of interleaved rounds' block means), and
+   the derived ``disabled_overhead_frac`` = (the step's spans and callbacks,
+   at the rates stated below) / step-ns must come in under 1% — the
    acceptance gate that keeps the spans always-on in any loop.
 3. **Cost reconciliation** — ``obs/recon.py`` joined end-to-end: the
    static cost vectors of the registered ``dp_train_step`` and
@@ -28,6 +31,7 @@ The JSON line's ``value`` is recorder throughput (events/sec).
 """
 
 import argparse
+import math
 import sys
 import time
 from pathlib import Path
@@ -41,6 +45,17 @@ from benchmarks.common import device_setup, report
 #: prefetching feed crosses five). This is the per-step multiplier for
 #: the derived disabled-overhead fraction.
 SPANS_PER_STEP = 6
+#: the stall watch's callbacks a step (obs/tracing.watch_stalls): a young
+#: collection's ``start`` and ``stop`` for four collections of generation
+#: 0 or 1, and a full collection's pair every hundred steps. Upper bounds
+#: of what a v5e window was counted to hold (PERF.md section 6, stalls):
+#: GPT-2 medium training met 212 young collections and one full one in
+#: 213 steps (~2 callbacks and 0.005 full pairs a step), GPT-2 XL serving
+#: ~0.04 young collections a tick and no full one
+YOUNG_CALLS_PER_STEP = 8
+FULL_PAIRS_PER_STEP = 0.01
+#: rounds of every timing; the least is the interference-free estimate
+ROUNDS = 15
 
 
 def main() -> int:
@@ -88,13 +103,38 @@ def main() -> int:
     assert dumped["total"] == n and len(dumped["events"]) <= args.capacity
 
     # ---- phase 2: disabled overhead -------------------------------------
+    # each side is its interference-free estimate: every round times a
+    # block of each pattern and a block of proxy steps, each block's mean
+    # a call, and each side keeps its least block over rounds that
+    # interleave them, so that a busy host (the smoke suite's parallel
+    # workers) inflates neither the spans nor the step they are measured
+    # against unless it is busy through all of them. A block and not one
+    # step: the fastest of many single steps is a tail (on the CPU it
+    # falls with the number of steps timed), a block's mean is not
     null = obs_events.NULL_RECORDER
-    m = 100_000
-    t0 = time.perf_counter()
-    for i in range(m):
-        with obs_tracing.span(null, "loop.dispatch", step=i):
-            pass
-    span_ns = (time.perf_counter() - t0) / m * 1e9
+    watch = obs_tracing.StallWatch()  # registered nowhere: driven by hand
+    young, full = {"generation": 0}, {"generation": 2}
+
+    def spans(m):
+        for i in range(m):
+            with obs_tracing.span(null, "loop.dispatch", step=i):
+                pass
+
+    def ticks(m):  # the tick's span, with the watch's two counters
+        for i in range(m):
+            with obs_tracing.span(null, "engine.tick", tick=i,
+                                  gc_full=watch.gc_full,
+                                  compiles=watch.compiles):
+                pass
+
+    def young_returns(m):
+        for _ in range(m):
+            watch.on_gc("start", young)
+
+    def full_pairs(m):
+        for _ in range(m):
+            watch.on_gc("start", full)
+            watch.on_gc("stop", full)
 
     # proxy step: a few chained matmuls — sized so one step is real work
     # on CPU but the bench stays inside the smoke budget
@@ -109,20 +149,33 @@ def main() -> int:
 
     x = proxy_step(x0)
     jax.block_until_ready(x)  # warm (compile outside the clock)
-    times = []
-    for _ in range(max(args.steps, 3)):
+    patterns = {"span": (spans, 10_000), "tick": (ticks, 10_000),
+                "young": (young_returns, 10_000), "full": (full_pairs, 1_000)}
+    steps = max(args.steps, 3)
+    best = dict.fromkeys([*patterns, "step"], math.inf)
+    for _ in range(ROUNDS):
+        for key, (body, m) in patterns.items():
+            t0 = time.perf_counter()
+            body(m)
+            best[key] = min(best[key], (time.perf_counter() - t0) / m)
         t0 = time.perf_counter()
-        x = proxy_step(x)
-        jax.block_until_ready(x)
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    step_s = times[len(times) // 2]
-    disabled_frac = SPANS_PER_STEP * span_ns * 1e-9 / step_s
+        for _ in range(steps):
+            x = proxy_step(x)
+            jax.block_until_ready(x)
+        best["step"] = min(best["step"], (time.perf_counter() - t0) / steps)
+    span_ns, tick_ns, young_ns, full_ns = (
+        best[k] * 1e9 for k in ("span", "tick", "young", "full"))
+    step_s = best["step"]
+    watched_ns = ((SPANS_PER_STEP - 1) * span_ns + tick_ns
+                  + YOUNG_CALLS_PER_STEP * young_ns
+                  + FULL_PAIRS_PER_STEP * full_ns)
+    disabled_frac = watched_ns * 1e-9 / step_s
     if disabled_frac >= 0.01:
         raise SystemExit(
             f"disabled-recorder overhead {disabled_frac:.2%} >= 1% of a "
             f"{step_s * 1e3:.2f} ms step — {SPANS_PER_STEP} spans of "
-            f"{span_ns:.0f} ns with nothing listening")
+            f"{span_ns:.0f} ns (the tick's {tick_ns:.0f}) and the stall "
+            f"watch's callbacks with nothing listening")
 
     # ---- phase 3: modeled-vs-measured reconciliation --------------------
     # abstract trace only (make_jaxpr): the SAME cost interpreter the
@@ -153,7 +206,12 @@ def main() -> int:
         ring_dropped=dumped["dropped"],
         dump_ms=round(dump_s * 1e3, 3),
         disabled_span_ns=round(span_ns, 1),
+        disabled_tick_span_ns=round(tick_ns, 1),
+        gc_young_return_ns=round(young_ns, 1),
+        gc_full_pair_ns=round(full_ns, 1),
         spans_per_step=SPANS_PER_STEP,
+        gc_young_calls_per_step=YOUNG_CALLS_PER_STEP,
+        gc_full_pairs_per_step=FULL_PAIRS_PER_STEP,
         proxy_step_ms=round(step_s * 1e3, 4),
         disabled_overhead_frac=round(disabled_frac, 6),
         measured_s_source=(

@@ -22,7 +22,7 @@ The event model every other obs layer builds on:
 * :func:`signature` — the determinism instrument: a stable tuple view of
   an event sequence that drops the wall clock (``mono``), the semantic
   clock by default, and :data:`VOLATILE` payload keys (wall-measured
-  durations), so two seeded runs compare exactly.
+  durations, process-wide counters), so two seeded runs compare exactly.
 
 Inertness contract: nothing here imports jax and no emission site feeds
 a compiled program; the recorder cannot change what the runtime computes,
@@ -46,8 +46,11 @@ SCHEMA = "dtg-flight-recorder-v1"
 
 #: payload keys that carry wall-measured durations — real data, but noise
 #: for the reproducibility signature (two identical seeded runs measure
-#: different launch times; everything else they emit is identical).
-VOLATILE = frozenset({"dur_s", "waited_s", "queue_wait_s", "ttft_s"})
+#: different launch times; everything else they emit is identical) — and
+#: the stall watch's process-wide counters on a tick or step's span (the
+#: collections and compiles of everything the process ran before).
+VOLATILE = frozenset({"dur_s", "waited_s", "queue_wait_s", "ttft_s",
+                      "gc_full", "compiles"})
 
 
 def _jsonable(v: Any) -> Any:

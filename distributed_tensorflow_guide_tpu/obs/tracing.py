@@ -6,7 +6,7 @@ that whenever a profiler session runs (``jax.profiler.start_trace``,
 ``utils.profiling.ProfilerHook``, the benchmark's ``--trace 1``) the span
 lies on the host plane of the same ``.xplane.pb`` as the device's ``XLA
 Modules`` and ``XLA Ops`` lines, on their clock. The session is the switch:
-with none running the annotation records nothing. And, when the recorder
+with none running no annotation is made. And, when the recorder
 is enabled, paired events (``span.begin`` / ``span.end``) in the same
 flight-recorder stream as everything else, with no second bookkeeping
 path. The exporter maps the serve engine's event vocabulary onto the
@@ -24,6 +24,11 @@ Chrome trace-event format
 * everything else                 -> ``i`` instant events (lifecycle
   terminals, prefix hits/evictions, chaos faults, snapshots, ...).
 
+:func:`watch_stalls` spans what stalls the host without being the
+program's own work: a generation-2 garbage collection (``dtg.gc``) and
+each phase of a JAX compile (``dtg.compile``), through the interpreter's
+``gc.callbacks`` and ``jax.monitoring``'s listeners, and counts both.
+
 Timestamps: the exporter prefers the semantic clock ``t`` (the engine's
 virtual ``now``) and falls back to ``mono`` when ``t`` is None (every
 span: a span measures the host, so it carries the wall clock). Events
@@ -36,14 +41,26 @@ records carrying the human names.
 
 from __future__ import annotations
 
+import gc
 import json
 import math
+import threading
 from typing import Iterable
 
+from distributed_tensorflow_guide_tpu.obs import events as obs_events
 from distributed_tensorflow_guide_tpu.obs.events import ObsEvent
 
 
 _annotation = None  # bound by the first span(): obs/ imports without jax
+_profiling = None  # ``TraceAnnotation.is_enabled``: a session is running
+
+
+def _bind() -> None:
+    global _annotation, _profiling
+    import jax
+
+    _profiling = jax.profiler.TraceAnnotation.is_enabled
+    _annotation = jax.profiler.TraceAnnotation  # the guard: bound last
 
 
 class span:
@@ -57,17 +74,18 @@ class span:
     evaluated whether or not anything listens. Spans nest; the ones of a
     tick or a step share its ``tick`` / ``step``. A class and not a
     generator under ``contextmanager``, which alone costs more than both
-    sinks do when nothing listens."""
+    sinks do when nothing listens. The annotation is made only while a
+    session runs (one made outside a session records nothing, even if a
+    session starts before it ends), which halves a span's cost when
+    nothing listens."""
 
     __slots__ = ("_profiled", "_rec", "_cat", "_name", "_attrs")
 
     def __init__(self, rec, name: str, *, cat: str = "train", **attrs):
-        global _annotation
         if _annotation is None:
-            import jax
-
-            _annotation = jax.profiler.TraceAnnotation
-        self._profiled = _annotation("dtg." + name, **attrs)
+            _bind()
+        self._profiled = (_annotation("dtg." + name, **attrs)
+                          if _profiling() else None)
         self._rec, self._cat, self._name, self._attrs = rec, cat, name, attrs
 
     def _emit(self, kind: str, attrs: dict) -> None:
@@ -76,14 +94,137 @@ class span:
                        payload={"name": self._name, "track": track, **attrs})
 
     def __enter__(self) -> None:
-        self._profiled.__enter__()
+        if self._profiled is not None:
+            self._profiled.__enter__()
         if self._rec.enabled:
             self._emit("span.begin", self._attrs)
 
     def __exit__(self, *exc) -> None:
         if self._rec.enabled:
             self._emit("span.end", {})
-        self._profiled.__exit__(*exc)
+        if self._profiled is not None:
+            self._profiled.__exit__(*exc)
+
+
+#: the phases of a JAX compile, by the event ``jax._src.dispatch.
+#: log_elapsed_time`` records around each: ``record_scalar(event, start)``
+#: on entry and ``record_event_duration_secs(event, elapsed)`` on exit,
+#: whether or not the phase raised
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+
+class StallWatch:
+    """Spans and counts of what stalls the host outside the program's own
+    work. ``gc_full``: generation-2 collections since the watch started;
+    ``compiles``: backend compiles (a persistent-cache hit among them);
+    ``faults``: callbacks that failed, caught here and never passed on to
+    the collector or the compiler.
+
+    A generation-2 collection is the span ``dtg.gc``; generations 0 and 1
+    return after one comparison (hundreds a second, each well under a
+    millisecond, and a span there would take the leaf from every engine
+    phase it struck). Each compile phase is ``dtg.compile`` with ``phase``
+    (``trace``, ``lower``, ``backend``) and ``fun``. Both spans go to the
+    profiler alone, the one sink their metrics read: a collection can
+    strike inside the flight recorder's own locked ``emit``, where a
+    ``span.begin`` would wait on a lock its own thread holds, and a
+    compile's phases depend on the cache, not on the run. Collections
+    never overlap (the interpreter makes one at a time), so one open gc
+    span is all there is; compile phases nest, and are kept per thread,
+    since a compile starts and ends on the thread that made it."""
+
+    def __init__(self):
+        self.gc_full = 0
+        self.compiles = 0
+        self.faults = 0
+        self._gc_open = None
+        self._open = threading.local()
+        self._count = threading.Lock()  # compiles may run on two threads
+
+    def _compiling(self) -> list:
+        """This thread's open compile spans, innermost last."""
+        return self._open.__dict__.setdefault("compile", [])
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` entry. ``gc_full`` needs no lock, and must not
+        take one: the collection may have struck while it was held."""
+        if info["generation"] != 2:
+            return
+        try:
+            if phase == "start":
+                self.gc_full += 1
+                self._gc_open = span(obs_events.NULL_RECORDER, "gc",
+                                     cat="runtime")
+                self._gc_open.__enter__()
+            elif self._gc_open is not None:
+                opened, self._gc_open = self._gc_open, None
+                opened.__exit__(None, None, None)
+        except Exception:  # noqa: BLE001 - never into the collector
+            self.faults += 1
+
+    def on_compile_start(self, event: str, value, **kwargs) -> None:
+        """``jax.monitoring`` scalar listener: a compile phase begins."""
+        phase = COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        try:
+            if phase == "backend":
+                with self._count:
+                    self.compiles += 1
+            # the profiler's stats end at a comma
+            fun = str(kwargs.get("fun_name", "")).replace(",", " ")
+            opened = span(obs_events.NULL_RECORDER, "compile", cat="runtime",
+                          phase=phase, fun=fun)
+            opened.__enter__()
+            self._compiling().append((phase, opened))
+        except Exception:  # noqa: BLE001 - never into the compiler
+            self.faults += 1
+
+    def on_compile_end(self, event: str, duration_secs, **kwargs) -> None:
+        """``jax.monitoring`` duration listener: the innermost open span of
+        the phase closes, with any left open inside it; a phase with none
+        open is ignored."""
+        phase = COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        try:
+            stack = self._compiling()
+            at = next((i for i in range(len(stack) - 1, -1, -1)
+                       if stack[i][0] == phase), None)
+            while at is not None and len(stack) > at:
+                stack.pop()[1].__exit__(None, None, None)
+        except Exception:  # noqa: BLE001 - never into the compiler
+            self.faults += 1
+
+
+_watch: StallWatch | None = None
+_watch_lock = threading.Lock()
+
+
+def watch_stalls() -> StallWatch:
+    """The process's one :class:`StallWatch`, registered with the
+    interpreter and with JAX on the first call; later calls register
+    nothing more. With no profiler session running a collection or a
+    compile costs the callback and a span that makes no annotation: the
+    session is the switch, as for every span."""
+    global _watch
+    with _watch_lock:
+        if _watch is None:
+            import jax
+
+            if _annotation is None:  # bound before a callback needs it
+                _bind()
+            watch = StallWatch()
+            gc.callbacks.append(watch.on_gc)
+            jax.monitoring.register_scalar_listener(watch.on_compile_start)
+            jax.monitoring.register_event_duration_secs_listener(
+                watch.on_compile_end)
+            _watch = watch
+    return _watch
 
 
 def _fields(e) -> tuple[str, str, str, float | None, float, dict]:

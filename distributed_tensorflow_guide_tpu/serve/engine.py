@@ -94,7 +94,7 @@ from distributed_tensorflow_guide_tpu.models.transformer import (
     TransformerConfig,
 )
 from distributed_tensorflow_guide_tpu.obs import events as obs_events
-from distributed_tensorflow_guide_tpu.obs.tracing import span
+from distributed_tensorflow_guide_tpu.obs.tracing import span, watch_stalls
 from distributed_tensorflow_guide_tpu.serve import program_cache
 from distributed_tensorflow_guide_tpu.serve.paged_cache import (
     BlockStore,
@@ -599,6 +599,9 @@ class ServeEngine:
                  tenant_quotas=None, drr_quantum: int | None = None,
                  adapters=None, recorder=None,
                  online_tune: bool | None = None):
+        # collections and compiles as spans of their own, counted on each
+        # tick's span (obs/tracing.watch_stalls: once a process)
+        self._stalls = watch_stalls()
         # online in-situ autotuning (round 21): True/False set the
         # process-wide override (the tuning table is process state, so
         # the knob is too — autotune.set_online_tune), None inherits the
@@ -928,7 +931,10 @@ class ServeEngine:
         was owed. The bench times this call to get per-launch service
         time.
 
-        The tick is one span, ``engine.tick``, and its phases five more
+        The tick is one span, ``engine.tick`` (with the stall watch's
+        ``gc_full`` and ``compiles`` as it opens: ``dtg.gc`` and
+        ``dtg.compile`` spans fall inside whichever phase they strike),
+        and its phases five more
         under it (obs/tracing.span: on the profiler's clock whenever a
         session runs, in the recorder when it is enabled): ``schedule``
         up to the plan, ``build`` the launch's host operands,
@@ -940,8 +946,9 @@ class ServeEngine:
         call they run in."""
         tick = self._tick
         self._tick += 1
-        rec, sd = self.rec, self.sched
-        with span(rec, "engine.tick", cat="serve", tick=tick):
+        rec, sd, stalls = self.rec, self.sched, self._stalls
+        with span(rec, "engine.tick", cat="serve", tick=tick,
+                  gc_full=stalls.gc_full, compiles=stalls.compiles):
             with span(rec, "engine.schedule", cat="serve", tick=tick):
                 if rec.enabled:
                     sd.now = now  # timestamps scheduler decisions

@@ -34,6 +34,9 @@ from pathlib import Path
 
 import jax
 
+from distributed_tensorflow_guide_tpu.obs import events as obs_events
+from distributed_tensorflow_guide_tpu.obs.tracing import span
+
 log = logging.getLogger(__name__)
 
 _PACKAGE = Path(__file__).resolve().parent.parent
@@ -101,11 +104,15 @@ def load_or_compile(jitted, args, *, program: str, static, device=None):
 
     path = Path(where) / _name(program, static, args, device)
     try:
-        with open(path, "rb") as f:
-            payload, in_tree, out_tree = pickle.load(f)
-        return serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree,
-            execution_devices=None if device is None else [device])
+        # JAX's compile events do not fire for a load: the span is ours,
+        # for the profiler alone as the watch's compile spans are
+        with span(obs_events.NULL_RECORDER, "compile", cat="runtime",
+                  phase="load", fun=program):
+            with open(path, "rb") as f:
+                payload, in_tree, out_tree = pickle.load(f)
+            return serialize_executable.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=None if device is None else [device])
     except FileNotFoundError:
         pass
     except Exception:  # noqa: BLE001 - any unreadable file: compile again

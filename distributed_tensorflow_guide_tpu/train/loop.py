@@ -33,7 +33,7 @@ import logging
 from typing import Any, Callable, Iterable, Iterator, Sequence
 
 from distributed_tensorflow_guide_tpu.obs import events as obs_events
-from distributed_tensorflow_guide_tpu.obs.tracing import span
+from distributed_tensorflow_guide_tpu.obs.tracing import span, watch_stalls
 from distributed_tensorflow_guide_tpu.train.hooks import Hook
 
 log = logging.getLogger("dtg.train")
@@ -115,6 +115,9 @@ class TrainLoop:
         # when it is enabled. Resolved once; nothing recorded ever feeds
         # the compiled step (50-step bitwise parity pinned).
         self.rec = recorder if recorder is not None else obs_events.current()
+        # collections and compiles as spans of their own, counted on each
+        # dispatch's span (once a process)
+        self._stalls = watch_stalls()
         from distributed_tensorflow_guide_tpu.utils.profiling import (
             DispatchStats,
         )
@@ -147,7 +150,9 @@ class TrainLoop:
         t0 = time.perf_counter()
         if self._last_return is not None:
             self.dispatch_stats.host_gap_s += t0 - self._last_return
-        with span(self.rec, "loop.dispatch", step=self.step):
+        stalls = self._stalls
+        with span(self.rec, "loop.dispatch", step=self.step,
+                  gc_full=stalls.gc_full, compiles=stalls.compiles):
             self.state, metrics = step_fn(self.state, batch)
         self._last_return = time.perf_counter()
         self.dispatch_stats.dispatch_s += self._last_return - t0

@@ -7,6 +7,7 @@ watchdog/give-up trips, export schema-valid Chrome traces, and join
 static cost vectors against measured time with pinned closed forms."""
 
 import dataclasses
+import gc
 import json
 import math
 import time
@@ -467,7 +468,8 @@ def test_tick_phases_nest_in_their_tick_and_do_not_overlap(profiled):
                          ENGINE_PHASES[:1]), (tick, names)
         for before, after in zip(inside, inside[1:]):
             assert before[2] <= after[1], (tick, before[0], after[0])
-        assert stats == {"tick": tick}
+        assert stats == {"tick": tick, "gc_full": stats["gc_full"],
+                         "compiles": stats["compiles"]}
         for r in inside:
             own = r[0] in ENGINE_PHASES[:3]
             assert r[3]["tick"] == (tick if own else unsettled), (tick, r[0])
@@ -558,6 +560,83 @@ def test_a_profiler_session_is_invisible_to_the_program(profiled):
     assert profiled.new_programs == (0, 0)
 
 
+@pytest.mark.parametrize("name", ["engine.tick", "loop.dispatch"])
+def test_tick_and_dispatch_carry_the_stall_counters(profiled, name):
+    """The watch's two counters as each span opened: whole numbers that
+    never fall and never pass what the watch has counted since, so a
+    reader tells "watched, nothing happened" (a 0 that stays) from a
+    program that does not watch (no attribute)."""
+    watch = obs_trace.watch_stalls()
+    rows = [r[3] for r in profiled.spans if r[0] == name]
+    assert rows
+    for counter, now in (("gc_full", watch.gc_full),
+                         ("compiles", watch.compiles)):
+        seen = [s[counter] for s in rows]
+        assert all(isinstance(v, int) and 0 <= v <= now for v in seen)
+        assert seen == sorted(seen), counter
+
+
+def test_watch_stalls_registers_once():
+    """Process-wide: an engine, a loop and a second call share one watch
+    and one entry in each of the three hooks."""
+    from jax._src import monitoring  # the public module lists no getters
+
+    watch = obs_trace.watch_stalls()
+    TrainLoop(_toy_step, jnp.ones((4,)), iter(()))
+    assert obs_trace.watch_stalls() is watch
+    assert gc.callbacks.count(watch.on_gc) == 1
+    assert monitoring.get_scalar_listeners().count(
+        watch.on_compile_start) == 1
+    assert monitoring.get_event_duration_listeners().count(
+        watch.on_compile_end) == 1
+
+
+def test_stall_callbacks_never_raise_and_count_what_failed(monkeypatch):
+    """A fresh watch (registered nowhere) driven by hand: a young
+    collection is ignored, a full one counted, a failure inside a callback
+    counted in ``faults`` and not raised, a phase's end with nothing open
+    ignored, and a stale inner phase closed with the one around it."""
+    watch = obs_trace.StallWatch()
+    watch.on_gc("start", {"generation": 0})
+    watch.on_gc("stop", {"generation": 1})
+    assert (watch.gc_full, watch._gc_open) == (0, None)
+    watch.on_gc("start", {"generation": 2})
+    assert watch.gc_full == 1 and watch._gc_open is not None
+    watch.on_gc("stop", {"generation": 2})
+    assert watch._gc_open is None
+    watch.on_gc("stop", {"generation": 2})  # nothing open: ignored
+    lower, trace = "/jax/core/compile/jaxpr_to_mlir_module_duration", \
+        "/jax/core/compile/jaxpr_trace_duration"
+    watch.on_compile_end(lower, 0.1, fun_name="f")  # nothing open
+    watch.on_compile_start("/jax/other", 1.0)  # not a compile phase
+    watch.on_compile_start(trace, 1.0, fun_name="f, g")
+    watch.on_compile_start(lower, 1.0, fun_name="f")
+    assert [p for p, _ in watch._compiling()] == ["trace", "lower"]
+    watch.on_compile_end(trace, 0.1, fun_name="f")  # closes both
+    assert (watch._compiling(), watch.faults) == ([], 0)
+
+    def broken(*a, **k):
+        raise RuntimeError("annotation")
+
+    # the stall spans go to the profiler alone, whatever recorder is set
+    rec = obs_events.FlightRecorder()
+    prev = obs_events.install(rec)
+    try:
+        watch.on_compile_start(trace, 1.0, fun_name="f")
+        watch.on_compile_end(trace, 0.1, fun_name="f")
+        watch.on_gc("start", {"generation": 2})
+        watch.on_gc("stop", {"generation": 2})
+    finally:
+        obs_events.install(prev)
+    assert (rec.events(), watch.gc_full) == ([], 2)
+
+    monkeypatch.setattr(obs_trace, "_annotation", broken)
+    monkeypatch.setattr(obs_trace, "_profiling", lambda: True)
+    watch.on_gc("start", {"generation": 2})
+    watch.on_compile_start("/jax/core/compile/backend_compile_duration", 1.0)
+    assert (watch.gc_full, watch.compiles, watch.faults) == (3, 1, 2)
+
+
 def test_span_feeds_recorder_and_profiler_alike():
     """Recorder on: the pair of events with the attrs in ``span.begin``;
     recorder off: nothing, and the block still runs; an exception leaves
@@ -576,9 +655,11 @@ def test_span_feeds_recorder_and_profiler_alike():
     assert end.payload == {"name": "engine.build", "track": "engine"}
     assert begin.t is None and end.mono >= begin.mono
     ran = []
-    with obs_trace.span(obs_events.NULL_RECORDER, "loop.hooks", step=1):
+    quiet = obs_trace.span(obs_events.NULL_RECORDER, "loop.hooks", step=1)
+    with quiet:
         ran.append(1)
     assert ran == [1] and len(rec.events()) == 2
+    assert quiet._profiled is None  # no session: no annotation is made
 
 
 # ---- black boxes: watchdog trip + seeded chaos storm ------------------------
