@@ -12,18 +12,17 @@ held (:func:`grouped_product`), and the weighted outputs are summed back per
 token. No capacity, so no token is dropped, and the cost follows the rows,
 not the experts.
 
-A grouped product is ``jax.lax.ragged_dot``: on a TPU one native
-grouped-matmul call whose operations are the rows' and not ``count`` times
-them (compiled for the v5e, PR 28). The compiler tiles that call by the
-largest power of two, up to 512, that divides each of the bank's two sizes.
-A bank of 2688 x 1920 (21 and 15 lanes of 128) gets tiles of 128 x 128: 20,000
-grid steps a call at a third of a microsecond each, 6.5 ms for 660 MB of
-weights that the memory delivers in 0.8 (my chip run, PR 33). For such a
-bank, on a TPU, the product is JAX's Pallas grouped matmul
+A grouped product on a TPU is JAX's Pallas grouped matmul
 (``pallas.ops.tpu.megablox``) with tiles this file derives: the whole
 contraction and as many whole lanes of the output as fit the kernel's share
-of VMEM, so a step moves megabytes of weights and the call is bound by
-their bytes. A bank whose sizes are multiples of 512 keeps the native call.
+of VMEM, so a grid step moves megabytes of weights and the call is bound by
+their bytes, not by its steps (a step costs a quarter to half a microsecond
+whatever it does). Its operations are the rows', not ``count`` times them.
+The compiler's own ``jax.lax.ragged_dot`` tiles by the largest power of two,
+up to 512, that divides each of the bank's sizes: on a TPU v5e, 128 x 128
+for a bank of 2688 x 1920 took 6.5 ms for 660 MB of weights that the memory
+delivers in 0.8, and 512 x 512 for 2048 x 1536 read 60% of the bytes'
+time. It stays for the CPU and for a bank that is not whole lanes.
 
 The parts that ``E / count`` programs give for the same tokens add up to
 the whole layer: routing is computed in full by each (it is small, and in
@@ -43,7 +42,6 @@ from jax import lax
 from distributed_tensorflow_guide_tpu.ops import autotune
 
 LANE = 128
-NATIVE_TILE = 512  # the largest edge the compiler's own tiling takes
 ROW_TILE = 128  # rows of a step of the Pallas product
 
 
@@ -71,16 +69,11 @@ def grouped_tiles(k: int, n: int, itemsize: int, *,
 
 
 def grouped_impl(k: int, n: int) -> str:
-    """``"native"`` (``lax.ragged_dot``) or ``"pallas"``: the latter on a
-    TPU for a bank of whole lanes that the compiler's own tiling would cut
-    finer than ``NATIVE_TILE`` on either side."""
-    def native(x):
-        return min(x & -x, NATIVE_TILE)
-
-    fine = native(k) < NATIVE_TILE or native(n) < NATIVE_TILE
+    """``"pallas"`` on a TPU for a bank whose two sizes are whole lanes,
+    else ``"native"`` (``lax.ragged_dot``)."""
     lanes = k % LANE == 0 and n % LANE == 0
     on_tpu = jax.default_backend() == "tpu"
-    return "pallas" if on_tpu and fine and lanes else "native"
+    return "pallas" if on_tpu and lanes else "native"
 
 
 def grouped_product(rows, bank, sizes, *, impl: str | None = None,

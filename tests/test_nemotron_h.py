@@ -401,8 +401,7 @@ def test_the_pallas_grouped_product_is_the_native_one():
     """Five groups of which one is empty and whose sizes fall short of the
     rows (the rest belongs to experts held elsewhere), through JAX's Pallas
     grouped matmul in interpret mode with this file's tiles, against
-    ``lax.ragged_dot``; the tiles derived for the Nemotron cell's banks;
-    and which banks take which."""
+    ``lax.ragged_dot``; and which banks take which."""
     from distributed_tensorflow_guide_tpu.ops import routed_ffn as R
 
     k = jax.random.split(jax.random.PRNGKey(0), 2)
@@ -416,20 +415,54 @@ def test_the_pallas_grouped_product_is_the_native_one():
     np.testing.assert_allclose(np.asarray(pallas[:167]),
                                np.asarray(native[:167]), rtol=1e-4,
                                atol=1e-3)
-    # the whole contraction and whole lanes of the output within 8 MiB
-    assert R.grouped_tiles(2688, 1920, 2) == (128, 2688, 384)
-    assert R.grouped_tiles(1920, 2688, 2) == (128, 1920, 384)
-    assert R.grouped_tiles(2688, 1920, 2, budget=1 << 20) == (128, 512, 128)
     # the CPU keeps the native call whatever the bank
     assert R.grouped_impl(2688, 1920) == "native"
 
 
-def test_on_a_tpu_a_bank_of_odd_lanes_takes_the_pallas_product(monkeypatch):
+@pytest.mark.parametrize("k,n,budget,tiles", [
+    (2688, 1920, None, (128, 2688, 384)),  # Nemotron's banks
+    (1920, 2688, None, (128, 1920, 384)),
+    (2688, 1920, 1 << 20, (128, 512, 128)),
+    (2048, 1536, None, (128, 2048, 768)),  # LFM2's
+    (1536, 2048, None, (128, 1536, 1024))])
+def test_grouped_tiles_take_the_whole_contraction_and_whole_lanes(
+        k, n, budget, tiles):
+    """The whole contraction and as many whole lanes of the output as fit
+    8 MiB (or the budget given)."""
+    from distributed_tensorflow_guide_tpu.ops import routed_ffn as R
+
+    assert R.grouped_tiles(k, n, 2, budget=budget) == tiles
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])
+def test_the_pallas_grouped_product_at_lfm2_widths(k, n):
+    """LFM2's banks, gate or up (2048 -> 1536) and down (1536 -> 2048), at a
+    decode launch's group sizes: four or five rows an expert and one expert
+    none, short of the rows. The Pallas product with the tiles derived for
+    the bank, in interpret mode, against ``lax.ragged_dot`` on the rows
+    that fall in groups."""
+    from distributed_tensorflow_guide_tpu.ops import routed_ffn as R
+
+    key = jax.random.split(jax.random.PRNGKey(1), 2)
+    rows = jax.random.normal(key[0], (24, k), jnp.float32)
+    bank = jax.random.normal(key[1], (5, k, n), jnp.float32) / np.sqrt(k)
+    sizes = jnp.asarray([4, 5, 0, 5, 4], jnp.int32)
+    native = R.grouped_product(rows, bank, sizes, impl="native")
+    pallas = R.grouped_product(rows, bank, sizes, impl="pallas",
+                               interpret=True)
+    assert pallas.shape == native.shape == (24, n)
+    np.testing.assert_allclose(np.asarray(pallas[:18]),
+                               np.asarray(native[:18]), rtol=1e-4,
+                               atol=1e-4)
+
+
+def test_on_a_tpu_a_bank_of_whole_lanes_takes_the_pallas_product(
+        monkeypatch):
     from distributed_tensorflow_guide_tpu.ops import routed_ffn as R
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert R.grouped_impl(2688, 1920) == R.grouped_impl(1920, 2688) == "pallas"
-    assert R.grouped_impl(2048, 1536) == R.grouped_impl(1536, 2048) == "native"
+    assert R.grouped_impl(2048, 1536) == R.grouped_impl(1536, 2048) == "pallas"
     assert R.grouped_impl(48, 24) == "native"  # no whole lanes
 
 

@@ -325,12 +325,13 @@ def test_paged_layer_moves_no_leaf(v5e, as_on_tpu, cell, chunk, prompts):
 @pytest.mark.parametrize("rows,first,held", [(64, 0, 64), (128, 0, 64),
                                              (128, 8, 8), (256, 0, 64),
                                              (512, 0, 64)])
-def test_routed_ffn_compiles_to_native_grouped_products(v5e, rows, first,
-                                                        held):
+def test_routed_ffn_compiles_to_pallas_grouped_products(v5e, as_on_tpu,
+                                                        rows, first, held):
     """A decode launch's rows and those of a prefill launch of one, two and
-    four chunks over all 64 experts, and a chip's share of 8: three grouped
-    products, each one call of the compiler's own whose operations follow
-    the rows, not the experts."""
+    four chunks over all 64 experts, and a chip's share of 8, at LFM2's
+    widths: three grouped products, each JAX's Pallas grouped matmul and
+    none the compiler's own, whose operations follow the rows, not the
+    experts."""
     from distributed_tensorflow_guide_tpu.ops.routed_ffn import routed_ffn
 
     d, ff, experts, top_k = 2048, 1536, 64, 4
@@ -344,7 +345,9 @@ def test_routed_ffn_compiles_to_native_grouped_products(v5e, rows, first,
         sds((d, experts), jnp.float32), sds((experts,), jnp.float32),
         sds((held, d, ff), jnp.bfloat16), sds((held, d, ff), jnp.bfloat16),
         sds((held, ff, d), jnp.bfloat16))
-    assert compiled.as_text().count("%ragged-dot-none") >= 3
+    text = compiled.as_text()
+    assert "%ragged-dot-none" not in text and "jit(gmm)" in text
+    assert text.count("tpu_custom_call") >= 3
     flops = compiled.cost_analysis()["flops"]
     products = rows * top_k * 3 * 2 * d * ff
     assert products <= flops < 1.5 * products  # not `held` times them
@@ -428,7 +431,7 @@ def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
     from distributed_tensorflow_guide_tpu.ops import routed_ffn
 
     assert routed_ffn.grouped_impl(z["d"], z["eff_stored"]) == "pallas"
-    assert routed_ffn.grouped_impl(2048, 1536) == "native"  # LFM2's banks
+    assert routed_ffn.grouped_impl(2048, 1536) == "pallas"  # LFM2's banks
     fns = E.build_step_fns(cfg, slots=slots, num_blocks=dep["num_blocks"],
                            block_size=dep["block_size"], prefill_chunk=chunk)
     assert fns.donates_pool and fns.declared_donate_argnums == (1, 2)
@@ -455,8 +458,7 @@ def test_state_space_step_pair_compiles_and_moves_no_state_leaf(v5e,
     for compiled in (decode, *prefills):
         text = compiled.as_text()
         # the paged kernel, two cache writes, and the two grouped products
-        # as the Pallas call with derived tiles (2688 and 1920 are no
-        # multiples of 512: the native call would tile them 128 x 128)
+        # as the Pallas call with derived tiles
         assert text.count("tpu_custom_call") >= 5
         assert "%ragged-dot-none" not in text and "jit(gmm)" in text
         mem = compiled.memory_analysis()
