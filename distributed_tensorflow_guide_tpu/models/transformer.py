@@ -42,7 +42,7 @@ from distributed_tensorflow_guide_tpu.utils.activation_sharding import (
 Dtype = Any
 
 MIXERS = ("attention", "short_conv", "mamba2", "mamba1", "window_attention",
-          "cross_attention", "gmu")
+          "cross_attention", "gmu", "latent_attention")
 FFNS = ("dense", "routed")
 #: the mixers whose sequences carry state beside their keys and values in
 #: the block pool (a window layer's keys and values are such state: they
@@ -53,6 +53,29 @@ HYBRID_ATTENTION = ("window_attention", "cross_attention")
 #: a window layer's two leaves of the ``state`` collection: its slots' rings
 WINDOW_LEAVES = ("win_key", "win_value")
 POSITIONS = ("table", "rotary", "none")
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """The sizes a ``latent_attention`` mixer (DeepSeek-V2's multi-head
+    latent attention) carries: queries through a rank ``q_rank`` bottleneck,
+    keys and values out of one ``kv_rank`` latent a position, each head's
+    key ``nope`` wide plus a rotary part ``rope`` wide that every head
+    shares, and each head's value ``value`` wide. A position's cache row is
+    its latent and its rotary key, ``kv_rank + rope`` numbers, whatever the
+    heads."""
+
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    value: int
+
+    @property
+    def row(self) -> int:
+        """What a position keeps in the cache: the latent and the rotary
+        key."""
+        return self.kv_rank + self.rope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -288,6 +311,12 @@ class TransformerConfig:
     attn_bias: bool = False
     # the head is the embedding, transposed: no ``lm_head`` leaf
     tie_embeddings: bool = False
+    # a ``latent_attention`` mixer's sizes (``LatentSizes``); its heads are
+    # ``num_heads`` and its rotation ``rope_theta``
+    latent: LatentSizes | None = None
+    # a second norm on each half's output before the residual add: ``x +
+    # norm_post(f(norm(x)))`` (``ln1_post`` / ``ln2_post``)
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         self._check_pattern()
@@ -416,7 +445,9 @@ class TransformerConfig:
                      window=self.window is not None,
                      differential=self.differential,
                      attn_bias=self.attn_bias,
-                     tie_embeddings=self.tie_embeddings)
+                     tie_embeddings=self.tie_embeddings,
+                     latent=self.latent is not None,
+                     sandwich_norm=self.sandwich_norm)
         if self.layers is None:
             given = sorted(k for k, v in sizes.items() if v)
             if given:
@@ -518,6 +549,18 @@ class TransformerConfig:
                 and (self.position_kind == "rotary" or self.qk_norm)):
             raise ValueError("window and cross attention have no wiring for "
                              "rotary positions or qk_norm")
+        if ("latent_attention" in mixers) != (self.latent is not None):
+            raise ValueError("latent_attention layers, and they alone, take "
+                             "latent sizes")
+        if self.latent is not None:
+            z = self.latent
+            if (self.position_kind != "rotary" or z.rope % 2
+                    or min(z.q_rank, z.kv_rank, z.nope, z.rope,
+                           z.value) < 1):
+                raise ValueError(
+                    "latent attention turns an even rotary part of its keys "
+                    f"by rope_theta: got {z!r}, positions "
+                    f"{self.position_kind!r}")
         if self.differential and (self.num_heads % 2 or self.kv_heads % 2):
             raise ValueError("differential attention pairs heads: "
                              "num_heads and num_kv_heads must be even")
@@ -1184,7 +1227,115 @@ def _dense_cache_read(q, keys, vals, index, layout: str, dtype,
         probs = probs * v_scale[:, :, None]
     out = jnp.einsum(f"bhgqk,{layout}->bqhgd", probs.astype(dtype),
                      vals.astype(dtype))
-    return out.reshape(B, C, hq, hd)
+    return out.reshape(B, C, hq, -1)  # a value may be narrower than a key
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section
+    2.1), the ``latent_attention`` mixer, ``cfg.latent``'s sizes over
+    ``cfg.num_heads`` heads:
+
+        c_q = RMSNorm(x W_qa);  q = c_q W_qb = [q_nope | q_rope] a head;
+        [c_kv | k_r] = x W_kva;  c_kv = RMSNorm(c_kv);
+        [k_nope | v] = c_kv W_kvb a head;  k = [k_nope | k_r];
+        out = concat_h(softmax(q k / sqrt(nope + rope)) v) W_o,
+
+    ``q_rope`` and ``k_r`` turned by their positions at ``rope_theta`` (one
+    rotary key that all heads share). A position keeps ONE row, ``[c_kv |
+    k_r]`` (``LatentSizes.row`` numbers), and nothing a head: attention
+    runs in the absorbed form over those rows, ``W_kvb``'s key half folded
+    into the query (``q_lat = q_nope W_uk^T``, a row ``[q_lat | q_rope]``)
+    and its value half applied to what the softmax weighs (``o = (p c_kv)
+    W_uv``), the same numbers as the expanded form up to rounding. In
+    decode mode the rows go into the paged pool's ``latent`` leaf, ``(N, 1,
+    row, block_size)``, and are read through the block tables: on a TPU by
+    ``ops/decode_attention.py paged_latent_attention`` (scope
+    ``dtg.latent_attn``), elsewhere through the gathered view and the dense
+    read. The training view attends over its own rows with a causal
+    mask."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, index=None, *, block_tables=None):
+        cfg, z = self.cfg, self.cfg.latent
+        h, dtype = cfg.num_heads, cfg.dtype
+        eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+
+        def project(features, names, name):
+            return nn.DenseGeneral(features, axis=-1, dtype=dtype,
+                                   use_bias=False,
+                                   kernel_init=_dense_init(*names),
+                                   name=name)
+
+        width = z.nope + z.rope
+        c_q = project(z.q_rank, ("embed", "mlp"), "q_a_proj")(x)
+        c_q = nn.RMSNorm(epsilon=eps, dtype=dtype, name="q_a_norm")(c_q)
+        q = project((h, width), ("mlp", "heads", "kv"), "q_b_proj")(c_q)
+        kv = project(z.row, ("embed", "mlp"), "kv_a_proj")(x)
+        c_kv = nn.RMSNorm(epsilon=eps, dtype=dtype, name="kv_a_norm")(
+            kv[..., :z.kv_rank])
+        positions = jnp.arange(x.shape[1])[None, :]
+        if cfg.decode:
+            positions = positions + jnp.reshape(index, (-1, 1))
+        q_rope = rotate(q[..., z.nope:], positions, cfg.rope_theta)
+        k_rope = rotate(kv[..., None, z.kv_rank:], positions,
+                        cfg.rope_theta)[:, :, 0]
+        rows = jnp.concatenate([c_kv, k_rope], -1)  # (B, S, row)
+        w_kv = _ExpertBank((z.kv_rank, h, z.nope + z.value),
+                           ("mlp", "heads", "kv"), name="kv_b_proj")()
+        w_kv = w_kv.astype(dtype)
+        q_lat = jnp.einsum("bshn,lhn->bshl", q[..., :z.nope],
+                           w_kv[:, :, :z.nope])
+        q = jnp.concatenate([q_lat, q_rope], -1)  # (B, S, h, row)
+        scale = width ** -0.5
+        if cfg.decode and cfg.paged:
+            lat = self._paged_attend(q, rows, index, block_tables, scale)
+        else:
+            view = jnp.swapaxes(rows, 1, 2)[:, None]  # (B, 1, row, S)
+            lat = _dense_cache_read(q, view, view[:, :, :z.kv_rank], 0,
+                                    "bhdk", dtype, scale=scale)
+        out = jnp.einsum("bshl,lhv->bshv", lat.astype(dtype),
+                         w_kv[:, :, z.nope:])
+        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), dtype=dtype,
+                               use_bias=False,
+                               kernel_init=_dense_init("heads", "kv",
+                                                       "embed"),
+                               name="o_proj")(out)
+
+    def _paged_attend(self, q, rows, index, block_tables, scale):
+        """Write the chunk's rows into the pool's ``latent`` leaf, then
+        attend over the rows through the tables: ``q`` (B, C, h, row) ->
+        (B, C, h, kv_rank), what the softmax weighs of the latents."""
+        cfg, z = self.cfg, self.cfg.latent
+        if index is None or block_tables is None:
+            raise ValueError(
+                "paged decode requires the per-request index vector and "
+                "the block tables")
+        from distributed_tensorflow_guide_tpu.ops import decode_attention as DA
+        from distributed_tensorflow_guide_tpu.serve.paged_cache import (
+            gather_view,
+            write_chunk,
+        )
+
+        N, bs = cfg.paged_num_blocks, cfg.paged_block_size
+        pallas = cfg.resolve_decode_impl() == "pallas"
+        leaf = self.variable("cache", "latent", jnp.zeros,
+                             (N, 1, z.row, bs), cfg.dtype)
+        leaf.value = write_chunk(
+            leaf.value, jnp.swapaxes(rows, 1, 2)[:, None], block_tables,
+            index, block_size=bs,
+            kernel=pallas and DA.paged_write_fits((1, z.row, bs), cfg.dtype))
+        lengths = index + q.shape[1]
+        if pallas:
+            with jax.named_scope("dtg.latent_attn"):
+                return DA.paged_latent_attention(
+                    q, leaf.value, block_tables, lengths, block_size=bs,
+                    value=z.kv_rank, scale=scale)
+        view = gather_view(leaf.value, block_tables)  # (B, 1, row, S)
+        with jax.named_scope("dtg.latent_attn"):
+            return _dense_cache_read(q, view, view[:, :, :z.kv_rank], index,
+                                     "bhdk", cfg.dtype, scale=scale)
 
 
 def _in_pairs(q, k=None, v=None):
@@ -2016,7 +2167,8 @@ class Block(nn.Module):
     def _patterned(self, x, index, block_tables, state_rows, valid,
                    carried):
         """``x + mixer(norm(x))`` then ``x + ffn(norm(x))``, each half only
-        if the layer has it (and its norm, ``ln1`` / ``ln2``, with it). A
+        if the layer has it (and its norm, ``ln1`` / ``ln2``, with it; with
+        ``sandwich_norm`` a second on its output, ``_sandwiched``). A
         routed layer's shared expert reads the same normalised rows and is
         a module of the block's, ``shared``, beside ``mlp``. Returns ``x``
         and what the layer hands to later ones (``_hybrid_mixer``)."""
@@ -2028,19 +2180,23 @@ class Block(nn.Module):
                     mixer == "attention" and "cross_attention" in mixers):
                 out, carried = self._hybrid_mixer(
                     h, index, block_tables, state_rows, valid, carried)
-                x = x + out
             elif mixer == "attention":
                 with jax.named_scope("dtg.attn"):
-                    x = x + MultiHeadAttention(
+                    out = MultiHeadAttention(
                         cfg, layer=self.layer, name="attn")(
                             h, index, block_tables=block_tables)
+            elif mixer == "latent_attention":
+                with jax.named_scope("dtg.mla"):
+                    out = LatentAttention(cfg, name="attn")(
+                        h, index, block_tables=block_tables)
             else:
                 scope, module, name = {
                     "short_conv": ("dtg.short_conv", ShortConv, "conv"),
                     "mamba2": ("dtg.ssm", Mamba2, "ssm")}[mixer]
                 with jax.named_scope(scope):
-                    x = x + module(cfg, name=name)(
+                    out = module(cfg, name=name)(
                         h, index, state_rows=state_rows, valid=valid)
+            x = x + self._sandwiched(out, "ln1_post")
         if ffn == "routed":
             h2 = _norm(cfg, "ln2")(x)
             live = None
@@ -2052,10 +2208,16 @@ class Block(nn.Module):
                 with jax.named_scope("dtg.shared_expert"):
                     y = y + MLP(cfg, width=cfg.shared_d_ff,
                                 name="shared")(h2)
-            x = x + y
+            x = x + self._sandwiched(y, "ln2_post")
         elif ffn == "dense":
-            x = x + MLP(cfg, name="mlp")(_norm(cfg, "ln2")(x))
+            y = MLP(cfg, name="mlp")(_norm(cfg, "ln2")(x))
+            x = x + self._sandwiched(y, "ln2_post")
         return _constrain(x, ("batch", "seq", "embed")), carried
+
+    def _sandwiched(self, out, name: str):
+        """A half's output as the residual stream takes it: normalised
+        again (``name``'s gain) where the model has ``sandwich_norm``."""
+        return _norm(self.cfg, name)(out) if self.cfg.sandwich_norm else out
 
     @nn.compact
     def __call__(self, x: jax.Array, index=None, *,
@@ -2114,9 +2276,11 @@ class Transformer(nn.Module):
         ``embed``'s table, transposed, where the head is tied to it)."""
         cfg = self.cfg
         if cfg.decode and not cfg.paged:
+            kinds = sorted({m for m, _ in cfg.layers if m})
             raise ValueError(
                 "a patterned model decodes through the paged engine only "
-                "(serve/engine.py): it has no one-shot cache")
+                f"(serve/engine.py): its {', '.join(kinds)} layers have no "
+                "one-shot cache")
         if cfg.position_kind == "table":
             positions = jnp.arange(x.shape[1])[None, :]
             if cfg.decode:

@@ -701,6 +701,174 @@ def paged_decode_attention(q, key_pool, value_pool, block_tables, lengths,
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, C, H, hd)
 
 
+# --------------------------------------------------------------------------
+# paged latent attention: one row a position, read once for both products
+# --------------------------------------------------------------------------
+#
+# A latent attention layer (models/transformer.py ``LatentAttention``) keeps
+# one row a position, ``[c_kv | k_r]``, in a pool leaf ``(N, 1, row,
+# block_size)`` (the pool's one layout: a block's slots on the lanes). Its
+# queries arrive absorbed, ``[q_nope W_uk^T | q_rope]`` a head, ``row`` wide:
+# a score is the row against the query, and what the softmax weighs is the
+# row's first ``value`` numbers (the latent). So a grid step takes a tile of
+# rows ONCE and makes both products from it, for every query head at once.
+
+
+def latent_tiling(heads: int, *, chunk: int, row: int, value: int,
+                  block_size: int, n_blk: int, dtype) -> tuple[int, int]:
+    """``(hb, nb)`` for :func:`paged_latent_attention`: query heads a grid
+    step carries and pool blocks it reads. All the heads where their rows
+    (``hb * chunk``) stay within 256 sublanes (a decode step carries all
+    of them, so a tile is read once a slot), else the largest divisor that
+    does; then the most blocks, up to 8 and dividing ``n_blk``, whose
+    working set fits ``autotune.VMEM_BUDGET_BYTES``: each block's tile, the
+    query and output rows, each double-buffered; the float32 ``m``, ``l``
+    and ``acc``; the scores, the probabilities and the select, float32, a
+    lane per key."""
+    f32 = jnp.float32
+    divisors = [d for d in range(1, heads + 1) if heads % d == 0]
+    hb = max([d for d in divisors if d * chunk <= 256], default=1)
+    rows = -(-hb * chunk // 16) * 16
+
+    def need(nb):
+        keys = nb * block_size
+        return (2 * nb * _vmem_tile_bytes(row, block_size, dtype)
+                + 2 * _vmem_tile_bytes(rows, row, dtype)
+                + 2 * _vmem_tile_bytes(rows, value, dtype)
+                + 2 * _vmem_tile_bytes(rows, LANE, f32)
+                + _vmem_tile_bytes(rows, value, f32)
+                + 3 * _vmem_tile_bytes(rows, keys, f32))
+
+    fits = [nb for nb in (8, 4, 2, 1)
+            if n_blk % nb == 0 and need(nb) <= autotune.VMEM_BUDGET_BYTES]
+    return hb, max(fits, default=1)
+
+
+def _paged_latent_kernel(len_ref, bt_ref, q_ref, *refs, scale: float,
+                         nb: int, block_size: int, chunk: int, rows: int,
+                         value: int):
+    """One grid step: ``hb`` query heads of one slot against ``nb`` pool
+    blocks. ``q_ref`` (1, 1, R, row): row ``r < rows`` is head ``r //
+    chunk`` at chunk position ``r % chunk`` (rows past ``rows`` pad);
+    ``refs`` the ``nb`` tiles (1, 1, row, block_size), then ``o_ref`` (1,
+    1, R, value) and the float32 ``m``, ``l`` (R, LANE) and ``acc`` (R,
+    value)."""
+    tiles, (o_ref, m_scr, l_scr, acc_scr) = refs[:nb], refs[nb:]
+    b, j = pl.program_id(0), pl.program_id(2)
+    keys = nb * block_size
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = len_ref[b]
+
+    @pl.when(j * keys < length)
+    def _():
+        q = q_ref[0, 0]  # (R, row)
+        rp = q.shape[0]
+        s = jnp.concatenate([jax.lax.dot_general(
+            q, t[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for t in tiles],
+            axis=1) * scale  # (R, keys): every tile once, its keys on lanes
+        r = jnp.minimum(
+            jax.lax.broadcasted_iota(jnp.int32, (rp, keys), 0), rows - 1)
+        q_pos = (length - chunk) + jax.lax.rem(r, chunk)
+        k_pos = j * keys + jax.lax.broadcasted_iota(jnp.int32, (rp, keys), 1)
+        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+        l_scr[...] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        p = p.astype(q.dtype)
+        weighed = acc_scr[...] * alpha
+        for i, t in enumerate(tiles):  # the same tiles' latent rows
+            weighed += jax.lax.dot_general(
+                p[:, i * block_size:(i + 1) * block_size],
+                t[0, 0, :value, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        acc_scr[...] = weighed
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        l = l_scr[:, :1]
+        o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def paged_latent_attention(q, pool, block_tables, lengths, *,
+                           block_size: int, value: int, scale: float,
+                           tiling: tuple[int, int] | None = None):
+    """Attention over a latent pool through block tables.
+
+    ``q`` (B, C, H, row): the absorbed queries. ``pool`` (num_blocks, 1,
+    row, block_size): a position's row a lane. ``block_tables`` (B,
+    blocks_per_seq) int32; ``lengths`` (B,) live lengths after the chunk's
+    write, the chunk at ``[lengths[b] - C, lengths[b])``, causal within it.
+    Returns (B, C, H, value) in q's dtype: each head's softmax over the
+    scores ``q . row`` at ``scale``, weighing the rows' first ``value``
+    numbers.
+
+    The grid is ``(B, H // hb, n_blk // nb)`` (:func:`latent_tiling`): a
+    step reads ``nb`` blocks, each an operand of its own mapped through
+    the table, and makes the scores and the weighted sum of ``hb`` heads
+    from them. A tile past a row's length maps to its last live block, so
+    the pipeline fetches nothing new for it, and computes nothing."""
+    B, C, H, row = q.shape
+    n_blk = block_tables.shape[1]
+    hb, nb = tiling or latent_tiling(
+        H, chunk=C, row=row, value=value, block_size=block_size,
+        n_blk=n_blk, dtype=pool.dtype)
+    if H % hb or n_blk % nb or pool.shape[1:] != (1, row, block_size):
+        raise ValueError(
+            f"paged_latent_attention: {H} heads by {hb}, {n_blk} blocks by "
+            f"{nb}, a pool of {pool.shape[1:]} for rows of {row}")
+    rows = hb * C
+    rp = -(-rows // 16) * 16
+    qk = jnp.transpose(q.reshape(B, C, H // hb, hb, row),
+                       (0, 2, 3, 1, 4)).reshape(B, H // hb, rows, row)
+    if rp != rows:
+        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
+    lengths = jnp.maximum(jnp.asarray(lengths, jnp.int32), 1)
+
+    def tile_map(i):
+        def index(b, g, j, len_ref, bt_ref):
+            last = (len_ref[b] + block_size - 1) // block_size - 1
+            return (bt_ref[b, jnp.minimum(j * nb + i, last)], 0, 0, 0)
+        return index
+
+    q_spec = _vmem_spec((1, 1, rp, row), lambda b, g, j, L, T: (b, g, 0, 0))
+    o_spec = _vmem_spec((1, 1, rp, value),
+                        lambda b, g, j, L, T: (b, g, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, H // hb, n_blk // nb),
+        in_specs=[q_spec] + [_vmem_spec((1, 1, row, block_size), tile_map(i))
+                             for i in range(nb)],
+        out_specs=o_spec,
+        scratch_shapes=[_vmem_scratch((rp, LANE), jnp.float32),
+                        _vmem_scratch((rp, LANE), jnp.float32),
+                        _vmem_scratch((rp, value), jnp.float32)],
+    )
+    kernel = functools.partial(_paged_latent_kernel, scale=scale, nb=nb,
+                               block_size=block_size, chunk=C, rows=rows,
+                               value=value)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H // hb, rp, value), q.dtype),
+        interpret=_interpret(),
+    )(lengths, jnp.asarray(block_tables, jnp.int32), qk, *([pool] * nb))
+    out = out[:, :, :rows].reshape(B, H // hb, hb, C, value)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, C, H, value)
+
+
 def _paged_write_kernel(phys_ref, first_ref, new_ref, pool_ref, out_ref, *,
                         chunk: int):
     # slot s of this block is position first + s of the row's chunk

@@ -1108,15 +1108,18 @@ class ServeEngine:
 
     def _decode_keys(self, ready: list[int]) -> dict:
         """What a decode launch over the ``ready`` slots reads of keys and
-        values, for a model with window layers (``{}`` for any other):
-        ``live_keys``, the rows' live lengths after the launch's write
-        summed (what a full-attention layer, and each layer that reads its
-        cache, attends), and ``window_keys``, each length capped at the
-        window (what a window layer attends)."""
-        window = self.fns.cfg.window
-        if window is None:
+        values, for a model with window or latent layers (``{}`` for any
+        other): ``live_keys``, the rows' live lengths after the launch's
+        write summed (what a full-attention or latent layer, and each layer
+        that reads its cache, attends), and with window layers
+        ``window_keys``, each length capped at the window (what a window
+        layer attends)."""
+        window, latent = self.fns.cfg.window, self.fns.cfg.latent
+        if window is None and latent is None:
             return {}
         live = [self.sched.slots[i].written + 1 for i in ready]
+        if window is None:
+            return {"live_keys": sum(live)}
         return {"live_keys": sum(live),
                 "window_keys": sum(min(n, window) for n in live)}
 

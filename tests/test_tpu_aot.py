@@ -193,6 +193,31 @@ def test_paged_decode_kernel_compiles(v5e, as_on_tpu, cache, block_size,
     assert_mosaic(compile_for(SingleDeviceSharding(v5e[0]), fn, *args))
 
 
+@pytest.mark.parametrize("rows,chunk", [(128, 1), (4, 128)])
+def test_paged_latent_kernel_compiles_at_the_pangu_cells_shapes(
+        v5e, as_on_tpu, rows, chunk):
+    """The latent kernel at openPangu-Ultra-MoE's widths (128 heads, a
+    row of 512 + 64, a value of 512) over the cell's pool (8,192 blocks of
+    128, 64 a slot): a decode launch of 128 slots, every head a step, and
+    a prefill launch of four 128-token chunks, within the VMEM the
+    compiler allows."""
+    n_blk, bs = 64, 128
+    q = sds((rows, chunk, 128, 576), jnp.bfloat16)
+    pool = sds((8192, 1, 576, bs), jnp.bfloat16)
+
+    def fn(q, pool, tables, lengths):
+        return DA.paged_latent_attention(q, pool, tables, lengths,
+                                         block_size=bs, value=512,
+                                         scale=192 ** -0.5)
+
+    compiled = compile_for(SingleDeviceSharding(v5e[0]), fn, q, pool,
+                           sds((rows, n_blk), jnp.int32),
+                           sds((rows,), jnp.int32))
+    assert_mosaic(compiled)
+    # the pool is read in place: nothing of its size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 8192 * 576 * bs
+
+
 # ---- a patterned model's parts, at LFM2's published widths (PR 28) ----------
 
 
